@@ -6,7 +6,8 @@ error-feedback compressor (repro.runtime.compression) must cut the
 pod-axis (DCN) payload ~4x vs f32 / ~2x vs bf16.
 
 Runs in a subprocess so the 512-device XLA flag never leaks into the
-benchmark process (the dry-run rule).
+benchmark process (the dry-run rule).  The child is pinned to the CPU
+(``JAX_PLATFORMS=cpu``): on a TPU host the parent already holds the chip.
 """
 from __future__ import annotations
 
@@ -20,11 +21,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import json
 import jax, jax.numpy as jnp
 from functools import partial
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    shard_map = partial(jax.shard_map, check_vma=False)
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _sm
-    shard_map = partial(_sm, check_rep=False)
+shard_map = partial(jax.shard_map, check_vma=False)
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch import hlo
@@ -61,7 +58,7 @@ def run():
     proc = subprocess.run([sys.executable, "-c", _CHILD],
                           capture_output=True, text=True, timeout=600,
                           env={**__import__("os").environ,
-                               "PYTHONPATH": "src"})
+                               "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"})
     if proc.returncode != 0:
         raise RuntimeError(proc.stderr[-1500:])
     data = json.loads(proc.stdout.strip().splitlines()[-1])

@@ -29,6 +29,8 @@ def _row_val(val):
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (attribution_serving, compression, fp_bp_overhead,
                             kernels, lm_attribution, load_replay,
                             memory_overhead, perturbation, roofline,

@@ -114,6 +114,44 @@ def requantize_np(acc: np.ndarray, shift: int = WGT_FRAC) -> np.ndarray:
                    -INT16_LIM, INT16_LIM).astype(np.int16)
 
 
+def _i8_digits(a: jnp.ndarray):
+    """int16-range int32 ``a`` -> int8 digits with ``a = 256*hi + lo + 128``."""
+    hi = (a >> 8).astype(jnp.int8)                  # [-128, 127]
+    lo = ((a & 255) - 128).astype(jnp.int8)         # [-128, 127]
+    return hi, lo
+
+
+def dot_i16(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """int16 [M, K] @ int16 [K, N] -> int32, exact, as four int8 MXU dots.
+
+    The TPU MXU takes int8 (not int16) operands.  With both operands split
+    as ``256*hi + lo + 128`` (:func:`_i8_digits`), and ``a' = a - 128``:
+
+        a @ b = a' @ b' + 128 * (rowsum(a) + colsum(b)) - 16384 * K
+        a' @ b' = 65536 hi_a@hi_b + 256 (hi_a@lo_b + lo_a@hi_b) + lo_a@lo_b
+
+    Every step is int32 arithmetic modulo 2^32, so the result equals the
+    int32-accumulated int16 dot bit for bit (wraparound included).  Usable
+    inside Pallas kernel bodies; operands may be int16 or their int32
+    carriers.
+    """
+    a = a.astype(jnp.int32)
+    b = b.astype(jnp.int32)
+    ha, la = _i8_digits(a)
+    hb, lb = _i8_digits(b)
+
+    def mm(x, y):       # explicit precision: never the float default's
+        return jnp.dot(x, y, preferred_element_type=jnp.int32,
+                       precision=jax.lax.Precision.DEFAULT)
+
+    k = a.shape[-1]
+    ab = ((mm(ha, hb) << 16) + ((mm(ha, lb) + mm(la, hb)) << 8)
+          + mm(la, lb))
+    rows = jnp.sum(a, axis=-1, keepdims=True)
+    cols = jnp.sum(b, axis=0, keepdims=True)
+    return ab + ((rows + cols) << 7) - (jnp.int32(k) << 14)
+
+
 def sat_add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Saturating int16 add (bias adds) — widen to int32, clip, narrow."""
     s = a.astype(jnp.int32) + b.astype(jnp.int32)

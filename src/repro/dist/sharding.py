@@ -12,16 +12,14 @@ Logical -> physical:
   model   -> "model"   (TP / SP)
   expert  -> "model"   (EP rides the same 16-way axis, mesh.py docstring)
 
-Logical axes without a translation entry fall through to themselves — e.g.
-"seeds" (the attribution seed-batch axis of the sharded serving engines)
-shards over a physical "seeds" axis when the mesh has one and replicates
-otherwise.
+Logical axes without a translation entry fall through to themselves and
+shard over a physical axis of that name when the mesh has one.
 
 Axes absent from the mesh are dropped to ``None`` — a smaller mesh silently
 replicates instead of erroring, which is what lets the dry-run lower the same
-program on single- and multi-pod meshes, and lets the ``mesh:<profile>:<n>``
-serving engines (``launch/mesh.py:make_serving_mesh``) run unchanged on the
-1-device CPU harness.
+program on single- and multi-pod meshes.  (The ``mesh:<profile>:<n>``
+serving engines do not use these constraints: Pallas kernels cannot be
+partitioned by the compiler, so ``repro.engine`` shard_maps them.)
 """
 from __future__ import annotations
 

@@ -22,11 +22,13 @@ to the manual int16 pair automatically (integers have no ``jax.vjp``).
 """
 from __future__ import annotations
 
+import math
 from collections import OrderedDict
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.engine import methods
 from repro.engine.backward import (BackwardEngine, ManualSeedBatchedBackward,
@@ -68,13 +70,11 @@ class Engine:
         # of the rule-bound logits program runs the planned block shapes.
         self._plan = spec.resolve_plan()
         # Mesh-sharded build: a ``mesh:<profile>:<n>`` device compiles ONE
-        # predict/explain pair whose inputs/outputs carry logical-axis
-        # sharding constraints under the serving mesh.  The plan above is
+        # predict/explain pair that shard_maps the batch over the serving
+        # mesh (see _shard_map below).  The plan above is
         # already per-shard (plan_cnn splits batch/seeds across the mesh
-        # before tiling); here the physical placement is resolved.  On a
-        # host with fewer devices than shards the mesh is capped and the
-        # constraints silently replicate (dist.sharding contract) — same
-        # program, degenerate placement, bitwise-identical outputs.
+        # before tiling); here the physical placement is resolved.  A host
+        # with fewer devices than shards raises (make_serving_mesh).
         device = (spec.device if spec.device is not None
                   else (self._plan.device if self._plan else None))
         if device is not None:
@@ -97,14 +97,14 @@ class Engine:
             fwd, bwd = model.pair(rules, spec.precision,
                                   plan=self._plan)
             if self._mesh is not None:
-                fwd = self._shard_pair_fwd(fwd)
+                fwd = self._shard_batch(fwd)
                 bwd = self._shard_pair_bwd(bwd)
             self._backend = ManualSeedBatchedBackward(fwd, bwd)
         else:
             f = model.logits_fn(rules, spec.precision,
                                 plan=self._plan)
             if self._mesh is not None:
-                f = self._shard_logits_fn(f)
+                f = self._shard_batch(f)
             self._backend = VjpBackward(f)
         # Rule-bound logits program: shared by predict, the composite
         # methods, and registry explainers.  Under fxp16 this IS the pair
@@ -115,56 +115,54 @@ class Engine:
             f = model.logits_fn(rules, spec.precision,
                                 plan=self._plan)
             if self._mesh is not None:
-                f = self._shard_logits_fn(f)
+                f = self._shard_batch(f)
             self._model_fn = jax.jit(f)
 
     # -- mesh-sharded build --------------------------------------------------
+    #
+    # A Pallas (Mosaic) kernel cannot be partitioned by the compiler, so the
+    # sharded programs are explicit ``jax.shard_map``s over the serving
+    # mesh's "data" axis: each device runs the unchanged single-device
+    # program on its slice of the batch (seeds replicated, masks/indices
+    # sharded with their examples).  The batch pads up to a multiple of the
+    # shard count and the outputs are sliced back, so any batch size runs.
+    # Packed uint8 residuals leave the shard_map as int32 words
+    # (:class:`_Words`): XLA:TPU corrupted [N, 32, 32, 4] uint8 masks when
+    # it resharded them (slicing the padded batch, the residual cache's
+    # per-example slices) on a v5e 2x2; int32 words reshard intact.
 
-    def _constrain_batch(self, v):
-        """Constrain an array's leading axis to the logical "batch" axis."""
-        from repro.dist.sharding import constrain
-        return constrain(v, "batch", *(None,) * (v.ndim - 1))
+    def _shard_map(self, f, in_specs, out_specs):
+        return jax.shard_map(f, mesh=self._mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
-    def _constrain_seeds(self, v):
-        """Constrain a [S, B, ...] array: seeds axis then batch axis."""
-        from repro.dist.sharding import constrain
-        return constrain(v, "seeds", "batch", *(None,) * (v.ndim - 2))
+    def _padded(self, b: int) -> int:
+        return -(-b // self._n_shards) * self._n_shards
 
-    def _shard_pair_fwd(self, fwd):
-        """Wrap a pair forward so the serving mesh is active AT TRACE TIME
-        (``use_mesh`` must be entered inside the jitted function body —
-        the backend jits at construction, traces at first call)."""
-        from repro.dist.sharding import use_mesh
-        mesh = self._mesh
+    def _shard_batch(self, f):
+        """``f(x [B, ...]) -> outputs [B, ...]`` (logits, or the pair
+        forward's ``(logits, residuals)``), batch-sharded."""
+        sharded = self._shard_map(lambda x: jax.tree.map(_Words.wrap, f(x)),
+                                  P("data"), P("data"))
 
         def run(x):
-            with use_mesh(mesh):
-                logits, residuals = fwd(self._constrain_batch(x))
-                return self._constrain_batch(logits), residuals
+            b = _batch_of(x)
+            out = sharded(_pad_rows(x, 0, self._padded(b)))
+            return jax.tree.map(lambda v: v[:b], out)
 
         return run
 
     def _shard_pair_bwd(self, bwd):
-        """Wrap a pair backward: seeds ride [S, B, C] -> relevance
-        [S, B, ...]; both are constrained on ("seeds", "batch")."""
-        from repro.dist.sharding import use_mesh
-        mesh = self._mesh
+        """``(residuals [B, ...], seeds [S, B, C]) -> relevance [S, B, ...]``,
+        batch-sharded (the seeds axis rides along on every device)."""
+        sharded = self._shard_map(
+            lambda res, seeds: bwd(_Words.unwrap_tree(res), seeds),
+            (P("data"), P(None, "data")), P(None, "data"))
 
         def run(residuals, seeds):
-            with use_mesh(mesh):
-                rel = bwd(residuals, self._constrain_seeds(seeds))
-                return jax.tree.map(self._constrain_seeds, rel)
-
-        return run
-
-    def _shard_logits_fn(self, f):
-        """Wrap a plain ``f(x) -> logits`` with batch-axis constraints."""
-        from repro.dist.sharding import use_mesh
-        mesh = self._mesh
-
-        def run(x):
-            with use_mesh(mesh):
-                return self._constrain_batch(f(self._constrain_batch(x)))
+            b, bp = seeds.shape[1], self._padded(seeds.shape[1])
+            rel = sharded(_pad_rows(residuals, 0, bp),
+                          _pad_rows(seeds, 1, bp))
+            return jax.tree.map(lambda v: v[:, :b], rel)
 
         return run
 
@@ -442,7 +440,7 @@ class Engine:
                 self._fold_fn = self._model_fn
             else:
                 if self._mesh is not None:
-                    f = self._shard_logits_fn(f)
+                    f = self._shard_batch(f)
                 self._fold_fn = jax.jit(f)
         return self._fold_fn
 
@@ -581,6 +579,65 @@ class Engine:
 
     def __repr__(self):
         return f"<Engine {self.spec!r}>"
+
+
+@jax.tree_util.register_pytree_node_class
+class _Words:
+    """A sub-32-bit array carried as int32 words: ``[N, *rest]`` flattened
+    to ``[N, ceil(bytes/4)]`` (zero-padded), bitcast back on unwrap.
+    Lossless and the same size, so residual-cache accounting is unchanged;
+    the leading (batch) axis is kept so slicing per example still works."""
+
+    def __init__(self, words, dtype, rest):
+        self.words, self.dtype, self.rest = words, dtype, rest
+
+    def tree_flatten(self):
+        return (self.words,), (self.dtype, self.rest)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], *aux)
+
+    @classmethod
+    def wrap(cls, v):
+        if v.dtype.itemsize >= 4:
+            return v
+        n, rest = v.shape[0], v.shape[1:]
+        per = 4 // v.dtype.itemsize
+        flat = v.reshape(n, -1)
+        flat = _pad_rows(flat, 1, -(-flat.shape[1] // per) * per)
+        words = jax.lax.bitcast_convert_type(flat.reshape(n, -1, per),
+                                             jnp.int32)
+        return cls(words, v.dtype, rest)
+
+    def unwrap(self):
+        n = self.words.shape[0]
+        flat = jax.lax.bitcast_convert_type(self.words, self.dtype)
+        size = math.prod(self.rest)
+        return flat.reshape(n, -1)[:, :size].reshape((n,) + self.rest)
+
+    @staticmethod
+    def unwrap_tree(tree):
+        return jax.tree.map(
+            lambda v: v.unwrap() if isinstance(v, _Words) else v, tree,
+            is_leaf=lambda v: isinstance(v, _Words))
+
+
+def _batch_of(x) -> int:
+    return jax.tree_util.tree_leaves(x)[0].shape[0]
+
+
+def _pad_rows(tree, axis: int, size: int):
+    """Zero-pad every leaf's ``axis`` up to ``size`` rows (sliced off after
+    the sharded launch, so the filler never reaches a caller)."""
+    def pad(v):
+        extra = size - v.shape[axis]
+        if extra == 0:
+            return v
+        widths = [(0, 0)] * v.ndim
+        widths[axis] = (0, extra)
+        return jnp.pad(v, widths)
+    return jax.tree.map(pad, tree)
 
 
 # ---------------------------------------------------------------------------

@@ -275,7 +275,7 @@ class EngineSpec:
         (and outputs sliced back) so one compiled program serves any
         smaller batch, the serving-shape discipline of the micro-batcher.
       * ``device`` — a ``repro.plan`` device-profile name (``"detected"``,
-        ``"tpu-v4"``, ``"edge-small"``, ...): ``build`` runs the
+        ``"tpu-v5e"``, ``"edge-small"``, ...): ``build`` runs the
         resource-aware tile planner for that profile BEFORE compiling, so
         every fused kernel executes block shapes fitted to its on-chip
         budget (the paper's per-FPGA-target resource model).  The

@@ -42,16 +42,68 @@ shared verbatim with the float kernels.
 
 Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling, MXU-aligned
 dots) and are validated on CPU with interpret=True against the ref.py
-oracles.  Every wrapper's ``interpret`` argument defaults to ``None`` ->
-:func:`interpret_mode`, so direct calls compile on TPU and interpret
-elsewhere without the caller having to thread the flag.
+oracles.  Every wrapper resolves its ``interpret`` argument through
+:func:`interpret_mode`: ``None`` compiles on TPU and interprets elsewhere,
+and an explicit ``interpret=True`` on a TPU backend is refused, so a chip
+run can never fall back to the Python interpreter unnoticed.
+
+Mosaic (the TPU kernel compiler) constrains the bodies: strided VMEM
+accesses only on 32-bit data, no scatter, no lane-splitting reshapes, no
+16-bit compares and no int16 MXU operands.  16-bit operands are therefore
+widened to 32 bits for the VPU work (:func:`widen`), bit packing is an
+exact small matmul (``relu_mask.pack_fields``), and the int16 dots are
+four int8 MXU dots (``repro.core.fixedpoint.dot_i16``).
 """
+from typing import Optional
+
 import jax
+import jax.numpy as jnp
 
 
-def interpret_mode() -> bool:
-    """True off-TPU: run kernel bodies in Python for CPU validation."""
-    return jax.default_backend() != "tpu"
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Resolve a wrapper's ``interpret`` argument.
+
+    ``None`` -> interpret exactly when the backend is not a TPU (CPU
+    validation).  ``True`` on a TPU backend raises: on the chip every
+    kernel runs compiled.
+    """
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise RuntimeError("interpret=True on a TPU backend: Pallas kernels "
+                           "run compiled on the chip, never interpreted")
+    return bool(interpret)
+
+
+_CARRIER = {jnp.dtype(jnp.bfloat16): jnp.dtype(jnp.float32),
+            jnp.dtype(jnp.int16): jnp.dtype(jnp.int32)}
+
+
+def carrier(dtype) -> jnp.dtype:
+    """32-bit carrier of a 16-bit dtype (bf16 -> f32, int16 -> int32)."""
+    dtype = jnp.dtype(dtype)
+    return _CARRIER.get(dtype, dtype)
+
+
+def mxu_precision(dtype) -> jax.lax.Precision:
+    """Contract precision of an in-kernel dot: f32 operands ask for full
+    f32 (Mosaic's fp32 contract) so the f32 path tracks an f32 reference;
+    other operands are exact on the MXU as they are.  Always explicit: an
+    unset precision would follow ``jax.default_matmul_precision``, and
+    Mosaic refuses an fp32 contract on bf16 or int8 operands."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
+
+
+def widen(x: jnp.ndarray) -> jnp.ndarray:
+    """A block on its 32-bit :func:`carrier` — exact.
+
+    The TPU vector unit has no 16-bit compares and strided VMEM accesses
+    need 32-bit data, so in-kernel selects run on the carrier.
+    """
+    return x.astype(carrier(x.dtype))
 
 
 def validate_bp_gates(method: str, gate, relu_mask, out_gate, out_relu_mask):

@@ -35,13 +35,18 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import interpret_mode, validate_bp_gates
-from repro.kernels.tiling import (SUBLANE, align_up, batch_tiling,
-                                  cout_tiling)
-from repro.kernels.pool.pool import unpack_crumbs, unpool_scatter
+from repro.kernels import (carrier, interpret_mode, mxu_precision,
+                           validate_bp_gates, widen)
+from repro.kernels.tiling import (CONV_VMEM_LIMIT_BYTES, SUBLANE, align_up,
+                                  batch_tiling, cout_tiling)
+from repro.kernels.pool.pool import unpack_crumbs, unpool_store
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
 from repro.obs import profile as obs_profile
+
+
+CONV_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=CONV_VMEM_LIMIT_BYTES)
 
 
 def _im2col_dot(xpad, K: int, H: int, W: int, wmat):
@@ -50,7 +55,8 @@ def _im2col_dot(xpad, K: int, H: int, W: int, wmat):
     cols = [xpad[:, i:i + H, j:j + W, :].reshape(s * H * W, c)
             for i in range(K) for j in range(K)]
     patches = jnp.concatenate(cols, axis=1)              # [S*H*W, K*K*C]
-    acc = jnp.dot(patches, wmat, preferred_element_type=jnp.float32)
+    acc = jnp.dot(patches, wmat, preferred_element_type=jnp.float32,
+                  precision=mxu_precision(patches.dtype))
     return acc.reshape(s, H, W, wmat.shape[-1])
 
 
@@ -78,8 +84,7 @@ def conv2d_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
     is block-size agnostic: the im2col patch matrix simply grows its
     sublane dim to ``bn * H * W``.
     """
-    if interpret is None:
-        interpret = interpret_mode()
+    interpret = interpret_mode(interpret)
     n, h, ww, cin = x.shape
     k, _, _, cout = w.shape
     p = (k - 1) // 2
@@ -103,6 +108,7 @@ def conv2d_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((bn, h, ww, tco), lambda b, c: (b, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((n_p, h, ww, cout_p), x.dtype),
+        compiler_params=CONV_PARAMS,
         interpret=interpret,
     )(xp, wp)
     return out[:n, ..., :cout]
@@ -122,6 +128,7 @@ def _conv_bwd_fused_kernel(*refs, K: int, H: int, W: int, method: str,
     m_ref = next(it) if has_mask else None
     om_ref = next(it) if has_omask else None
     o_ref = next(it)
+    u_ref = next(it) if has_pool else None              # 32-bit unpool scratch
 
     p = (K - 1) // 2
     c = g_ref.shape[-1]
@@ -130,14 +137,14 @@ def _conv_bwd_fused_kernel(*refs, K: int, H: int, W: int, method: str,
 
     g = g_ref[:, 0]                                     # [S, Hg, Wg, C]
     if has_pool:                                        # prologue 1: unpool
-        g = unpool_scatter(unpack_crumbs(i_ref[0]), g)  # -> [S, H, W, C]
+        unpool_store(u_ref, unpack_crumbs(i_ref[0]), widen(g))
+        g = u_ref[...].astype(g.dtype)                  # -> [S, H, W, C]
     if gate_in:                                         # prologue 2: Eq. 3-5
         m = unpack_bits(m_ref[0]) if has_mask else None
         g = gate_gradient(g, m, method)
 
     # halo-pad in VMEM, then the single im2col dot (flipped-transpose conv)
-    gp = jnp.zeros((s, H + 2 * p, W + 2 * p, c), g.dtype)
-    gp = gp.at[:, p:p + H, p:p + W, :].set(g)
+    gp = jnp.pad(g, ((0, 0), (p, p), (p, p), (0, 0)))
     out = _im2col_dot(gp, K, H, W, w_ref[...].reshape(K * K * c, tco))
 
     if gate_out:                                        # epilogue: prev ReLU
@@ -174,8 +181,7 @@ def conv2d_bwd_fused_pallas(
     Masks/indices carry no seeds axis: all S seeds share one stored residual
     load per tile (the paper's mask-reuse amortization).
     """
-    if interpret is None:
-        interpret = interpret_mode()
+    interpret = interpret_mode(interpret)
     gate, out_gate = validate_bp_gates(method, gate, relu_mask, out_gate,
                                        out_relu_mask)
     seeded = g.ndim == 5
@@ -232,6 +238,9 @@ def conv2d_bwd_fused_pallas(
         out_specs=pl.BlockSpec((s, 1, h, w_sp, tco),
                                lambda b, co: (0, b, 0, 0, co)),
         out_shape=jax.ShapeDtypeStruct((s, n, h, w_sp, cout_p), g.dtype),
+        scratch_shapes=([pltpu.VMEM((s, h, w_sp, cp), carrier(g.dtype))]
+                        if has_pool else []),
+        compiler_params=CONV_PARAMS,
         interpret=interpret,
     )(*operands)
     out = out[..., :cout]

@@ -21,23 +21,25 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.fixedpoint import WGT_FRAC, requantize
-from repro.kernels import interpret_mode, validate_bp_gates
+from repro.core.fixedpoint import WGT_FRAC, dot_i16, requantize
+from repro.kernels import interpret_mode, validate_bp_gates, widen
+from repro.kernels.conv2d.conv2d import CONV_PARAMS
 from repro.kernels.tiling import SUBLANE, align_up, cout_tiling
-from repro.kernels.pool.pool import unpack_crumbs, unpool_scatter
+from repro.kernels.pool.pool import unpack_crumbs, unpool_store
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
 from repro.obs import profile as obs_profile
 
 
 def _im2col_dot_i32(xpad, K: int, H: int, W: int, wmat):
-    """[S, H+2p, W+2p, C] int16 -> [S, H, W, T] int32 single-dot im2col."""
+    """[S, H+2p, W+2p, C] int16 values -> [S, H, W, T] int32 single-dot
+    im2col; the contraction is :func:`dot_i16` (int8 MXU digits)."""
     s, _, _, c = xpad.shape
     cols = [xpad[:, i:i + H, j:j + W, :].reshape(s * H * W, c)
             for i in range(K) for j in range(K)]
-    patches = jnp.concatenate(cols, axis=1)              # [S*H*W, K*K*C] i16
-    acc = jnp.dot(patches, wmat, preferred_element_type=jnp.int32)
-    return acc.reshape(s, H, W, wmat.shape[-1])
+    patches = jnp.concatenate(cols, axis=1)              # [S*H*W, K*K*C]
+    return dot_i16(patches, wmat).reshape(s, H, W, wmat.shape[-1])
 
 
 def _conv_fxp_kernel(x_ref, w_ref, o_ref, *, K: int, H: int, W: int,
@@ -45,7 +47,7 @@ def _conv_fxp_kernel(x_ref, w_ref, o_ref, *, K: int, H: int, W: int,
     cin = x_ref.shape[-1]
     tco = o_ref.shape[-1]
     wmat = w_ref[...].reshape(K * K * cin, tco)
-    acc = _im2col_dot_i32(x_ref[...], K, H, W, wmat)
+    acc = _im2col_dot_i32(widen(x_ref[...]), K, H, W, wmat)
     o_ref[...] = requantize(acc, shift)
 
 
@@ -58,8 +60,7 @@ def conv2d_fxp_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
     ``shift`` is the weight fraction width: products carry scale
     2^(8+shift) and one requantization returns the Q7.8 activation grid.
     """
-    if interpret is None:
-        interpret = interpret_mode()
+    interpret = interpret_mode(interpret)
     assert x.dtype == jnp.int16 and w.dtype == jnp.int16, (x.dtype, w.dtype)
     n, h, ww, cin = x.shape
     k, _, _, cout = w.shape
@@ -81,6 +82,7 @@ def conv2d_fxp_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, h, ww, tco), lambda b, c: (b, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((n, h, ww, cout_p), jnp.int16),
+        compiler_params=CONV_PARAMS,
         interpret=interpret,
     )(xp, wp)
     return out[..., :cout]
@@ -101,21 +103,22 @@ def _conv_bwd_fused_fxp_kernel(*refs, K: int, H: int, W: int, method: str,
     m_ref = next(it) if has_mask else None
     om_ref = next(it) if has_omask else None
     o_ref = next(it)
+    u_ref = next(it) if has_pool else None              # int32 unpool scratch
 
     p = (K - 1) // 2
     c = g_ref.shape[-1]
     s = g_ref.shape[0]
     tco = o_ref.shape[-1]
 
-    g = g_ref[:, 0]                                     # [S, Hg, Wg, C] i16
+    g = widen(g_ref[:, 0])                              # [S, Hg, Wg, C] i32
     if has_pool:                                        # prologue 1: unpool
-        g = unpool_scatter(unpack_crumbs(i_ref[0]), g)  # -> [S, H, W, C]
+        unpool_store(u_ref, unpack_crumbs(i_ref[0]), g)
+        g = u_ref[...]                                  # -> [S, H, W, C]
     if gate_in:                                         # prologue 2: Eq. 3-5
         m = unpack_bits(m_ref[0]) if has_mask else None
         g = gate_gradient(g, m, method)
 
-    gp = jnp.zeros((s, H + 2 * p, W + 2 * p, c), g.dtype)
-    gp = gp.at[:, p:p + H, p:p + W, :].set(g)
+    gp = jnp.pad(g, ((0, 0), (p, p), (p, p), (0, 0)))
     out = requantize(
         _im2col_dot_i32(gp, K, H, W, w_ref[...].reshape(K * K * c, tco)),
         shift)
@@ -140,8 +143,7 @@ def conv2d_bwd_fused_fxp_pallas(
     """int16 twin of :func:`conv2d.conv2d_bwd_fused_pallas` — same fused
     dataflow and argument contract, Q7.8 gradients / Q1.14 weights, ONE
     pallas_call per conv layer backward step."""
-    if interpret is None:
-        interpret = interpret_mode()
+    interpret = interpret_mode(interpret)
     assert g.dtype == jnp.int16 and wt.dtype == jnp.int16, (g.dtype, wt.dtype)
     gate, out_gate = validate_bp_gates(method, gate, relu_mask, out_gate,
                                        out_relu_mask)
@@ -197,6 +199,9 @@ def conv2d_bwd_fused_fxp_pallas(
         out_specs=pl.BlockSpec((s, 1, h, w_sp, tco),
                                lambda b, co: (0, b, 0, 0, co)),
         out_shape=jax.ShapeDtypeStruct((s, n, h, w_sp, cout_p), jnp.int16),
+        scratch_shapes=([pltpu.VMEM((s, h, w_sp, cp), jnp.int32)]
+                        if has_pool else []),
+        compiler_params=CONV_PARAMS,
         interpret=interpret,
     )(*operands)
     out = out[..., :cout]

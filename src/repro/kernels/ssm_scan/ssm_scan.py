@@ -59,8 +59,7 @@ def selective_scan_pallas(dt, x, bmat, cmat, a, h0, *, d_tile: int = 256,
 
     Returns (y [B,S,D] (x.dtype), h_last [B,D,N] f32).
     """
-    if interpret is None:
-        interpret = interpret_mode()
+    interpret = interpret_mode(interpret)
     b, s, d = x.shape
     n = a.shape[1]
     dt_t = min(d_tile, d)

@@ -22,8 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.fixedpoint import WGT_FRAC, requantize
-from repro.kernels import interpret_mode, validate_bp_gates
+from repro.core.fixedpoint import WGT_FRAC, dot_i16, requantize
+from repro.kernels import interpret_mode, validate_bp_gates, widen
 from repro.kernels.tiling import vmm_tiling
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
 from repro.obs import profile as obs_profile
@@ -34,8 +34,7 @@ def _mm_fxp_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int, shift: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                            preferred_element_type=jnp.int32)
+    acc_ref[...] += dot_i16(x_ref[...], w_ref[...])
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -52,8 +51,7 @@ def vmm_fxp_pallas(x: jnp.ndarray, w: jnp.ndarray, *, shift: int = WGT_FRAC,
     ``tm/tk/tn=None`` resolve through :func:`repro.kernels.tiling.vmm_tiling`
     (same policy as the f32 twin; int16 operands, int32 accumulator).
     """
-    if interpret is None:
-        interpret = interpret_mode()
+    interpret = interpret_mode(interpret)
     assert x.dtype == jnp.int16 and w.dtype == jnp.int16, (x.dtype, w.dtype)
     m, k = x.shape
     k2, n = w.shape
@@ -97,11 +95,11 @@ def _mm_bwd_fused_fxp_kernel(*refs, k_steps: int, shift: int, method: str,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    g = g_ref[0]
+    g = widen(g_ref[0])
     if gate_in:                                         # prologue: Eq. 3-5
         m = unpack_bits(m_ref[...]) if has_mask else None
         g = gate_gradient(g, m, method)
-    acc_ref[...] += jnp.dot(g, w_ref[...], preferred_element_type=jnp.int32)
+    acc_ref[...] += dot_i16(g, w_ref[...])
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -126,8 +124,7 @@ def vmm_bwd_fused_fxp_pallas(
     """int16 twin of :func:`vmm.vmm_bwd_fused_pallas` — same fused dataflow
     and argument contract, Q7.8 gradients / Q1.14 weights, ONE pallas_call
     per FC layer backward step."""
-    if interpret is None:
-        interpret = interpret_mode()
+    interpret = interpret_mode(interpret)
     assert g.dtype == jnp.int16 and w.dtype == jnp.int16, (g.dtype, w.dtype)
     gate, out_gate = validate_bp_gates(method, gate, relu_mask, out_gate,
                                        out_relu_mask)

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import interpret_mode, validate_bp_gates
+from repro.kernels import interpret_mode, mxu_precision, validate_bp_gates
 from repro.kernels.tiling import vmm_tiling
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
 from repro.obs import profile as obs_profile
@@ -37,8 +37,9 @@ def _mm_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                            preferred_element_type=jnp.float32)
+    x = x_ref[...]
+    acc_ref[...] += jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32,
+                            precision=mxu_precision(x.dtype))
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -55,8 +56,7 @@ def vmm_pallas(x: jnp.ndarray, w: jnp.ndarray, *, tm: Optional[int] = None,
     (planner-provided tiles override the defaults); K/N padding is always
     lane-aligned, never the raw dim.
     """
-    if interpret is None:
-        interpret = interpret_mode()
+    interpret = interpret_mode(interpret)
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
@@ -102,7 +102,8 @@ def _mm_bwd_fused_kernel(*refs, k_steps: int, method: str, gate_in: bool,
     if gate_in:                                         # prologue: Eq. 3-5
         m = unpack_bits(m_ref[...]) if has_mask else None
         g = gate_gradient(g, m, method)
-    acc_ref[...] += jnp.dot(g, w_ref[...], preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(g, w_ref[...], preferred_element_type=jnp.float32,
+                            precision=mxu_precision(g.dtype))
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -133,8 +134,7 @@ def vmm_bwd_fused_pallas(
     [M, ceil(N/8)].  Masks carry no seeds axis — shared across S.
     ``tk/tn=None`` resolve through :func:`repro.kernels.tiling.vmm_tiling`.
     """
-    if interpret is None:
-        interpret = interpret_mode()
+    interpret = interpret_mode(interpret)
     gate, out_gate = validate_bp_gates(method, gate, relu_mask, out_gate,
                                        out_relu_mask)
     seeded = g.ndim == 3

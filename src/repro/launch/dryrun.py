@@ -1,7 +1,9 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init). 512 placeholder host devices let jax.make_mesh build
+# The lines above MUST run before any other import (jax locks the platform
+# and device count at first init; on a TPU host the dry-run must not take
+# the chip). 512 placeholder host devices let jax.make_mesh build
 # the production (2, 16, 16) multi-pod mesh for lower()+compile() without
 # hardware. Dry-run only — smoke tests and benchmarks see the real 1 device.
 

@@ -31,7 +31,7 @@ import numpy as np
 
 import repro.configs as configs
 from repro import engine as engine_lib
-from repro.launch import steps as steps_lib
+from repro.launch import compile_cache, steps as steps_lib
 from repro.models import cnn as cnn_lib, transformer as tf
 from repro.obs import Tracer, dumps_strict, snapshot as obs_snapshot
 from repro.obs import profile as obs_profile
@@ -63,6 +63,23 @@ def explain(cfg, params, prompt_tokens, *, method: str = "saliency"):
         model=engine_lib.LMModel(params, cfg), method=method))
     logits, scores = eng.explain_tokens({"tokens": prompt_tokens})
     return logits, scores
+
+
+def check_outcome(tag: str, responses, sheds_at_submit: int,
+                  admission) -> None:
+    """Fail the run (non-zero exit) when any request errored, or was shed
+    without admission control configured to shed it."""
+    failed = [r for r in responses if not r.ok and r.error_type != "ShedError"]
+    shed = sheds_at_submit + sum(r.error_type == "ShedError"
+                                 for r in responses)
+    print(f"[serve/{tag}] {len(failed)} errors, {shed} shed")
+    if failed:
+        r = failed[0]
+        raise SystemExit(f"[serve/{tag}] {len(failed)} requests failed; "
+                         f"first: {r.uid} {r.error_type}: {r.error}")
+    if shed and admission is None:
+        raise SystemExit(f"[serve/{tag}] {shed} requests shed with no "
+                         f"admission control configured")
 
 
 def run_lm(args) -> None:
@@ -139,10 +156,8 @@ def run_lm(args) -> None:
         responses.extend(server.poll())
     responses.extend(server.drain())
     dt = time.time() - t0
-    errors = sum(1 for r in responses if not r.ok)
     print(f"[serve/lm] {len(responses)} responses in {dt:.2f}s "
-          f"({len(responses) / dt:.1f} req/s); sequence buckets {buckets}; "
-          f"{errors} errors")
+          f"({len(responses) / dt:.1f} req/s); sequence buckets {buckets}")
     if admission is not None:
         snap = server.stats.snapshot()
         print(f"[serve/lm] admission: {sheds} shed at submit "
@@ -165,6 +180,7 @@ def run_lm(args) -> None:
     if args.metrics:
         print("[serve/lm] unified metrics snapshot:")
         print(dumps_strict(obs_snapshot(), indent=2))
+    check_outcome("lm", responses, sheds, admission)
 
 
 def run_cnn(args) -> None:
@@ -268,6 +284,7 @@ def run_cnn(args) -> None:
                           measure=True)
         print(format_drift(rows))
         print(f"[serve/cnn] drift table -> {write_drift(rows)}")
+    check_outcome("cnn", responses, sheds, admission)
 
 
 def main():
@@ -329,6 +346,7 @@ def main():
                          "the cost-model drift table (measured vs "
                          "Footprint.est_time_s)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.workload == "lm":
         if args.method not in registry.token_methods():

@@ -379,7 +379,6 @@ def embed(p, tokens, cfg):
     table = p["table"]
     if mesh is None:
         return jnp.take(table, tokens, axis=0)
-    from jax.experimental.shard_map import shard_map
     names = set(mesh.axis_names)
     bd = tuple(a for a in ("pod", "data") if a in names)
     dp = 1
@@ -387,7 +386,7 @@ def embed(p, tokens, cfg):
         dp *= mesh.shape[a]
     tok_spec = (bd if (bd and tokens.shape[0] % dp == 0) else None)
     model = "model" if "model" in names else None
-    f = shard_map(
+    f = jax.shard_map(
         lambda t, x: jnp.take(t, x, axis=0),
         mesh=mesh,
         in_specs=(P(None, model), P(tok_spec, None)),

@@ -89,9 +89,8 @@ def _local_take(xpad, tok_slots):
         dp *= mesh.shape[ax]
     if mesh is None or xpad.shape[0] % max(dp, 1) != 0 or dp == 1:
         return jnp.take_along_axis(xpad, tok_slots[..., None], axis=1)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    f = shard_map(
+    f = jax.shard_map(
         lambda xp, ts: jnp.take_along_axis(xp, ts[..., None], axis=1),
         mesh=mesh,
         in_specs=(P(bd, None, None), P(bd, None)),
@@ -116,9 +115,8 @@ def _local_combine(yw, tok_slots, t: int):
         dp *= mesh.shape[ax]
     if mesh is None or yw.shape[0] % max(dp, 1) != 0 or dp == 1:
         return scatter(yw, tok_slots)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    f = shard_map(
+    f = jax.shard_map(
         scatter, mesh=mesh,
         in_specs=(P(bd, None, None), P(bd, None)),
         out_specs=P(bd, None, None),

@@ -7,9 +7,13 @@ model converts footprints into time with.
 
 Profiles:
 
-  * ``detected`` — the host we are actually on (TPU: a full ~16 MB/core
-    VMEM; CPU interpret mode adopts the same budget so plans are portable).
-  * ``tpu-v4``   — an explicit full-size TPU core target.
+  * ``detected`` — the host we are actually on: on a TPU the profile of
+    its ``device_kind`` (an unknown kind is an error, never a default); on
+    the CPU harness (interpret mode) the ``tpu-v5e`` envelope under the
+    name ``detected``, so a plan made on the CPU is the plan the chip runs.
+  * ``tpu-v5e``  — one TPU v5e chip: published peaks (Google Cloud, "TPU
+    v5e" system architecture page: 197 TFLOP/s bf16, 819 GB/s HBM) and a
+    16 MiB planning budget, Mosaic's default scoped-VMEM limit.
   * ``edge-large`` / ``edge-small`` / ``edge-tiny`` — constrained 4/2/1 MB
     on-chip budgets mirroring the paper's edge-FPGA deployment points
     (large/mid ZU+ class parts down to a small Artix-class part), with
@@ -24,6 +28,7 @@ Profiles:
 """
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Dict, Tuple
 
@@ -103,8 +108,9 @@ def mesh_profile(core, n_shards: int) -> MeshProfile:
 
 PROFILES: Dict[str, DeviceProfile] = {
     p.name: p for p in (
-        DeviceProfile("tpu-v4", vmem_bytes=16 * MB, mxu=128,
-                      hbm_gbps=1200.0, mxu_tflops=137.5),
+        # Google Cloud, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s.
+        DeviceProfile("tpu-v5e", vmem_bytes=16 * MB, mxu=128,
+                      hbm_gbps=819.0, mxu_tflops=197.0),
         # Paper-style edge targets: small on-chip budgets, narrow MAC
         # arrays, DDR-class bandwidth.
         DeviceProfile("edge-large", vmem_bytes=4 * MB, mxu=64,
@@ -117,18 +123,31 @@ PROFILES: Dict[str, DeviceProfile] = {
 }
 
 
+#: ``jax.devices()[0].device_kind`` -> profile name.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu-v5e",
+}
+
+
 def detect() -> DeviceProfile:
     """The profile of the host backend.
 
-    On a real TPU this is the full-core envelope; everywhere else the
-    kernels run in interpret mode, and the planner adopts the same 16 MB
-    budget so a plan made on the CPU harness is the plan the TPU runs.
+    On a TPU: the profile keyed by the device's ``device_kind``; a kind not
+    in :data:`DEVICE_KINDS` raises rather than borrow another chip's peaks.
+    Everywhere else the kernels run in interpret mode and the planner
+    adopts the ``tpu-v5e`` envelope, so a plan made on the CPU harness is
+    the plan the chip runs.
     """
     import jax
     if jax.default_backend() == "tpu":
-        return PROFILES["tpu-v4"]
-    return DeviceProfile("detected", vmem_bytes=16 * MB, mxu=128,
-                         hbm_gbps=1200.0, mxu_tflops=137.5)
+        kind = jax.devices()[0].device_kind
+        if kind not in DEVICE_KINDS:
+            raise ValueError(
+                f"no device profile for TPU device_kind {kind!r}; known "
+                f"kinds: {sorted(DEVICE_KINDS)} (add a sourced profile to "
+                f"repro.plan.profiles)")
+        return PROFILES[DEVICE_KINDS[kind]]
+    return dataclasses.replace(PROFILES["tpu-v5e"], name="detected")
 
 
 def profile_names() -> Tuple[str, ...]:

@@ -23,9 +23,14 @@ Bit-exact assertions compare SAME-PROGRAM outputs only:
 """
 import os
 
-# Tests run on the single real CPU device — the 512-device override is
-# strictly dry-run-only (see launch/dryrun.py).
+# Tests run on the CPU, split into four virtual devices so mesh-sharded
+# engines (``mesh:<profile>:4``) run a real 4-way mesh here; unsharded code
+# uses device 0.  The 512-device override is strictly dry-run-only (see
+# launch/dryrun.py).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["XLA_FLAGS"] = " ".join(
+    [os.environ.get("XLA_FLAGS", ""),
+     "--xla_force_host_platform_device_count=4"]).strip()
 
 import jax  # noqa: E402
 import pytest  # noqa: E402

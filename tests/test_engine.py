@@ -470,9 +470,9 @@ def test_one_shard_mesh_engine_is_bitwise_single_device(setup):
 
 
 def test_four_shard_mesh_engine_serves_and_matches(setup):
-    """An n_shards > local-device-count mesh degenerates to replicated
-    placement on the test harness but still reports its extent (for the
-    batcher's fill target) and serves correct results."""
+    """A 4-shard mesh over the harness's four virtual devices reports its
+    extent (for the batcher's fill target) and serves correct results; the
+    batch of 3 pads to 4 rows and is sliced back."""
     params, x = setup
     e4 = build(spec_for(params, method="saliency",
                         device="mesh:edge-small:4"))
@@ -497,6 +497,47 @@ def test_mesh_engine_forward_replay_roundtrip(setup):
     seeds = jax.nn.one_hot(jnp.argmax(logits0, -1), CFG.num_classes)[None]
     np.testing.assert_array_equal(np.asarray(e0.replay(res0, seeds)),
                                   np.asarray(e1.replay(res1, seeds)))
+
+
+def test_mesh_residuals_reshard_as_words_and_replay(setup):
+    """The 4-shard forward hands out no sub-32-bit residual (packed uint8
+    masks leave the shard_map as int32 words), and the residual cache's
+    per-example slice -> concat round trip replays like one device."""
+    from repro.serve.adapters import concat_examples, slice_example
+    params, x = setup
+    e0 = build(spec_for(params, method="guided", device="edge-small"))
+    e4 = build(spec_for(params, method="guided",
+                        device="mesh:edge-small:4"))
+    logits0, res0 = e0.forward(x)
+    logits4, res4 = e4.forward(x)
+    assert all(v.dtype.itemsize >= 4 for v in jax.tree.leaves(res4))
+    assert any(v.dtype == jnp.uint8 for v in jax.tree.leaves(res0))
+    cached = concat_examples([slice_example(res4, i)
+                              for i in range(x.shape[0])])
+    seeds = jax.nn.one_hot(jnp.argsort(logits0, -1)[:, ::-1][:, :2].T,
+                           CFG.num_classes)
+    np.testing.assert_allclose(np.asarray(e4.replay(cached, seeds)),
+                               np.asarray(e0.replay(res0, seeds)), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(logits4), np.asarray(logits0),
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype,shape", [
+    (jnp.uint8, (3, 8, 8, 4)), (jnp.uint8, (2, 5)), (jnp.int16, (3, 7)),
+    (jnp.bfloat16, (2, 3, 3))])
+def test_words_roundtrip_is_lossless(dtype, shape):
+    """_Words carries a sub-32-bit array as int32 words (ragged byte counts
+    zero-padded) with its leading axis kept, and unwraps it bit for bit."""
+    from repro.engine.engine import _Words
+    rng = np.random.RandomState(0)
+    v = jnp.asarray(rng.randint(0, 255, shape)).astype(dtype)
+    w = _Words.wrap(v)
+    assert w.words.dtype == jnp.int32 and w.words.shape[0] == shape[0]
+    back = jax.jit(_Words.unwrap)(w)
+    assert back.dtype == v.dtype
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(v))
+    f = jnp.ones((2, 3), jnp.float32)
+    assert _Words.wrap(f) is f
 
 
 def test_adapter_reports_mesh_extent(setup):

@@ -151,3 +151,30 @@ def test_ste_gradient_identity_property(seed):
     ct = jax.random.normal(jax.random.PRNGKey(seed + 1), (128,))
     g = jax.vjp(fxp.fxp16, x)[1](ct)[0]
     np.testing.assert_array_equal(np.asarray(g), np.asarray(ct))
+
+
+# ---------------------------------------------------------------------------
+# int16 dot as four int8 MXU dots (the TPU v5e lowering of the fxp kernels)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("values", ["random", "rails", "digit_edges"])
+@pytest.mark.parametrize("k", [1, 7, 576, 4096])
+def test_dot_i16_matches_wrapped_int32_dot(values, k):
+    """dot_i16 equals the int32-accumulated int16 dot bit for bit, including
+    int32 wraparound (the int64 product reduced mod 2^32)."""
+    rng = np.random.RandomState(k)
+    if values == "random":
+        a = rng.randint(-32767, 32768, (9, k))
+        b = rng.randint(-32767, 32768, (k, 5))
+    elif values == "rails":
+        a = rng.choice([-32767, 32767], (9, k))
+        b = rng.choice([-32767, 32767], (k, 5))
+    else:
+        edges = [-32768, -32640, -129, -128, -1, 0, 1, 127, 128, 255, 256,
+                 32639, 32640, 32767]
+        a, b = rng.choice(edges, (9, k)), rng.choice(edges, (k, 5))
+    a, b = a.astype(np.int16), b.astype(np.int16)
+    want = (a.astype(np.int64) @ b.astype(np.int64)).astype(np.int32)
+    got = np.asarray(fxp.dot_i16(jnp.asarray(a), jnp.asarray(b)))
+    np.testing.assert_array_equal(got, want)

@@ -69,3 +69,36 @@ def test_pool_ops_vjp_matches_rules():
     d_k = jax.vjp(lambda v: pops.maxpool2x2(v, "saliency"), x)[1](g)[0]
     d_r = jax.vjp(lambda v: rules.maxpool2x2(v, "saliency"), x)[1](g)[0]
     np.testing.assert_array_equal(np.asarray(d_k), np.asarray(d_r))
+
+
+@pytest.mark.parametrize("width", [1, 2])
+def test_pack_fields_matches_core_masks(width):
+    """The in-kernel MXU pack reproduces core.masks' byte layout exactly."""
+    from repro.core import masks
+    from repro.kernels.relu_mask.relu_mask import pack_fields
+    rng = np.random.RandomState(width)
+    v = rng.randint(0, 1 << width, (40, 64)).astype(np.int32)
+    want = (masks.pack_mask(jnp.asarray(v, bool)) if width == 1
+            else masks.pack_crumbs(jnp.asarray(v)))
+    got = pack_fields(jnp.asarray(v), width).astype(jnp.uint8)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_interpret_refused_on_tpu_backend(monkeypatch):
+    """On a TPU backend a kernel never runs interpreted: None resolves to
+    compiled, and an explicit interpret=True raises."""
+    import repro.kernels as kernels
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "tpu")
+    assert kernels.interpret_mode() is False
+    assert kernels.interpret_mode(False) is False
+    with pytest.raises(RuntimeError, match="never interpreted"):
+        kernels.interpret_mode(True)
+    x = jnp.ones((8, 128), jnp.float32)
+    with pytest.raises(RuntimeError, match="never interpreted"):
+        relu_fwd_pallas(x, interpret=True)
+
+
+def test_interpret_default_off_tpu():
+    import repro.kernels as kernels
+    assert kernels.interpret_mode() is True
+    assert kernels.interpret_mode(True) is True

@@ -470,3 +470,33 @@ def test_mesh_plan_tiles_the_per_shard_slice():
     local = plan_cnn(TINY_CFG, device="edge-small", batch=2, seeds=1)
     assert split.keys() == whole.keys()
     assert split.entries == local.entries
+
+
+def test_detect_keys_tpu_profile_on_device_kind(monkeypatch):
+    """On a TPU, detect() returns the profile of the device's kind (v5e:
+    the published 197 TF/s bf16 and 819 GB/s), and an unknown kind raises
+    instead of borrowing another chip's peaks."""
+    import jax
+
+    from repro.plan import profiles
+
+    class Device:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [Device()])
+    p = profiles.detect()
+    assert (p.name, p.mxu_tflops, p.hbm_gbps) == ("tpu-v5e", 197.0, 819.0)
+    Device.device_kind = "TPU v99"
+    with pytest.raises(ValueError, match="no device profile"):
+        profiles.detect()
+
+
+def test_cpu_detected_profile_is_the_v5e_envelope():
+    """Off-TPU, plans are made against the v5e envelope, so a plan made on
+    the CPU harness is the plan the chip runs."""
+    v5e = get_profile("tpu-v5e")
+    assert DETECTED.name == "detected"
+    assert ((DETECTED.vmem_bytes, DETECTED.hbm_gbps, DETECTED.mxu_tflops)
+            == (v5e.vmem_bytes, v5e.hbm_gbps, v5e.mxu_tflops))

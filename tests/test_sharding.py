@@ -96,7 +96,7 @@ def test_serving_mesh_replicates_absent_axes():
     silently replicate — the absent-axis fallback the mesh-sharded
     engines lean on."""
     from repro.launch.mesh import make_serving_mesh
-    mesh = make_serving_mesh(4)        # capped at the local device count
+    mesh = make_serving_mesh(4)        # the four virtual test devices
     assert tuple(mesh.axis_names) == ("data",)
     assert physical_spec(("batch", None), mesh) == P("data", None)
     assert physical_spec(("seeds", "batch"), mesh) == P(None, "data")
@@ -116,3 +116,13 @@ def test_constrain_identity_on_one_shard_serving_mesh():
         y = jax.jit(lambda v: constrain(v, "batch", None))(x)
         z = jax.jit(lambda v: constrain(v, "seeds", "batch"))(x)
     assert jnp.array_equal(y, x) and jnp.array_equal(z, x)
+
+
+def test_serving_mesh_refuses_more_shards_than_devices():
+    """A mesh engine never quietly replicates: asking for more shards than
+    the host has devices raises instead of capping the mesh."""
+    from repro.launch.mesh import make_serving_mesh
+    n = len(jax.devices())
+    assert make_serving_mesh(n).devices.size == n
+    with pytest.raises(ValueError, match="needs"):
+        make_serving_mesh(n + 1)
