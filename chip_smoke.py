@@ -24,8 +24,9 @@ One chip (no arguments), per precision f32, bf16, fxp16:
   the served heatmaps against it >= 0.95 (the worst one is printed).
 
 ``--chips 4``: only the mesh-sharded engine (``mesh:tpu-v5e:4``) served
-through the server, and the same requests on a one-device engine; the mesh
-must span 4 devices and the heatmaps must agree.
+through the server, and the same requests on a one-device engine, per
+precision f32, bf16, fxp16; the mesh must span 4 devices and the heatmaps
+must agree.
 
 Printed times are smoke timings from the host clock, not benchmark
 figures.  The last line of stdout is one JSON object::
@@ -244,6 +245,11 @@ def run_one_chip(params, cfg, xs):
 
 
 def run_four_chips(params, cfg, xs):
+    for precision in ("f32", "bf16", "fxp16"):
+        _four_chips_at(params, cfg, xs, precision)
+
+
+def _four_chips_at(params, cfg, xs, precision):
     import numpy as np
     from repro import engine as engine_lib
     from repro.serve import CNNAdapter, ExplanationServer
@@ -252,23 +258,25 @@ def run_four_chips(params, cfg, xs):
     for device in ("mesh:tpu-v5e:4", "tpu-v5e"):
         t0 = time.perf_counter()
         eng = engine_lib.build(engine_lib.EngineSpec(
-            model=model, method="saliency", device=device))
+            model=model, method="saliency", precision=precision,
+            device=device))
         if device.startswith("mesh:"):
             size = 0 if eng.mesh is None else eng.mesh.devices.size
             check(size == 4, f"mesh engine spans {size} devices")
             logits, _ = eng.forward(xs[:4 * MAX_BATCH])
             placed = len(logits.sharding.device_set)
-            log(f"{device}: mesh over {eng.mesh.devices.size} devices "
-                f"{eng.mesh.axis_names}, forward logits placed on "
-                f"{placed} devices")
+            log(f"{precision} {device}: mesh over {eng.mesh.devices.size} "
+                f"devices {eng.mesh.axis_names}, forward logits "
+                f"{logits.dtype} placed on {placed} devices")
             check(placed == 4, f"sharded logits on {placed} devices")
         server = ExplanationServer(CNNAdapter.from_engine(eng),
                                    max_batch=MAX_BATCH)
         predicts, explains = _session(server, xs, "m")
         hits = sum(r.cache_hit for r in explains.values())
         check(hits > 0, f"{device}: no explain hit the residual cache")
-        log(f"{device}: {len(predicts)} predicts + {len(explains)} explains, "
-            f"0 errors, cache hits {hits}/{len(explains)}; smoke timing "
+        log(f"{precision} {device}: {len(predicts)} predicts + "
+            f"{len(explains)} explains, 0 errors, cache hits "
+            f"{hits}/{len(explains)}; smoke timing "
             f"{time.perf_counter() - t0:.2f}s incl. compile")
         out[device] = (predicts, explains)
     (mp, me), (sp, se) = out["mesh:tpu-v5e:4"], out["tpu-v5e"]
@@ -281,12 +289,14 @@ def run_four_chips(params, cfg, xs):
         worst_h[m] = max(worst_h[m], float(np.abs(
             _panel_heatmaps(me[uid]) - _panel_heatmaps(se[uid])).max()))
         worst_l = max(worst_l, float(np.abs(
-            np.asarray(mp[uid].logits) - np.asarray(sp[uid].logits)).max()))
-    log(f"4-shard vs 1-device: logits max abs diff {worst_l:.3e}, "
-        f"heatmap max abs diff "
+            np.asarray(mp[uid].logits, np.float32)
+            - np.asarray(sp[uid].logits, np.float32)).max()))
+    log(f"{precision} 4-shard vs 1-device: logits max abs diff "
+        f"{worst_l:.3e}, heatmap max abs diff "
         + ", ".join(f"{m}={e:.3e}" for m, e in worst_h.items()))
     check(max(worst_h.values()) <= MESH_HEATMAP_TOL,
-          f"mesh heatmaps differ by more than {MESH_HEATMAP_TOL}: {worst_h}")
+          f"{precision} mesh heatmaps differ by more than "
+          f"{MESH_HEATMAP_TOL}: {worst_h}")
 
 
 def main() -> int:

@@ -72,7 +72,7 @@ class Engine:
         # Mesh-sharded build: a ``mesh:<profile>:<n>`` device compiles ONE
         # predict/explain pair that shard_maps the batch over the serving
         # mesh (see _shard_map below).  The plan above is
-        # already per-shard (plan_cnn splits batch/seeds across the mesh
+        # already per-shard (plan_cnn splits the batch across the mesh
         # before tiling); here the physical placement is resolved.  A host
         # with fewer devices than shards raises (make_serving_mesh).
         device = (spec.device if spec.device is not None
@@ -97,7 +97,7 @@ class Engine:
             fwd, bwd = model.pair(rules, spec.precision,
                                   plan=self._plan)
             if self._mesh is not None:
-                fwd = self._shard_batch(fwd)
+                fwd = self._shard_batch(fwd, residuals=True)
                 bwd = self._shard_pair_bwd(bwd)
             self._backend = ManualSeedBatchedBackward(fwd, bwd)
         else:
@@ -126,10 +126,11 @@ class Engine:
     # program on its slice of the batch (seeds replicated, masks/indices
     # sharded with their examples).  The batch pads up to a multiple of the
     # shard count and the outputs are sliced back, so any batch size runs.
-    # Packed uint8 residuals leave the shard_map as int32 words
-    # (:class:`_Words`): XLA:TPU corrupted [N, 32, 32, 4] uint8 masks when
-    # it resharded them (slicing the padded batch, the residual cache's
-    # per-example slices) on a v5e 2x2; int32 words reshard intact.
+    # The pair forward's packed uint8 residuals leave the shard_map as int32
+    # words (:class:`_Words`): XLA:TPU corrupted [N, 32, 32, 4] uint8 masks
+    # when it resharded them (slicing the padded batch, the residual cache's
+    # per-example slices) on a v5e 2x2; int32 words reshard intact.  Logits
+    # keep their dtype (bf16 under precision="bf16").
 
     def _shard_map(self, f, in_specs, out_specs):
         return jax.shard_map(f, mesh=self._mesh, in_specs=in_specs,
@@ -138,11 +139,17 @@ class Engine:
     def _padded(self, b: int) -> int:
         return -(-b // self._n_shards) * self._n_shards
 
-    def _shard_batch(self, f):
-        """``f(x [B, ...]) -> outputs [B, ...]`` (logits, or the pair
-        forward's ``(logits, residuals)``), batch-sharded."""
-        sharded = self._shard_map(lambda x: jax.tree.map(_Words.wrap, f(x)),
-                                  P("data"), P("data"))
+    def _shard_batch(self, f, *, residuals: bool = False):
+        """``f(x [B, ...]) -> logits [B, ...]``, batch-sharded; with
+        ``residuals`` f is a pair forward ``-> (logits, residuals)`` and the
+        residuals come back as :class:`_Words`."""
+        def local(x):
+            if not residuals:
+                return f(x)
+            logits, res = f(x)
+            return logits, jax.tree.map(_Words.wrap, res)
+
+        sharded = self._shard_map(local, P("data"), P("data"))
 
         def run(x):
             b = _batch_of(x)

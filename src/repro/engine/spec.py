@@ -280,8 +280,8 @@ class EngineSpec:
         every fused kernel executes block shapes fitted to its on-chip
         budget (the paper's per-FPGA-target resource model).  The
         ``"mesh:<profile>:<n>"`` form names a ``repro.plan.MeshProfile``
-        (N cores of ``<profile>``): the planner splits the batch/seeds
-        axes across the mesh before tiling the per-shard slice, and
+        (N cores of ``<profile>``): the planner splits the batch axis
+        across the mesh before tiling the per-shard slice, and
         ``build`` compiles ONE sharded predict/explain pair under the
         serving mesh (``Engine.mesh`` / ``Engine.n_shards``); on a
         1-shard mesh the engine is bitwise-identical to the single-core

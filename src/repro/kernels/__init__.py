@@ -76,6 +76,20 @@ def interpret_mode(interpret: Optional[bool] = None) -> bool:
     return bool(interpret)
 
 
+def compiler_params():
+    """Mosaic parameters of the planned kernels (conv, vmm).
+
+    The scoped-VMEM limit is the detected device profile's ``vmem_bytes``,
+    the budget the planner fits tiles into (``repro.plan.profiles``), so
+    the planner and the compiler work to one figure.  Called at trace
+    time: the profile is read from the backend, never at import.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.plan.profiles import detect
+    return pltpu.CompilerParams(vmem_limit_bytes=detect().vmem_bytes)
+
+
 _CARRIER = {jnp.dtype(jnp.bfloat16): jnp.dtype(jnp.float32),
             jnp.dtype(jnp.int16): jnp.dtype(jnp.int32)}
 

@@ -37,16 +37,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import (carrier, interpret_mode, mxu_precision,
-                           validate_bp_gates, widen)
-from repro.kernels.tiling import (CONV_VMEM_LIMIT_BYTES, SUBLANE, align_up,
-                                  batch_tiling, cout_tiling)
+from repro.kernels import (carrier, compiler_params, interpret_mode,
+                           mxu_precision, validate_bp_gates, widen)
+from repro.kernels.tiling import SUBLANE, align_up, batch_tiling, cout_tiling
 from repro.kernels.pool.pool import unpack_crumbs, unpool_store
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
 from repro.obs import profile as obs_profile
-
-
-CONV_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=CONV_VMEM_LIMIT_BYTES)
 
 
 def _im2col_dot(xpad, K: int, H: int, W: int, wmat):
@@ -108,7 +104,7 @@ def conv2d_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((bn, h, ww, tco), lambda b, c: (b, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((n_p, h, ww, cout_p), x.dtype),
-        compiler_params=CONV_PARAMS,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(xp, wp)
     return out[:n, ..., :cout]
@@ -240,7 +236,7 @@ def conv2d_bwd_fused_pallas(
         out_shape=jax.ShapeDtypeStruct((s, n, h, w_sp, cout_p), g.dtype),
         scratch_shapes=([pltpu.VMEM((s, h, w_sp, cp), carrier(g.dtype))]
                         if has_pool else []),
-        compiler_params=CONV_PARAMS,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*operands)
     out = out[..., :cout]

@@ -24,8 +24,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fixedpoint import WGT_FRAC, dot_i16, requantize
-from repro.kernels import interpret_mode, validate_bp_gates, widen
-from repro.kernels.conv2d.conv2d import CONV_PARAMS
+from repro.kernels import (compiler_params, interpret_mode,
+                           validate_bp_gates, widen)
 from repro.kernels.tiling import SUBLANE, align_up, cout_tiling
 from repro.kernels.pool.pool import unpack_crumbs, unpool_store
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
@@ -82,7 +82,7 @@ def conv2d_fxp_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, h, ww, tco), lambda b, c: (b, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((n, h, ww, cout_p), jnp.int16),
-        compiler_params=CONV_PARAMS,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(xp, wp)
     return out[..., :cout]
@@ -201,7 +201,7 @@ def conv2d_bwd_fused_fxp_pallas(
         out_shape=jax.ShapeDtypeStruct((s, n, h, w_sp, cout_p), jnp.int16),
         scratch_shapes=([pltpu.VMEM((s, h, w_sp, cp), jnp.int32)]
                         if has_pool else []),
-        compiler_params=CONV_PARAMS,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*operands)
     out = out[..., :cout]

@@ -29,12 +29,6 @@ LANE = 128
 CRUMBS_PER_BYTE = 4
 #: 1-bit ReLU-mask bits per packed byte.
 BITS_PER_BYTE = 8
-#: scoped VMEM a conv kernel may ask the compiler for.  Mosaic's default
-#: scoped limit (16 MiB) is below what a seed-batched conv backward at the
-#: paper's 32x32 maps needs once lanes pad to 128; a TPU v5e core has
-#: 128 MiB of VMEM.  The planner's budget (``repro.plan`` profiles) stays
-#: the smaller, portable figure.
-CONV_VMEM_LIMIT_BYTES = 96 << 20
 
 # Default tile policy — the ONE place the legacy hardcoded numbers live.
 DEFAULT_CO_TILE = 128           # conv Cout tile (lane width)

@@ -23,7 +23,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fixedpoint import WGT_FRAC, dot_i16, requantize
-from repro.kernels import interpret_mode, validate_bp_gates, widen
+from repro.kernels import (compiler_params, interpret_mode,
+                           validate_bp_gates, widen)
 from repro.kernels.tiling import vmm_tiling
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
 from repro.obs import profile as obs_profile
@@ -72,6 +73,7 @@ def vmm_fxp_pallas(x: jnp.ndarray, w: jnp.ndarray, *, shift: int = WGT_FRAC,
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int16),
         # i32 output-stationary accumulator, persists across the K grid axis
         scratch_shapes=[pltpu.VMEM((tm_, tn_), jnp.int32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(xp, wp)
     return out[:m, :n]
@@ -170,6 +172,7 @@ def vmm_bwd_fused_fxp_pallas(
         out_specs=pl.BlockSpec((1, mp, tn_), lambda si, j, st: (si, 0, j)),
         out_shape=jax.ShapeDtypeStruct((s, mp, np_), jnp.int16),
         scratch_shapes=[pltpu.VMEM((mp, tn_), jnp.int32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*operands)
     out = out[:, :m, :n]

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import interpret_mode, mxu_precision, validate_bp_gates
+from repro.kernels import (compiler_params, interpret_mode, mxu_precision,
+                           validate_bp_gates)
 from repro.kernels.tiling import vmm_tiling
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
 from repro.obs import profile as obs_profile
@@ -76,6 +77,7 @@ def vmm_pallas(x: jnp.ndarray, w: jnp.ndarray, *, tm: Optional[int] = None,
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         # f32 output-stationary accumulator, persists across the K grid axis
         scratch_shapes=[pltpu.VMEM((tm_, tn_), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(xp, wp)
     return out[:m, :n]
@@ -179,6 +181,7 @@ def vmm_bwd_fused_pallas(
         out_specs=pl.BlockSpec((1, mp, tn_), lambda si, j, st: (si, 0, j)),
         out_shape=jax.ShapeDtypeStruct((s, mp, np_), g.dtype),
         scratch_shapes=[pltpu.VMEM((mp, tn_), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*operands)
     out = out[:, :m, :n]

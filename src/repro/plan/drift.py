@@ -80,7 +80,7 @@ def drift_rows(cfg, plan: Optional[TilePlan] = None, *, device=None,
     for key, family, kw in cnn_kernel_shapes(cfg, batch, seeds):
         tile = plan.get(key) if plan is not None else None
         est_s = _footprint(family, kw, tile, precision,
-                           profile.mxu).est_time_s(profile)
+                           profile).est_time_s(profile)
         dims = tuple(int(v) for v in kw.values())
         measured, source = _measured_us(
             family, kw, dims, precision, tile, profile,

@@ -17,10 +17,23 @@ is a statement about the real kernel, not an idealization.
 
 dtype widths: f32 -> 4 B operands / f32 accumulator; bf16 -> 2 B / f32;
 fxp16 (true int16, paper §IV) -> 2 B / int32.
+
+On-chip layout: the conv families take a ``layout``.  ``None`` counts
+flat bytes (the edge targets' BRAM).  A ``(sublane, lane)`` pair models
+TPU VMEM, which stores an array in whole tiles: the last dim rounds up to
+lanes and the one before it to sublanes (a 32-channel map occupies 128
+lanes).  Pallas also double-buffers every pipelined in/out block there;
+Mosaic materializes the unpooled and gated gradient, and fxp16 kernels
+compute on the int32 carrier and split the im2col dot into int8 digit
+planes (``core.fixedpoint.dot_i16``).  Checked against the TPU compiler:
+``tests/test_tpu_compile.py`` compiles the paper CNN's conv kernels for a
+v5e under exactly the modelled bytes.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
+from typing import Optional, Sequence, Tuple
 
 from repro.kernels.tiling import (BITS_PER_BYTE, CRUMBS_PER_BYTE, SUBLANE,
                                   align_up, cout_tiling, vmm_tiling)
@@ -65,6 +78,35 @@ class Footprint:
         return max(compute, memory)
 
 
+def _vmem(shape: Sequence[int], elt: int,
+          layout: Optional[Tuple[int, int]]) -> int:
+    """On-chip bytes of an array of ``shape`` under ``layout`` (see the
+    module docstring): flat when None, else its last two dims padded to
+    whole (sublane, lane) tiles."""
+    dims = list(shape)
+    if layout is not None:
+        sub, lane = layout
+        dims[-1] = align_up(dims[-1], lane)
+        if len(dims) > 1:
+            dims[-2] = align_up(dims[-2], sub)
+    return math.prod(dims) * elt
+
+
+def _im2col_work(rows: int, depth: int, tco: int, precision: str,
+                 layout: Optional[Tuple[int, int]]) -> int:
+    """In-kernel values of the single-dot im2col: the [rows, depth] patch
+    matrix and the [rows, tco] accumulator.  On tiled VMEM an fxp16 kernel
+    builds the patches on the int32 carrier and adds ``dot_i16``'s two int8
+    digit planes and four int32 digit products."""
+    elt, acc = _elt(precision), ACC_BYTES[precision]
+    if layout is None or precision != "fxp16":
+        return (_vmem((rows, depth), elt, layout)
+                + _vmem((rows, tco), acc, layout))
+    return (_vmem((rows, depth), 4, layout)
+            + 2 * _vmem((rows, depth), 1, layout)
+            + 5 * _vmem((rows, tco), 4, layout))
+
+
 def _dot_util(sub_rows: int, depth: int, lanes: int, mxu: int) -> float:
     """MAC-array occupancy proxy of an [R, D] @ [D, L] tile dot."""
     return (min(1.0, sub_rows / mxu) * min(1.0, depth / mxu)
@@ -78,25 +120,31 @@ def _dot_util(sub_rows: int, depth: int, lanes: int, mxu: int) -> float:
 
 def conv2d_fwd_footprint(n: int, h: int, w: int, k: int, cin: int,
                          cout: int, co_tile: int, precision: str = "f32",
-                         mxu: int = 128) -> Footprint:
+                         mxu: int = 128,
+                         layout: Optional[Tuple[int, int]] = None
+                         ) -> Footprint:
     """One (batch, cout-tile) grid cell of :func:`conv2d_pallas`.
 
     VMEM: padded input block + weight block + the [H*W, K*K*Cin] im2col
     patch matrix gathered in VMEM + the f32/int32 accumulator + the output
     block.  HBM: the input block reloads once per cout tile.
     """
-    elt, acc = _elt(precision), ACC_BYTES[precision]
+    elt = _elt(precision)
     p = (k - 1) // 2
     cin_p = align_up(cin, SUBLANE)
     tco, cout_p = cout_tiling(cout, co_tile)
     x_blk = (h + 2 * p) * (w + 2 * p) * cin_p * elt
     w_blk = k * k * cin_p * tco * elt
-    patches = h * w * k * k * cin_p * elt
-    acc_blk = h * w * tco * acc
-    out_blk = h * w * tco * elt
     tiles = cout_p // tco
+    bufs = 1 if layout is None else 2
+    blocks = (_vmem((h + 2 * p, w + 2 * p, cin_p), elt, layout)
+              + _vmem((k * k, cin_p, tco), elt, layout)
+              + _vmem((h, w, tco), elt, layout))
+    work = _im2col_work(h * w, k * k * cin_p, tco, precision, layout)
+    if layout is not None and precision == "fxp16":
+        work += _vmem((h + 2 * p, w + 2 * p, cin_p), 4, layout)   # widened x
     return Footprint(
-        vmem_bytes=x_blk + w_blk + patches + acc_blk + out_blk,
+        vmem_bytes=bufs * blocks + work,
         hbm_bytes=n * tiles * (x_blk + w_blk) + n * h * w * cout_p * elt,
         flops=2 * n * h * w * k * k * cin_p * cout_p,
         mxu_util=_dot_util(h * w, k * k * cin_p, tco, mxu))
@@ -105,7 +153,9 @@ def conv2d_fwd_footprint(n: int, h: int, w: int, k: int, cin: int,
 def conv2d_bwd_footprint(s: int, n: int, hg: int, wg: int, k: int, c: int,
                          cout: int, co_tile: int, *, pooled: bool,
                          gated: bool = True, precision: str = "f32",
-                         mxu: int = 128) -> Footprint:
+                         mxu: int = 128,
+                         layout: Optional[Tuple[int, int]] = None
+                         ) -> Footprint:
     """One grid cell of the FUSED conv backward
     (:func:`conv2d_bwd_fused_pallas`): unpool + mask-gate prologues and the
     flipped-transpose single-dot BP in one call.
@@ -115,7 +165,7 @@ def conv2d_bwd_footprint(s: int, n: int, hg: int, wg: int, k: int, c: int,
     outgoing channels (the forward Cin).  ``hg/wg`` are the INCOMING
     gradient's spatial dims (post-pool when ``pooled``).
     """
-    elt, acc = _elt(precision), ACC_BYTES[precision]
+    elt = _elt(precision)
     p = (k - 1) // 2
     cp = align_up(c, SUBLANE)
     tco, cout_p = cout_tiling(cout, co_tile)
@@ -124,16 +174,35 @@ def conv2d_bwd_footprint(s: int, n: int, hg: int, wg: int, k: int, c: int,
     w_blk = k * k * cp * tco * elt
     idx_blk = hg * wg * cp // CRUMBS_PER_BYTE if pooled else 0
     mask_blk = h * w * cp // BITS_PER_BYTE if gated else 0
-    # in-kernel scratch: the halo-padded gradient + the im2col patch matrix
-    gp_blk = s * (h + 2 * p) * (w + 2 * p) * cp * elt
-    patches = s * h * w * k * k * cp * elt
-    acc_blk = s * h * w * tco * acc
-    out_blk = s * h * w * tco * elt
     tiles = cout_p // tco
     loads = g_blk + w_blk + idx_blk + mask_blk
+    bufs = 1 if layout is None else 2
+    blocks = (_vmem((s, hg, wg, cp), elt, layout)
+              + _vmem((k * k, cp, tco), elt, layout)
+              + _vmem((s, h, w, tco), elt, layout))
+    if pooled:
+        blocks += _vmem((hg, wg, cp // CRUMBS_PER_BYTE), 1, layout)
+    if gated:
+        blocks += _vmem((h, w, cp // BITS_PER_BYTE), 1, layout)
+    # in-kernel values: the halo-padded gradient and the im2col dot; tiled
+    # VMEM also holds the unpooled/gated gradient (fxp16: on the int32
+    # carrier), the 32-bit unpool scratch, and the unpacked indices and
+    # mask, whose [..., bytes, fields] int32 intermediates pad the short
+    # fields axis to a whole lane tile
+    val = 4 if layout is not None and precision == "fxp16" else elt
+    work = (_vmem((s, h + 2 * p, w + 2 * p, cp), val, layout)
+            + _im2col_work(s * h * w, k * k * cp, tco, precision, layout))
+    if layout is not None:
+        work += _vmem((s, h, w, cp), val, layout)
+        if pooled:
+            work += (_vmem((s, h, w, cp), 4, layout)
+                     + _vmem((hg, wg, cp // CRUMBS_PER_BYTE, CRUMBS_PER_BYTE),
+                             4, layout))
+        if gated:
+            work += _vmem((h, w, cp // BITS_PER_BYTE, BITS_PER_BYTE), 4,
+                          layout)
     return Footprint(
-        vmem_bytes=(g_blk + w_blk + idx_blk + mask_blk + gp_blk + patches
-                    + acc_blk + out_blk),
+        vmem_bytes=bufs * blocks + work,
         hbm_bytes=n * tiles * loads + s * n * h * w * cout_p * elt,
         flops=2 * s * n * h * w * k * k * cp * cout_p,
         mxu_util=_dot_util(s * h * w, k * k * cp, tco, mxu))

@@ -244,18 +244,22 @@ def measure_kernel(family: str, kw: Dict[str, Any], tile,
 
 
 def _footprint(family: str, kw: Dict[str, Any], tile, precision: str,
-               mxu: int) -> cost.Footprint:
+               profile) -> cost.Footprint:
+    """Footprint of one launch on ``profile`` (its MXU edge, and for the
+    conv families its on-chip layout: tiled VMEM or flat RAM)."""
+    mxu = profile.mxu
+    layout = (profile.sublane, profile.lane) if profile.tiled_vmem else None
     if family == "conv2d_fwd":
         return cost.conv2d_fwd_footprint(
             kw["n"], kw["h"], kw["w"], kw["k"], kw["cin"], kw["cout"],
             tile.co_tile if tile is not None else None,
-            precision=precision, mxu=mxu)
+            precision=precision, mxu=mxu, layout=layout)
     if family == "conv2d_bwd":
         return cost.conv2d_bwd_footprint(
             kw["s"], kw["n"], kw["hg"], kw["wg"], kw["k"], kw["c"],
             kw["cout"], tile.co_tile if tile is not None else None,
             pooled=kw["pooled"], gated=kw.get("gated", True),
-            precision=precision, mxu=mxu)
+            precision=precision, mxu=mxu, layout=layout)
     if family == "vmm_fwd":
         t = tile or VmmTile(None, None, None)
         return cost.vmm_fwd_footprint(kw["m"], kw["k"], kw["n"],
@@ -319,7 +323,7 @@ def _plan_family(family: str, kw: Dict[str, Any], profile, precision: str,
     (optionally) measure.  Returns ``(tile, measured_us | None)``."""
     scored = []
     for tile in _candidates(family, kw):
-        fp = _footprint(family, kw, tile, precision, profile.mxu)
+        fp = _footprint(family, kw, tile, precision, profile)
         if fp.fits(profile):
             scored.append((fp.est_time_s(profile), -_tile_volume(tile), tile))
     if not scored:
@@ -362,21 +366,16 @@ def plan_vmm(m: int, k: int, n: int, *, profile=None,
 
 def shard_batch_seeds(batch: int, seeds: int,
                       n_shards: int) -> Tuple[int, int]:
-    """Per-shard ``(batch, seeds)`` once a mesh splits the two data axes.
+    """Per-shard ``(batch, seeds)`` of a mesh-sharded engine launch.
 
-    The batch axis is split first (it is the serving throughput axis);
-    shards left over once every example has its own core split the seeds
-    axis (the top-K panel fan-out).  Sizes are ceil-divided — a shard may
-    run a padded remainder slice, never a larger one — so the per-shard
-    shapes the planner tiles against are the worst-case shard's.
+    The engine shard_maps the batch axis only: it pads the batch to a
+    multiple of ``n_shards``, so every shard runs ``ceil(batch / n)``
+    examples, and every shard runs the whole seeds axis (the top-K panel
+    rides along with its examples).
     """
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
-    batch_ways = min(n_shards, max(batch, 1))
-    local_batch = -(-max(batch, 1) // batch_ways)
-    seed_ways = min(n_shards // batch_ways, max(seeds, 1))
-    local_seeds = -(-max(seeds, 1) // max(seed_ways, 1))
-    return local_batch, local_seeds
+    return -(-max(batch, 1) // n_shards), max(seeds, 1)
 
 
 def cnn_kernel_shapes(cfg, batch: int = 1, seeds: int = 1):
@@ -420,7 +419,7 @@ def plan_cnn(cfg, device=None, precision: str = "f32", *, batch: int = 1,
     knob but are still audited against the budget.
 
     A :class:`~repro.plan.profiles.MeshProfile` device splits the batch
-    and seeds axes across its shards FIRST (:func:`shard_batch_seeds`) and
+    across its shards FIRST (:func:`shard_batch_seeds`) and
     tiles the per-shard slice against the per-core budget — the paper's
     fit-the-envelope discipline applied per core of an N-core mesh.  On a
     1-shard mesh the local shapes equal the global ones, so the plan is
@@ -436,7 +435,7 @@ def plan_cnn(cfg, device=None, precision: str = "f32", *, batch: int = 1,
     entries = []
     for key, family, kw in cnn_kernel_shapes(cfg, batch, seeds):
         if family == "pool":
-            fp = _footprint(family, kw, None, precision, profile.mxu)
+            fp = _footprint(family, kw, None, precision, profile)
             if not fp.fits(profile):
                 raise InfeasiblePlanError(
                     f"{key} ({family} {kw}) needs {fp.vmem_bytes} B on-chip "
@@ -480,7 +479,7 @@ def cnn_plan_footprints(cfg, plan: Optional[TilePlan], *,
     out = {}
     for key, family, kw in cnn_kernel_shapes(cfg, batch, seeds):
         tile = plan.get(key) if plan is not None else None
-        out[key] = _footprint(family, kw, tile, precision, profile.mxu)
+        out[key] = _footprint(family, kw, tile, precision, profile)
     return out
 
 
@@ -569,5 +568,5 @@ def lm_plan_footprints(cfg, plan: Optional[TilePlan], *,
     out = {}
     for key, family, kw in lm_kernel_shapes(cfg, batch, seq):
         tile = plan.get(key) if plan is not None else None
-        out[key] = _footprint(family, kw, tile, precision, profile.mxu)
+        out[key] = _footprint(family, kw, tile, precision, profile)
     return out

@@ -13,7 +13,11 @@ Profiles:
     name ``detected``, so a plan made on the CPU is the plan the chip runs.
   * ``tpu-v5e``  — one TPU v5e chip: published peaks (Google Cloud, "TPU
     v5e" system architecture page: 197 TFLOP/s bf16, 819 GB/s HBM) and a
-    16 MiB planning budget, Mosaic's default scoped-VMEM limit.
+    96 MiB budget of the core's 128 MiB VMEM.  The budget is also the
+    scoped-VMEM limit every planned kernel asks the compiler for
+    (``repro.kernels.compiler_params``), so a tile the planner fits is a
+    tile the compiler accepts.  VMEM is tiled (``tiled_vmem``): the
+    footprint model pads arrays to whole (sublane, lane) tiles.
   * ``edge-large`` / ``edge-small`` / ``edge-tiny`` — constrained 4/2/1 MB
     on-chip budgets mirroring the paper's edge-FPGA deployment points
     (large/mid ZU+ class parts down to a small Artix-class part), with
@@ -21,8 +25,8 @@ Profiles:
     planner must split work the default profile keeps whole.
   * ``mesh:<profile>:<n>`` — a :class:`MeshProfile`: N cores of
     ``<profile>``, each with that profile's per-core VMEM/bandwidth/MXU
-    envelope.  The planner splits the batch and seeds axes across the
-    cores FIRST and then tiles the per-core slice against the per-core
+    envelope.  The planner splits the batch axis across the cores
+    FIRST and then tiles the per-core slice against the per-core
     budget, so one mesh-sharded launch obeys the same resource discipline
     as N independent single-core launches.
 """
@@ -54,6 +58,11 @@ class DeviceProfile:
     hbm_gbps: float = 100.0
     #: peak MAC throughput at full utilization (TFLOP/s).
     mxu_tflops: float = 10.0
+    #: on-chip memory holds arrays in (sublane, lane) tiles, as TPU VMEM
+    #: does: the footprint model pads an array's last two dims to whole
+    #: tiles and double-buffers pipelined blocks.  False models flat
+    #: on-chip RAM (the edge targets' BRAM).
+    tiled_vmem: bool = False
 
     def __post_init__(self):
         if self.vmem_bytes <= 0:
@@ -70,7 +79,7 @@ class MeshProfile(DeviceProfile):
     geometry) are PER CORE, so the cost model's ``Footprint.fits`` check
     is unchanged: a kernel invocation must fit one core's budget — the
     mesh buys parallel shards, never a bigger working set.  ``n_shards``
-    is the mesh extent the planner splits the batch/seeds axes over and
+    is the mesh extent the planner splits the batch axis over and
     the occupancy target the serve batcher fills toward
     (``max_batch * n_shards`` seats per launch).
     """
@@ -89,7 +98,7 @@ class MeshProfile(DeviceProfile):
             name=self.name.split(":")[1] if ":" in self.name else self.name,
             vmem_bytes=self.vmem_bytes, sublane=self.sublane,
             lane=self.lane, mxu=self.mxu, hbm_gbps=self.hbm_gbps,
-            mxu_tflops=self.mxu_tflops)
+            mxu_tflops=self.mxu_tflops, tiled_vmem=self.tiled_vmem)
 
 
 def mesh_profile(core, n_shards: int) -> MeshProfile:
@@ -103,14 +112,14 @@ def mesh_profile(core, n_shards: int) -> MeshProfile:
         name=f"mesh:{base.name}:{int(n_shards)}",
         vmem_bytes=base.vmem_bytes, sublane=base.sublane, lane=base.lane,
         mxu=base.mxu, hbm_gbps=base.hbm_gbps, mxu_tflops=base.mxu_tflops,
-        n_shards=int(n_shards))
+        tiled_vmem=base.tiled_vmem, n_shards=int(n_shards))
 
 
 PROFILES: Dict[str, DeviceProfile] = {
     p.name: p for p in (
         # Google Cloud, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s.
-        DeviceProfile("tpu-v5e", vmem_bytes=16 * MB, mxu=128,
-                      hbm_gbps=819.0, mxu_tflops=197.0),
+        DeviceProfile("tpu-v5e", vmem_bytes=96 * MB, mxu=128,
+                      hbm_gbps=819.0, mxu_tflops=197.0, tiled_vmem=True),
         # Paper-style edge targets: small on-chip budgets, narrow MAC
         # arrays, DDR-class bandwidth.
         DeviceProfile("edge-large", vmem_bytes=4 * MB, mxu=64,

@@ -522,6 +522,35 @@ def test_mesh_residuals_reshard_as_words_and_replay(setup):
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("precision", ["f32", "bf16", "fxp16"])
+def test_four_shard_mesh_engine_matches_one_device_per_precision(setup,
+                                                                 precision):
+    """Every precision runs sharded: predict, forward (logits keep their
+    dtype — bf16 stays an array, only residuals travel as words), replay
+    of a top-2 panel, and explain all match the one-device engine."""
+    params, x = setup
+    e0 = build(spec_for(params, method="guided", precision=precision,
+                        device="edge-small"))
+    e4 = build(spec_for(params, method="guided", precision=precision,
+                        device="mesh:edge-small:4"))
+
+    def same(a, b):
+        assert type(a) is type(b) and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=1e-6)
+
+    same(e4.predict(x), e0.predict(x))
+    logits0, res0 = e0.forward(x)
+    logits4, res4 = e4.forward(x)
+    same(logits4, logits0)
+    seeds = jax.nn.one_hot(
+        jnp.argsort(logits0.astype(jnp.float32), -1)[:, ::-1][:, :2].T,
+        CFG.num_classes, dtype=logits0.dtype)
+    same(e4.replay(res4, seeds), e0.replay(res0, seeds))
+    for got, want in zip(e4.explain(x), e0.explain(x)):
+        same(got, want)
+
+
 @pytest.mark.parametrize("dtype,shape", [
     (jnp.uint8, (3, 8, 8, 4)), (jnp.uint8, (2, 5)), (jnp.int16, (3, 7)),
     (jnp.bfloat16, (2, 3, 3))])

@@ -71,6 +71,33 @@ def test_vmm_footprint_precision_widths():
     assert fxp.hbm_bytes < f32.hbm_bytes
 
 
+def test_tiled_vmem_pads_lanes_and_flat_profiles_do_not():
+    """On the v5e profile a conv footprint counts whole (8, 128) VMEM
+    tiles — a 32-channel map costs four times its flat bytes — and the
+    edge profiles keep the flat on-chip accounting; HBM traffic is the
+    same either way."""
+    from repro.plan import model as cost
+    assert get_profile("tpu-v5e").tiled_vmem and DETECTED.tiled_vmem
+    assert not EDGE.tiled_vmem
+    assert get_profile("mesh:tpu-v5e:4").tiled_vmem
+    assert (cost._vmem((1024, 32), 4, (8, 128))
+            == 4 * cost._vmem((1024, 32), 4, None))
+    flat = conv2d_fwd_footprint(1, 32, 32, 3, 32, 32, 32)
+    tiled = conv2d_fwd_footprint(1, 32, 32, 3, 32, 32, 32, layout=(8, 128))
+    assert tiled.vmem_bytes > 2 * flat.vmem_bytes
+    assert tiled.hbm_bytes == flat.hbm_bytes
+    on_v5e = cnn_plan_footprints(PAPER_CFG, None, seeds=3, profile="tpu-v5e")
+    on_edge = cnn_plan_footprints(PAPER_CFG, None, seeds=3, profile=EDGE)
+    assert on_v5e["conv1.bwd"].vmem_bytes > on_edge["conv1.bwd"].vmem_bytes
+
+
+def test_kernel_vmem_limit_is_the_profile_budget():
+    """The planned kernels ask the compiler for exactly the budget the
+    planner fits tiles into: the detected profile's ``vmem_bytes``."""
+    from repro.kernels import compiler_params
+    assert compiler_params().vmem_limit_bytes == DETECTED.vmem_bytes
+
+
 def test_footprint_fits_is_budget_comparison():
     fp = vmm_fwd_footprint(8, 4096, 128, 8, 4096, 128)
     assert fp.fits(DETECTED) and not fp.fits(get_profile("edge-tiny"))
@@ -446,9 +473,12 @@ def test_mesh_profile_rejects_malformed_names():
 
 
 def test_shard_batch_seeds_batch_first_then_seeds():
+    """The batch splits (ceil-divided, as the engine pads it); every shard
+    runs the whole seeds axis, as the shard_mapped engine does."""
     from repro.plan import shard_batch_seeds
     assert shard_batch_seeds(8, 16, 4) == (2, 16)   # batch covers the mesh
-    assert shard_batch_seeds(2, 16, 4) == (1, 8)    # leftover shards -> seeds
+    assert shard_batch_seeds(2, 16, 4) == (1, 16)   # seeds never split
+    assert shard_batch_seeds(1, 3, 4) == (1, 3)     # top-3 panel, batch 1
     assert shard_batch_seeds(1, 1, 4) == (1, 1)     # nothing left to split
     assert shard_batch_seeds(8, 16, 1) == (8, 16)   # single core: identity
     assert shard_batch_seeds(3, 1, 2) == (2, 1)     # ceil remainder slice

@@ -96,6 +96,50 @@ def test_conv_bwd_fused_with_pool_and_mask_compiles(one_chip, compiled,
 
 
 @pytest.mark.parametrize("precision", ["f32", "bf16", "fxp16"])
+def test_conv_kernels_compile_within_planned_footprint(one_chip, compiled,
+                                                       monkeypatch,
+                                                       precision):
+    """The planner's tiled-VMEM footprint is enough for the compiler: every
+    conv launch of the paper CNN (one example per grid cell, a top-3
+    panel) compiles with its scoped-VMEM limit set to exactly the bytes
+    ``cnn_plan_footprints`` models for it on the v5e profile."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.plan import cnn_kernel_shapes, cnn_plan_footprints, plan_cnn
+    cfg = cnn.CNNConfig()
+    plan = plan_cnn(cfg, device="tpu-v5e", precision=precision, seeds=S)
+    fps = cnn_plan_footprints(cfg, plan, precision=precision, seeds=S)
+    fxp = precision == "fxp16"
+    conv = _mod("conv2d.fxp" if fxp else "conv2d.conv2d")
+    fwd = conv.conv2d_fxp_pallas if fxp else conv.conv2d_pallas
+    bwd = (conv.conv2d_bwd_fused_fxp_pallas if fxp
+           else conv.conv2d_bwd_fused_pallas)
+    dt = DTYPE[precision]
+    for key, family, kw in cnn_kernel_shapes(cfg, batch=1, seeds=S):
+        limit = fps[key].vmem_bytes
+        monkeypatch.setattr(conv, "compiler_params", lambda limit=limit:
+                            pltpu.CompilerParams(vmem_limit_bytes=limit))
+        co_tile = getattr(plan.get(key), "co_tile", None)
+        if family == "conv2d_fwd":
+            compile_for(one_chip,
+                        lambda x, w: fwd(x, w, co_tile=co_tile),
+                        ((1, kw["h"], kw["w"], kw["cin"]), dt),
+                        ((kw["k"], kw["k"], kw["cin"], kw["cout"]), dt))
+        elif family == "conv2d_bwd":
+            hg, wg, c, pooled = kw["hg"], kw["wg"], kw["c"], kw["pooled"]
+            h, w = (2 * hg, 2 * wg) if pooled else (hg, wg)
+
+            def step(g, wt, idx, mask, pooled=pooled):
+                return bwd(g, wt, pool_idx=idx if pooled else None,
+                           relu_mask=mask, method="guided", co_tile=co_tile)
+
+            compile_for(one_chip, step, ((S, 1, hg, wg, c), dt),
+                        ((kw["k"], kw["k"], c, kw["cout"]), dt),
+                        ((1, hg, wg, -(-c // 4)), jnp.uint8),
+                        ((1, h, w, -(-c // 8)), jnp.uint8))
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16", "fxp16"])
 def test_maxpool_compiles(one_chip, compiled, precision):
     compile_for(one_chip, _mod("pool.pool").maxpool_fwd_pallas,
                 ((N, 32, 32, 32), DTYPE[precision]))
