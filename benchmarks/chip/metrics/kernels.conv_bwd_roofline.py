@@ -1,0 +1,33 @@
+"""Kernels (``kernels/conv2d``, f32 and fxp): the fused conv backward steps'
+share of their roofline, in percent: the least time ``flops.py`` and the
+peaks allow for every conv layer of every replay launch (all its seeds),
+over the summed device time of the ops that ran those kernels."""
+
+#: The trace names an op by its HLO instruction only; the Pallas kernel's
+#: function name is not in it.  A fused conv backward step is the one Mosaic
+#: custom call with a rank-5 result: [seeds, batch, H, W, Cin].
+PATTERNS = (r"= \w+\[\d+(?:,\d+){4}\]\{[^}]*\} custom-call\(.*"
+            r"custom_call_target=\"tpu_custom_call\"",)
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    dev_s = ctx.tracing.family_seconds(ctx.trace, PATTERNS)
+    if dev_s <= 0:
+        return None
+    f = ctx.flops
+    need = 0.0
+    for lc in ctx.launches:
+        if lc.program != "replay":
+            continue
+        rows = ctx.rows_per_shard(lc.rows)
+        for lyr in f.layers(ctx.model):
+            if lyr.kind == "conv":
+                w = f.conv_backward_launch(lyr, rows, lc.seeds, ctx.precision,
+                                           lc.method)
+                need += ctx.shards * f.roofline_s(
+                    w["flops"], w["bytes"], ctx.bf16_peak, ctx.hbm_bw)
+    if need <= 0:
+        return None
+    return 100.0 * need / dev_s
