@@ -42,7 +42,6 @@ from repro.kernels import (carrier, compiler_params, interpret_mode,
 from repro.kernels.tiling import SUBLANE, align_up, batch_tiling, cout_tiling
 from repro.kernels.pool.pool import unpack_crumbs, unpool_store
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
-from repro.obs import profile as obs_profile
 
 
 def _im2col_dot(xpad, K: int, H: int, W: int, wmat):
@@ -64,7 +63,6 @@ def _conv_kernel(x_ref, w_ref, o_ref, *, K: int, H: int, W: int):
     o_ref[...] = _im2col_dot(x_ref[...], K, H, W, wmat).astype(o_ref.dtype)
 
 
-@obs_profile.instrument("conv2d_fwd")
 def conv2d_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
                   co_tile: Optional[int] = None,
                   bn: Optional[int] = None,
@@ -105,6 +103,7 @@ def conv2d_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bn, h, ww, tco), lambda b, c: (b, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((n_p, h, ww, cout_p), x.dtype),
         compiler_params=compiler_params(),
+        name="conv2d_fwd",
         interpret=interpret,
     )(xp, wp)
     return out[:n, ..., :cout]
@@ -149,7 +148,6 @@ def _conv_bwd_fused_kernel(*refs, K: int, H: int, W: int, method: str,
     o_ref[...] = out.reshape(s, 1, H, W, tco).astype(o_ref.dtype)
 
 
-@obs_profile.instrument("conv2d_bwd")
 def conv2d_bwd_fused_pallas(
         g: jnp.ndarray, wt: jnp.ndarray, *,
         pool_idx: Optional[jnp.ndarray] = None,
@@ -237,6 +235,7 @@ def conv2d_bwd_fused_pallas(
         scratch_shapes=([pltpu.VMEM((s, h, w_sp, cp), carrier(g.dtype))]
                         if has_pool else []),
         compiler_params=compiler_params(),
+        name="conv2d_bwd",
         interpret=interpret,
     )(*operands)
     out = out[..., :cout]

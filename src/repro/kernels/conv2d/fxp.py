@@ -29,7 +29,6 @@ from repro.kernels import (compiler_params, interpret_mode,
 from repro.kernels.tiling import SUBLANE, align_up, cout_tiling
 from repro.kernels.pool.pool import unpack_crumbs, unpool_store
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
-from repro.obs import profile as obs_profile
 
 
 def _im2col_dot_i32(xpad, K: int, H: int, W: int, wmat):
@@ -51,7 +50,6 @@ def _conv_fxp_kernel(x_ref, w_ref, o_ref, *, K: int, H: int, W: int,
     o_ref[...] = requantize(acc, shift)
 
 
-@obs_profile.instrument("conv2d_fwd")
 def conv2d_fxp_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
                       shift: int = WGT_FRAC, co_tile: Optional[int] = None,
                       interpret: Optional[bool] = None) -> jnp.ndarray:
@@ -83,6 +81,7 @@ def conv2d_fxp_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, h, ww, tco), lambda b, c: (b, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((n, h, ww, cout_p), jnp.int16),
         compiler_params=compiler_params(),
+        name="conv2d_fwd",
         interpret=interpret,
     )(xp, wp)
     return out[..., :cout]
@@ -129,7 +128,6 @@ def _conv_bwd_fused_fxp_kernel(*refs, K: int, H: int, W: int, method: str,
     o_ref[...] = out.reshape(s, 1, H, W, tco)
 
 
-@obs_profile.instrument("conv2d_bwd")
 def conv2d_bwd_fused_fxp_pallas(
         g: jnp.ndarray, wt: jnp.ndarray, *,
         pool_idx: Optional[jnp.ndarray] = None,
@@ -202,6 +200,7 @@ def conv2d_bwd_fused_fxp_pallas(
         scratch_shapes=([pltpu.VMEM((s, h, w_sp, cp), jnp.int32)]
                         if has_pool else []),
         compiler_params=compiler_params(),
+        name="conv2d_bwd",
         interpret=interpret,
     )(*operands)
     out = out[..., :cout]

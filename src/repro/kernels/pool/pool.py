@@ -28,7 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import carrier, interpret_mode, widen
 from repro.kernels.relu_mask.relu_mask import pack_fields
 from repro.kernels.tiling import CRUMBS_PER_BYTE, align_up, crumb_bytes
-from repro.obs import profile as obs_profile
 
 #: window positions (di, dj) in 2-bit index order
 WINDOW = ((0, 0), (0, 1), (1, 0), (1, 1))
@@ -91,7 +90,6 @@ def _unpool_bwd_kernel(i_ref, g_ref, o_ref, u_ref):
     o_ref[0] = u_ref[...].astype(o_ref.dtype)
 
 
-@obs_profile.instrument("pool")
 def maxpool_fwd_pallas(x: jnp.ndarray, *, interpret: Optional[bool] = None):
     """x: [N, H, W, C] (H, W even; C padded to 4) -> (pooled, packed idx)."""
     interpret = interpret_mode(interpret)
@@ -108,6 +106,7 @@ def maxpool_fwd_pallas(x: jnp.ndarray, *, interpret: Optional[bool] = None):
                    pl.BlockSpec((1, h // 2, w // 2, cp // 4), lambda b: (b, 0, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((n, h // 2, w // 2, cp), x.dtype),
                    jax.ShapeDtypeStruct((n, h // 2, w // 2, cp // 4), jnp.uint8)],
+        name="maxpool_fwd",
         interpret=interpret,
     )(xp)
     return y[..., :c], idx[..., :crumb_bytes(c)]
@@ -129,6 +128,7 @@ def unpool_bwd_pallas(packed: jnp.ndarray, g: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, 2 * hp, 2 * wp, cp), lambda b: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 2 * hp, 2 * wp, cp), g.dtype),
         scratch_shapes=[pltpu.VMEM((2 * hp, 2 * wp, cp), carrier(g.dtype))],
+        name="maxpool_bwd",
         interpret=interpret,
     )(ip, gp)
     return out[..., :c]

@@ -122,6 +122,7 @@ def relu_fwd_pallas(x2d: jnp.ndarray, *, tr: Optional[int] = None,
                    pl.BlockSpec((tr, cp // 8), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rp, cp), x2d.dtype),
                    jax.ShapeDtypeStruct((rp, cp // 8), jnp.uint8)],
+        name="relu_mask_fwd",
         interpret=interpret,
     )(xp)
     return y[:r, :c], m[:r, :mask_bytes(c)]
@@ -143,6 +144,7 @@ def relu_bwd_pallas(packed: jnp.ndarray, g2d: jnp.ndarray, method: str, *,
                   pl.BlockSpec((tr, cp), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tr, cp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, cp), g2d.dtype),
+        name="relu_mask_bwd",
         interpret=interpret,
     )(mp, gp)
     return out[:r, :c]

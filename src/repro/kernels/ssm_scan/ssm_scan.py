@@ -92,6 +92,7 @@ def selective_scan_pallas(dt, x, bmat, cmat, a, h0, *, d_tile: int = 256,
             jax.ShapeDtypeStruct((b, d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dt_t, n), jnp.float32)],
+        name="ssm_scan",
         interpret=interpret,
     )(dt.astype(jnp.float32), x, bmat.astype(jnp.float32),
       cmat.astype(jnp.float32), a, h0)

@@ -27,7 +27,6 @@ from repro.kernels import (compiler_params, interpret_mode,
                            validate_bp_gates, widen)
 from repro.kernels.tiling import vmm_tiling
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
-from repro.obs import profile as obs_profile
 
 
 def _mm_fxp_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int, shift: int):
@@ -42,7 +41,6 @@ def _mm_fxp_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int, shift: int):
         o_ref[...] = requantize(acc_ref[...], shift)
 
 
-@obs_profile.instrument("vmm_fwd")
 def vmm_fxp_pallas(x: jnp.ndarray, w: jnp.ndarray, *, shift: int = WGT_FRAC,
                    tm: Optional[int] = None, tk: Optional[int] = None,
                    tn: Optional[int] = None,
@@ -74,6 +72,7 @@ def vmm_fxp_pallas(x: jnp.ndarray, w: jnp.ndarray, *, shift: int = WGT_FRAC,
         # i32 output-stationary accumulator, persists across the K grid axis
         scratch_shapes=[pltpu.VMEM((tm_, tn_), jnp.int32)],
         compiler_params=compiler_params(),
+        name="vmm_fwd",
         interpret=interpret,
     )(xp, wp)
     return out[:m, :n]
@@ -112,7 +111,6 @@ def _mm_bwd_fused_fxp_kernel(*refs, k_steps: int, shift: int, method: str,
         o_ref[0] = out
 
 
-@obs_profile.instrument("vmm_bwd")
 def vmm_bwd_fused_fxp_pallas(
         g: jnp.ndarray, w: jnp.ndarray, *,
         relu_mask: Optional[jnp.ndarray] = None,
@@ -173,6 +171,7 @@ def vmm_bwd_fused_fxp_pallas(
         out_shape=jax.ShapeDtypeStruct((s, mp, np_), jnp.int16),
         scratch_shapes=[pltpu.VMEM((mp, tn_), jnp.int32)],
         compiler_params=compiler_params(),
+        name="vmm_bwd",
         interpret=interpret,
     )(*operands)
     out = out[:, :m, :n]

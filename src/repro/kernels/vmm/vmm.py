@@ -30,7 +30,6 @@ from repro.kernels import (compiler_params, interpret_mode, mxu_precision,
                            validate_bp_gates)
 from repro.kernels.tiling import vmm_tiling
 from repro.kernels.relu_mask.relu_mask import gate_gradient, unpack_bits
-from repro.obs import profile as obs_profile
 
 
 def _mm_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
@@ -47,7 +46,6 @@ def _mm_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-@obs_profile.instrument("vmm_fwd")
 def vmm_pallas(x: jnp.ndarray, w: jnp.ndarray, *, tm: Optional[int] = None,
                tk: Optional[int] = None, tn: Optional[int] = None,
                interpret: Optional[bool] = None) -> jnp.ndarray:
@@ -78,6 +76,7 @@ def vmm_pallas(x: jnp.ndarray, w: jnp.ndarray, *, tm: Optional[int] = None,
         # f32 output-stationary accumulator, persists across the K grid axis
         scratch_shapes=[pltpu.VMEM((tm_, tn_), jnp.float32)],
         compiler_params=compiler_params(),
+        name="vmm_fwd",
         interpret=interpret,
     )(xp, wp)
     return out[:m, :n]
@@ -116,7 +115,6 @@ def _mm_bwd_fused_kernel(*refs, k_steps: int, method: str, gate_in: bool,
         o_ref[0] = out.astype(o_ref.dtype)
 
 
-@obs_profile.instrument("vmm_bwd")
 def vmm_bwd_fused_pallas(
         g: jnp.ndarray, w: jnp.ndarray, *,
         relu_mask: Optional[jnp.ndarray] = None,
@@ -182,6 +180,7 @@ def vmm_bwd_fused_pallas(
         out_shape=jax.ShapeDtypeStruct((s, mp, np_), g.dtype),
         scratch_shapes=[pltpu.VMEM((mp, tn_), jnp.float32)],
         compiler_params=compiler_params(),
+        name="vmm_bwd",
         interpret=interpret,
     )(*operands)
     out = out[:, :m, :n]

@@ -34,7 +34,6 @@ from repro import engine as engine_lib
 from repro.launch import compile_cache, steps as steps_lib
 from repro.models import cnn as cnn_lib, transformer as tf
 from repro.obs import Tracer, dumps_strict, snapshot as obs_snapshot
-from repro.obs import profile as obs_profile
 from repro.serve import (AdmissionConfig, CNNAdapter, DegradePolicy,
                          ExplanationServer, Request, ShedError, registry)
 
@@ -217,7 +216,6 @@ def run_cnn(args) -> None:
                                 if args.deadline_ms is not None else None),
             degrade=degrade)
     tracer = Tracer() if args.trace_out else None
-    profiler = obs_profile.enable() if args.profile_kernels else None
     # perturbation fan-out knob: lime/rise sample counts ride method_opts
     # (occlusion's fan-out is geometric — window/stride opts instead)
     method_opts = {}
@@ -274,16 +272,6 @@ def run_cnn(args) -> None:
     if args.metrics:
         print("[serve/cnn] unified metrics snapshot:")
         print(dumps_strict(obs_snapshot(), indent=2))
-    if profiler is not None:
-        from repro.plan.drift import drift_rows, format_drift, write_drift
-        obs_profile.disable()
-        print("[serve/cnn] cost-model drift (eager calibration, "
-              f"{args.precision}):")
-        rows = drift_rows(cfg, eng.plan, device=args.device_profile,
-                          precision=args.precision, profiler=profiler,
-                          measure=True)
-        print(format_drift(rows))
-        print(f"[serve/cnn] drift table -> {write_drift(rows)}")
     check_outcome("cnn", responses, sheds, admission)
 
 
@@ -332,7 +320,7 @@ def main():
     ap.add_argument("--autotune", action="store_true",
                     help="refine the tile plan by measured timings "
                          "(persisted in the repro.plan tuning cache)")
-    # observability (cnn workload): all three are opt-in; the server runs
+    # observability (cnn workload): both are opt-in; the server runs
     # on no-op singletons otherwise (zero-cost guarantee)
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write a Perfetto-loadable Chrome trace-event "
@@ -341,10 +329,6 @@ def main():
     ap.add_argument("--metrics", action="store_true",
                     help="print the unified repro.obs metrics snapshot "
                          "(serve + plan-cache + engine-cache series)")
-    ap.add_argument("--profile-kernels", action="store_true",
-                    help="time eager kernel launches and print/persist "
-                         "the cost-model drift table (measured vs "
-                         "Footprint.est_time_s)")
     args = ap.parse_args()
     compile_cache.enable()
 

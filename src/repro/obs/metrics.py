@@ -81,12 +81,6 @@ ENGINE_BUILDS = _R.counter(
     "engine build-cache outcomes (build/hit/evict)",
     ("outcome",))
 
-# --- kernels (opt-in profiler; see repro.obs.profile) ----------------------
-KERNEL_SECONDS = _R.histogram(
-    "kernel_launch_seconds",
-    "fenced wall time of eager Pallas wrapper launches",
-    ("family", "shape", "precision"))
-
 # seed the series acceptance cares about, so a fresh snapshot names them
 for _reason in ("queue_full", "rate_limit", "deadline", "expired"):
     SERVE_SHEDS.inc(0, reason=_reason)

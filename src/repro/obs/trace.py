@@ -7,12 +7,23 @@ expired / errored paths.  :meth:`Tracer.save` writes Chrome trace-event
 JSON (the ``{"traceEvents": [...]}`` form) loadable in Perfetto or
 ``chrome://tracing`` — each trace id renders as its own named track.
 
+:meth:`Tracer.scope` opens a span for the length of a ``with`` block and
+mirrors it on JAX's profiler as a ``TraceAnnotation`` of the same name,
+with the span's id as the event's ``span_id`` stat.  Inside a profiler
+session every scoped span thus lands on the device trace's clock; the
+offset between the two clocks is the difference of any pair (the host
+event's start minus the span's ``t0``), which places the tracer's other
+spans on the device timeline too.  With no profiler session running the
+annotation records nothing.
+
 ZERO-COST WHEN DISABLED: a disabled tracer's ``start`` returns the
 process-wide :data:`NULL_SPAN` whose ``end``/``annotate``/``child`` are
-no-ops — no allocation, no clock read, no branch in caller code.
+no-ops, and its ``scope`` the shared :data:`NULL_SCOPE` — no allocation,
+no clock read, no branch in caller code.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 from typing import Any, Dict, List, Optional
 
@@ -95,6 +106,32 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+#: the disabled tracer's scope: enters as :data:`NULL_SPAN`, reusable
+NULL_SCOPE = contextlib.nullcontext(NULL_SPAN)
+
+
+class _Scope:
+    """A span open for a ``with`` block, mirrored on the profiler's host
+    plane by a ``TraceAnnotation`` of the same name and ``span_id``."""
+
+    __slots__ = ("span", "_note")
+
+    def __init__(self, span: Span):
+        self.span = span
+        self._note = None
+
+    def __enter__(self) -> Span:
+        from jax.profiler import TraceAnnotation
+        self._note = TraceAnnotation(self.span.name,
+                                     span_id=self.span.span_id)
+        self._note.__enter__()
+        return self.span
+
+    def __exit__(self, *exc) -> bool:
+        self.span.end()
+        self._note.__exit__(*exc)
+        return False
+
 
 class RequestTrace:
     """The per-request span bundle the server threads through dispatch."""
@@ -137,6 +174,16 @@ class Tracer:
                     parent_id, self.clock() if t0 is None else t0, args)
         self.spans.append(span)
         return span
+
+    def scope(self, name: str, *, parent: Optional[Span] = None,
+              cat: str = "span", trace_id: Optional[str] = None,
+              t0: Optional[float] = None, **args: Any):
+        """A context manager: the span ``start`` would make, ended when the
+        block exits (by return or raise), and on the profiler's host plane
+        while it is open.  ``as`` binds the span."""
+        span = self.start(name, cat=cat, trace_id=trace_id, parent=parent,
+                          t0=t0, args=args)
+        return NULL_SCOPE if span is NULL_SPAN else _Scope(span)
 
     def finish(self) -> None:
         """Terminate any still-open spans (marked incomplete)."""
@@ -196,6 +243,9 @@ class _NullTracer:
 
     def start(self, name: str, **kw: Any) -> _NullSpan:
         return NULL_SPAN
+
+    def scope(self, name: str, **kw: Any):
+        return NULL_SCOPE
 
     def finish(self) -> None:
         return None

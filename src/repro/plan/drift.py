@@ -1,26 +1,24 @@
 """Cost-model drift: measured kernel times vs :meth:`Footprint.est_time_s`.
 
 The planner ranks tile candidates by the analytic roofline in
-:mod:`repro.plan.model`; the profiler (:mod:`repro.obs.profile`) and the
-autotuner record what the kernels actually cost.  This module joins the
-two into one table — per ``cnn_kernel_shapes`` launch, the estimated and
-measured microseconds and their ratio — so a drifting cost model is a
-number you can watch, not a vibe.
+:mod:`repro.plan.model`; the autotuner records what the kernels actually
+cost.  This module joins the two into one table — per
+``cnn_kernel_shapes`` launch, the estimated and measured microseconds and
+their ratio — so a drifting cost model is a number you can watch, not a
+vibe.
 
 Measured times come from the first available source per row:
 
-  1. a live :class:`repro.obs.profile.KernelProfiler` aggregate whose
-     (family, dims, precision) key matches the launch (eager calls only —
-     jitted serving launches pass through the profiler untimed);
-  2. the tuning cache's ``measured_us`` (written by ``autotune=True``
+  1. the tuning cache's ``measured_us`` (written by ``autotune=True``
      plans);
-  3. a fresh eager :func:`repro.plan.planner.measure_kernel` calibration
+  2. a fresh eager :func:`repro.plan.planner.measure_kernel` calibration
      when ``measure=True`` (pool launches carry no tile knob and are not
-     measurable this way — they join only via source 1).
+     measurable this way).
 
 The table persists next to the tuning cache (``<cache>.drift.json``) as
-strict JSON, and ``python -m repro.obs drift`` / ``launch/serve.py
---profile-kernels`` print it.
+strict JSON, and ``python -m repro.obs drift`` prints it.  Kernel times of
+served launches are in the device trace, where every Pallas kernel is
+named after its family.
 """
 from __future__ import annotations
 
@@ -45,12 +43,8 @@ def drift_path(cache_path: Optional[str] = None) -> str:
 
 
 def _measured_us(family, kw, dims, precision, tile, profile, *,
-                 profiler=None, cache=None, measure=False):
+                 cache=None, measure=False):
     """(measured_us, source) from the first source that has this launch."""
-    if profiler is not None:
-        agg = profiler.aggregates().get((family, dims, precision))
-        if agg is not None:
-            return agg["mean_us"], "profiler"
     if cache is not None and family != "pool":
         ck = cache_key(family, list(dims), PLAN_DTYPES[precision],
                        precision, profile.name)
@@ -66,7 +60,7 @@ def _measured_us(family, kw, dims, precision, tile, profile, *,
 
 def drift_rows(cfg, plan: Optional[TilePlan] = None, *, device=None,
                precision: str = "f32", batch: int = 1, seeds: int = 1,
-               profiler=None, cache: Optional[TuningCache] = None,
+               cache: Optional[TuningCache] = None,
                measure: bool = False) -> List[Dict[str, Any]]:
     """One row per CNN kernel launch: est_us, measured_us, drift ratio.
 
@@ -84,7 +78,7 @@ def drift_rows(cfg, plan: Optional[TilePlan] = None, *, device=None,
         dims = tuple(int(v) for v in kw.values())
         measured, source = _measured_us(
             family, kw, dims, precision, tile, profile,
-            profiler=profiler, cache=cache, measure=measure)
+            cache=cache, measure=measure)
         est_us = 1e6 * est_s
         rows.append({
             "key": key, "family": family,

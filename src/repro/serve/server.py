@@ -36,6 +36,15 @@ Heavy-traffic hardening (see :mod:`repro.serve.admission`):
 
 Everything is synchronous and deterministic (injectable clock); an async
 transport would wrap ``submit``/``poll`` without touching the dataflow.
+
+With a tracer, every launch is a ``batch/<kind>`` span carrying the serving
+thread's CPU and run-queue seconds over it (``cpu_s``, ``runq_s``), with one
+``cat="phase"`` child per phase: ``batch.stack``, ``engine.forward``,
+``targets``, ``engine.replay``, ``engine.attribute``, ``cache.gather``,
+``cache.store`` and ``respond``.  The engine phases carry the launch's
+``program``, padded ``rows``, ``live`` rows, ``seeds`` and ``method``.  All
+of them are scoped spans (:meth:`repro.obs.trace.Tracer.scope`), so under
+``jax.profiler`` they also appear on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -47,7 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import clock as clock_lib
-from repro.obs.trace import NULL_SPAN, NULL_TRACER, RequestTrace, Tracer
+from repro.obs.trace import (NULL_SCOPE, NULL_SPAN, NULL_TRACER,
+                             RequestTrace, Tracer)
 from repro.serve import registry
 from repro.serve.admission import AdmissionConfig, AdmissionController
 from repro.serve.api import (EXPLAIN, PREDICT, SHED_EXPIRED,
@@ -69,11 +79,13 @@ class ExplanationServer:
                  tracer: Optional[Tracer] = None):
         self.adapter = adapter
         self.clock = clock
-        # tracer=None is the zero-cost path: NULL_TRACER's start() returns
-        # the shared no-op span and requests never carry a RequestTrace.
+        # tracer=None is the zero-cost path: NULL_TRACER's start() and
+        # scope() return the shared no-op span and scope, and requests
+        # never carry a RequestTrace.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         if self.tracer.enabled:
             self.tracer.clock = clock      # spans and deadlines share "now"
+        self._batch_span = NULL_SPAN       # the launch in flight (_dispatch)
         self._trace_seq = itertools.count()
         # Mesh-sharded adapters (engine built for a mesh:<profile>:<n>
         # device) expose n_shards; the batcher then fills buckets toward
@@ -282,26 +294,40 @@ class ExplanationServer:
         """Fault-isolated batch execution: an exception inside a batch
         becomes per-request error responses, never a dead worker loop."""
         t0 = self.clock()
-        bspan = NULL_SPAN
+        scope = NULL_SCOPE
         if self.tracer.enabled:
             # the batch is its own track; request spans point at it by id
             bid = f"batch#{next(self._trace_seq)}"
-            bspan = self.tracer.start(
+            scope = self.tracer.scope(
                 f"batch/{batch.kind}", cat="batch", trace_id=bid, t0=t0,
-                args={"n": len(batch.requests), "degraded": batch.degraded,
-                      "method": (batch.requests[0].method
-                                 if batch.kind == EXPLAIN else "")})
-            for req in batch.requests:
-                if req.trace is not None:
-                    req.trace.queued.end(t=t0)
-                    req.trace.engine = req.trace.root.child(
-                        "engine", cat="engine", t0=t0, args={"batch": bid})
-        try:
-            out = self._process(batch)
-        except Exception as e:                          # noqa: BLE001
-            out = [self._finish_error(req, e) for req in batch.requests]
-        duration = self.clock() - t0
-        bspan.end(t=t0 + duration)
+                n=len(batch.requests), degraded=batch.degraded,
+                method=(batch.requests[0].method
+                        if batch.kind == EXPLAIN else ""))
+        # the span's ends and its profiler event's stay a few statements
+        # apart: their offset is what puts spans on the device's clock
+        with scope as bspan:
+            if self.tracer.enabled:
+                for req in batch.requests:
+                    if req.trace is not None:
+                        req.trace.queued.end(t=t0)
+                        req.trace.engine = req.trace.root.child(
+                            "engine", cat="engine", t0=t0,
+                            args={"batch": bid})
+            times0 = clock_lib.thread_times() if bspan.enabled else None
+            self._batch_span = bspan
+            try:
+                out = self._process(batch)
+            except Exception as e:                      # noqa: BLE001
+                out = [self._finish_error(req, e) for req in batch.requests]
+            finally:
+                self._batch_span = NULL_SPAN
+            if times0 is not None:
+                (cpu0, runq0), (cpu1, runq1) = times0, clock_lib.thread_times()
+                bspan.annotate(cpu_s=cpu1 - cpu0,
+                               runq_s=(None if runq0 is None or runq1 is None
+                                       else runq1 - runq0))
+            duration = self.clock() - t0
+            bspan.end(t=t0 + duration)
         if (self.dispatch_timeout_s is not None
                 and duration > self.dispatch_timeout_s):
             self.stats.record_timeout()
@@ -313,6 +339,11 @@ class ExplanationServer:
                 req0.kind, req0.method if req0.kind == EXPLAIN else "",
                 duration, len(batch.requests))
         return out
+
+    def _phase(self, name: str, **args):
+        """A scoped span over one phase of the launch in flight."""
+        return self.tracer.scope(name, parent=self._batch_span, cat="phase",
+                                 **args)
 
     def _process(self, batch: Batch) -> List[Response]:
         if batch.kind == PREDICT:
@@ -348,23 +379,27 @@ class ExplanationServer:
         return resp
 
     def _run_predict(self, batch: Batch) -> List[Response]:
-        xb, live = batch.stack(self.batcher.fill_target)
-        logits, residuals = self.adapter.predict(xb)
-        jax.block_until_ready(logits)
-        self.stats.record_batch(live, xb.shape[0])
+        with self._phase("batch.stack"):
+            xb, live = batch.stack(self.batcher.fill_target)
+        rows = xb.shape[0]
+        with self._phase("engine.forward", program="forward", rows=rows,
+                         live=live, seeds=0, method=""):
+            logits, residuals = self.adapter.predict(xb)
+            jax.block_until_ready(logits)
+        self.stats.record_batch(live, rows)
         now = self.clock()
-        out = []
-        for i, req in enumerate(batch.requests):
-            self.cache.put(req.uid, CacheEntry(
-                logits=logits[i], residuals=slice_example(residuals, i),
-                rules=self.adapter.store_rules))
-            if req.trace is not None:
-                req.trace.root.child("cache", cat="cache", t0=now).end(
-                    t=now, result="store")
-            out.append(self._finish(req, Response(
-                uid=req.uid, kind=PREDICT, logits=logits[i],
-                batch_size=xb.shape[0])))
-        return out
+        with self._phase("cache.store"):
+            for i, req in enumerate(batch.requests):
+                self.cache.put(req.uid, CacheEntry(
+                    logits=logits[i], residuals=slice_example(residuals, i),
+                    rules=self.adapter.store_rules))
+                if req.trace is not None:
+                    req.trace.root.child("cache", cat="cache", t0=now).end(
+                        t=now, result="store")
+        with self._phase("respond"):
+            return [self._finish(req, Response(
+                uid=req.uid, kind=PREDICT, logits=logits[i], batch_size=rows))
+                for i, req in enumerate(batch.requests)]
 
     @staticmethod
     def _rules_compatible(stored_rules: str, method: str) -> bool:
@@ -427,30 +462,34 @@ class ExplanationServer:
         """Forward-free path: seed-batched fused BP over cached masks."""
         reqs = [r for r, _ in hits]
         entries = [e for _, e in hits]
-        targets = [self._targets_for(r, e.logits)
-                   for r, e in zip(reqs, entries)]
+        with self._phase("targets"):
+            targets = [self._targets_for(r, e.logits)
+                       for r, e in zip(reqs, entries)]
         # pow2-pad the hit group too (rows repeat entry 0, sliced off below)
         # so the BP program compiles for a handful of batch shapes only.
         psize = pad_size(len(reqs), self.batcher.fill_target)
         ent_pad = entries + [entries[0]] * (psize - len(reqs))
         tgt_pad = targets + [targets[0]] * (psize - len(reqs))
-        residuals = concat_examples([e.residuals for e in ent_pad])
+        with self._phase("cache.gather"):
+            residuals = concat_examples([e.residuals for e in ent_pad])
         num_classes = entries[0].logits.shape[-1]
-        # [S, B, C]; S is bucket-homogeneous (topk is part of the bucket key)
-        seeds = jax.nn.one_hot(jnp.asarray(np.stack(tgt_pad, axis=1)),
-                               num_classes,
-                               dtype=entries[0].logits.dtype)
-        rel = self.adapter.explain_cached(method, residuals, seeds)
-        jax.block_until_ready(rel)
+        with self._phase("engine.replay", program="replay", rows=psize,
+                         live=len(reqs), seeds=len(targets[0]),
+                         method=method):
+            # [S, B, C]; S is bucket-homogeneous (topk is in the bucket key)
+            seeds = jax.nn.one_hot(jnp.asarray(np.stack(tgt_pad, axis=1)),
+                                   num_classes,
+                                   dtype=entries[0].logits.dtype)
+            rel = self.adapter.explain_cached(method, residuals, seeds)
+            jax.block_until_ready(rel)
         self.stats.record_batch(len(reqs), psize)
-        out = []
-        for i, (req, entry) in enumerate(zip(reqs, entries)):
-            rel_i = rel[:, i] if req.topk is not None else rel[0, i]
-            out.append(self._finish(req, Response(
+        with self._phase("respond"):
+            return [self._finish(req, Response(
                 uid=req.uid, kind=EXPLAIN, logits=entry.logits,
-                relevance=rel_i, targets=tuple(int(t) for t in targets[i]),
-                method=method, cache_hit=True, batch_size=psize)))
-        return out
+                relevance=rel[:, i] if req.topk is not None else rel[0, i],
+                targets=tuple(int(t) for t in targets[i]), method=method,
+                cache_hit=True, batch_size=psize))
+                for i, (req, entry) in enumerate(zip(reqs, entries))]
 
     def _explain_cold(self, method: str, reqs: List[Request],
                       degraded: bool = False) -> List[Response]:
@@ -469,7 +508,9 @@ class ExplanationServer:
         if (registry.get(method).mask_reuse
                 and self._rules_compatible(adapter.store_rules, method)):
             return self._explain_cold_bp(method, reqs, degraded=degraded)
-        xb, live = Batch(("explain",), reqs).stack(self.batcher.fill_target)
+        with self._phase("batch.stack"):
+            xb, live = Batch(("explain",),
+                             reqs).stack(self.batcher.fill_target)
         explainer = self.explainer(method, degraded)
         if reqs[0].target is None:             # bucket-homogeneous target kind
             target = None
@@ -491,17 +532,20 @@ class ExplanationServer:
                 # non-foldable stochastic methods ride singleton buckets
                 # (batcher token), so reqs is exactly one request here
                 key = reqs[0].key
-        logits, rel = explainer.attribute(xb, target=target, key=key)
-        jax.block_until_ready(rel)
+        with self._phase("engine.attribute", program="attribute",
+                         rows=xb.shape[0], live=live, seeds=1, method=method):
+            logits, rel = explainer.attribute(xb, target=target, key=key)
+            jax.block_until_ready(rel)
         self.stats.record_batch(live, xb.shape[0])
         out = []
-        for i, req in enumerate(reqs):
-            tgt = (req.target if req.target is not None
-                   else int(np.argmax(np.asarray(logits[i]))))
-            out.append(self._finish(req, Response(
-                uid=req.uid, kind=EXPLAIN, logits=logits[i],
-                relevance=rel[i], targets=(int(tgt),), method=method,
-                batch_size=xb.shape[0])))
+        with self._phase("respond"):
+            for i, req in enumerate(reqs):
+                tgt = (req.target if req.target is not None
+                       else int(np.argmax(np.asarray(logits[i]))))
+                out.append(self._finish(req, Response(
+                    uid=req.uid, kind=EXPLAIN, logits=logits[i],
+                    relevance=rel[i], targets=(int(tgt),), method=method,
+                    batch_size=xb.shape[0])))
         return out
 
     def _explain_cold_bp(self, method: str, reqs: List[Request],
@@ -510,28 +554,37 @@ class ExplanationServer:
         warming the residual cache with the forward's packed masks (primary
         adapter only — degraded residuals are engine-incompatible)."""
         adapter = self._adapter_for(degraded)
-        xb, live = Batch(("explain",), reqs).stack(self.batcher.fill_target)
-        logits, residuals = adapter.predict(xb)
-        targets = [self._targets_for(r, logits[i])
-                   for i, r in enumerate(reqs)]
-        pad = xb.shape[0] - live
-        tmat = np.concatenate([np.stack(targets, axis=1),
-                               np.zeros((targets[0].shape[0], pad), int)],
-                              axis=1)
-        seeds = jax.nn.one_hot(jnp.asarray(tmat), logits.shape[-1],
-                               dtype=logits.dtype)
-        rel = adapter.explain_cached(method, residuals, seeds)
-        jax.block_until_ready(rel)
-        self.stats.record_batch(live, xb.shape[0])
-        out = []
-        for i, req in enumerate(reqs):
-            if not degraded:
-                self.cache.put(req.uid, CacheEntry(
-                    logits=logits[i], residuals=slice_example(residuals, i),
-                    rules=adapter.store_rules))
-            out.append(self._finish(req, Response(
+        with self._phase("batch.stack"):
+            xb, live = Batch(("explain",),
+                             reqs).stack(self.batcher.fill_target)
+        rows = xb.shape[0]
+        with self._phase("engine.forward", program="forward", rows=rows,
+                         live=live, seeds=0, method=method):
+            logits, residuals = adapter.predict(xb)
+        with self._phase("targets"):
+            targets = [self._targets_for(r, logits[i])
+                       for i, r in enumerate(reqs)]
+        with self._phase("engine.replay", program="replay", rows=rows,
+                         live=live, seeds=len(targets[0]), method=method):
+            tmat = np.concatenate(
+                [np.stack(targets, axis=1),
+                 np.zeros((targets[0].shape[0], rows - live), int)], axis=1)
+            seeds = jax.nn.one_hot(jnp.asarray(tmat), logits.shape[-1],
+                                   dtype=logits.dtype)
+            rel = adapter.explain_cached(method, residuals, seeds)
+            jax.block_until_ready(rel)
+        self.stats.record_batch(live, rows)
+        if not degraded:
+            with self._phase("cache.store"):
+                for i, req in enumerate(reqs):
+                    self.cache.put(req.uid, CacheEntry(
+                        logits=logits[i],
+                        residuals=slice_example(residuals, i),
+                        rules=adapter.store_rules))
+        with self._phase("respond"):
+            return [self._finish(req, Response(
                 uid=req.uid, kind=EXPLAIN, logits=logits[i],
                 relevance=rel[:, i] if req.topk is not None else rel[0, i],
                 targets=tuple(int(t) for t in targets[i]), method=method,
-                batch_size=xb.shape[0])))
-        return out
+                batch_size=rows))
+                for i, req in enumerate(reqs)]

@@ -1,22 +1,24 @@
-"""repro.obs: metrics registry, request tracing, kernel profiling, clock.
+"""repro.obs: metrics registry, request tracing, clock.
 
 The contracts the observability layer sells:
 
   * zero-cost when disabled — no tracer means the NULL singletons (no
-    allocation, no clock reads), no profiler means one ``is None`` check,
-    and enabling either NEVER changes explain outputs (bitwise);
+    allocation, no clock reads, no thread counters), and enabling it
+    NEVER changes explain outputs (bitwise);
   * every span terminates on every dispatch path — success, shed at
     submit, expired in queue, degraded — and the Chrome export is
     strict-JSON, schema-valid, Perfetto-loadable;
+  * every launch is a ``batch/*`` span with its phases nested inside, and
+    every scoped span lands on the profiler's host plane by name and
+    ``span_id``, a constant offset from the tracer's clock;
   * the registry guards label cardinality and its snapshot round-trips
     ``json.dumps(..., allow_nan=False)``;
-  * the drift table joins profiler/cache/fresh measurements against the
-    analytic cost model per ``cnn_kernel_shapes`` launch.
+  * the drift table joins cache/fresh measurements against the analytic
+    cost model per ``cnn_kernel_shapes`` launch.
 """
 import json
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -24,7 +26,6 @@ from repro.obs import (NULL_SPAN, NULL_TRACER, Tracer, VirtualClock,
                        dumps_strict, sanitize)
 from repro.obs import clock as clock_lib
 from repro.obs import metrics as obsm
-from repro.obs import profile as obs_profile
 from repro.obs import registry as obs_registry
 from repro.obs.registry import OVERFLOW, Registry, percentile_of
 from repro.obs.trace import integrity_errors, validate_chrome
@@ -34,12 +35,6 @@ from repro.serve.replay import SimAdapter, replay, synthesize
 from repro.serve.stats import percentile
 
 X = np.zeros((8, 8, 1), np.float32)
-
-
-@pytest.fixture(autouse=True)
-def _no_leaked_profiler():
-    yield
-    obs_profile.disable()
 
 
 # ---------------------------------------------------------------------------
@@ -180,8 +175,7 @@ def test_default_registry_names_all_subsystem_series():
     snap = obs_registry.snapshot()
     for name in ("serve_requests_total", "serve_sheds_total",
                  "serve_degrades_total", "serve_residual_cache_events_total",
-                 "plan_cache_lookups_total", "engine_builds_total",
-                 "kernel_launch_seconds"):
+                 "plan_cache_lookups_total", "engine_builds_total"):
         assert name in snap, name
     sheds = {s["labels"]["reason"]
              for s in snap["serve_sheds_total"]["series"]}
@@ -360,60 +354,201 @@ def test_tracing_bitwise_noop_on_outputs():
 
 
 # ---------------------------------------------------------------------------
-# kernel profiler
+# scoped spans: launch phases, the profiler's clock, thread counters
 # ---------------------------------------------------------------------------
 
-
-def test_profiler_disabled_is_passthrough():
-    from repro.kernels.vmm.vmm import vmm_pallas
-    assert obs_profile.profiler() is None
-    assert hasattr(vmm_pallas, "__wrapped__")
-
-
-def test_profiler_records_eager_launches_bitwise_noop():
-    from repro.kernels.vmm.vmm import vmm_pallas
-    x = jnp.ones((8, 128), jnp.float32)
-    w = jnp.ones((128, 128), jnp.float32)
-    base = np.asarray(vmm_pallas(x, w))
-    with obs_profile.profiled() as prof:
-        out = np.asarray(vmm_pallas(x, w))
-    np.testing.assert_array_equal(base, out)
-    key = ("vmm_fwd", (8, 128, 128), "f32")
-    assert key in prof.records
-    agg = prof.aggregates()[key]
-    assert agg["count"] == 1 and agg["mean_us"] > 0
-    assert obsm.KERNEL_SECONDS.snapshot()  # histogram series materialized
-    assert obs_profile.profiler() is None  # context restored
+#: the phases each kind of launch runs, in order
+PHASES = {
+    "predict": ["batch.stack", "engine.forward", "cache.store", "respond"],
+    "hit": ["targets", "cache.gather", "engine.replay", "respond"],
+    "cold": ["batch.stack", "engine.forward", "targets", "engine.replay",
+             "cache.store", "respond"],
+    "composite": ["batch.stack", "engine.attribute", "respond"],
+}
 
 
-def test_profiler_passes_through_jitted_tracers():
-    from repro.kernels.vmm.vmm import vmm_pallas
-    x = jnp.ones((8, 128), jnp.float32)
-    w = jnp.ones((128, 128), jnp.float32)
-    f = jax.jit(lambda a, b: vmm_pallas(a, b))
-    with obs_profile.profiled() as prof:
-        jax.block_until_ready(f(x, w))
-    assert prof.passthrough >= 1
-    assert ("vmm_fwd", (8, 128, 128), "f32") not in prof.records
+def test_tracer_scope_nests_and_terminates_spans():
+    clock = VirtualClock()
+    tracer = Tracer(clock=clock)
+    with tracer.scope("batch/explain", cat="batch", trace_id="b1",
+                      n=2) as outer:
+        clock.advance(0.001)
+        with tracer.scope("engine.replay", parent=outer, cat="phase",
+                          rows=2) as inner:
+            clock.advance(0.002)
+        assert inner.t1 == pytest.approx(0.003) and outer.t1 is None
+        with pytest.raises(RuntimeError):
+            with tracer.scope("respond", parent=outer, cat="phase"):
+                clock.advance(0.001)
+                raise RuntimeError("a poisoned batch")
+    assert [s.name for s in tracer.spans] == ["batch/explain",
+                                              "engine.replay", "respond"]
+    assert all(s.trace_id == "b1" for s in tracer.spans)
+    assert [s.parent_id for s in tracer.spans[1:]] == [outer.span_id] * 2
+    assert tracer.spans[2].t1 == pytest.approx(0.004)   # ended on raise
+    assert outer.args == {"n": 2} and inner.args == {"rows": 2}
+    assert (outer.t0, outer.t1) == (0.0, pytest.approx(0.004))
+    assert integrity_errors(tracer.spans) == []
+    # an explicit end inside the block wins over the scope's own
+    with tracer.scope("batch/predict", cat="batch", t0=1.0) as span:
+        span.end(t=1.5)
+    assert (span.t0, span.t1) == (1.0, 1.5)
 
 
-def test_profiler_signature_matches_planner_kw_order():
-    """tuple(sig.values()) must join bit-exactly with cache_key dims."""
+def test_null_scope_allocates_nothing():
+    import itertools
+    import tracemalloc
+
+    from repro.obs import NULL_SCOPE
+
+    def no_clock():
+        raise AssertionError("a disabled tracer read the clock")
+
+    off = Tracer(clock=no_clock, enabled=False)
+    for tracer in (NULL_TRACER, off):
+        assert tracer.scope("batch/explain", cat="batch", n=1) is NULL_SCOPE
+        with tracer.scope("engine.replay", parent=NULL_SPAN,
+                          cat="phase") as span:
+            assert span is NULL_SPAN
+    assert off.spans == []
+
+    def grown(calls):
+        tracemalloc.start()
+        try:
+            before = tracemalloc.get_traced_memory()[0]
+            for _ in itertools.repeat(None, calls):   # no int per turn
+                with NULL_TRACER.scope("engine.forward", parent=NULL_SPAN,
+                                       cat="phase", rows=8, live=1) as span:
+                    span.end()
+            return tracemalloc.get_traced_memory()[0] - before
+        finally:
+            tracemalloc.stop()
+
+    assert grown(10_000) == grown(10) == 0
+
+
+@pytest.fixture(scope="module")
+def tiny_cnn_adapter():
     from repro.models import cnn
-    from repro.plan.planner import cnn_kernel_shapes
     cfg = cnn.CNNConfig(in_hw=(8, 8), channels=(4, 4), fc=(16,))
-    for _key, family, kw in cnn_kernel_shapes(cfg, batch=2, seeds=3):
-        assert family in obs_profile._SIG_FNS
-        expected = list(kw.keys())
-        got = {
-            "conv2d_fwd": ["n", "h", "w", "k", "cin", "cout"],
-            "conv2d_bwd": ["s", "n", "hg", "wg", "k", "c", "cout",
-                           "pooled", "gated"],
-            "vmm_fwd": ["m", "k", "n"],
-            "vmm_bwd": ["s", "m", "k", "n", "gated"],
-            "pool": ["n", "h", "w", "c"],
-        }[family]
-        assert expected == got, (family, expected, got)
+    return CNNAdapter(cnn.init(jax.random.PRNGKey(0), cfg), cfg)
+
+
+def _serve_every_path(adapter, tracer):
+    """Predicts, then explains that hit the cache (argmax and a top-3
+    panel), cold explains and a composite one: every launch kind.  ->
+    {launch kind: batch span ids}"""
+    server = ExplanationServer(adapter, max_batch=4, max_delay_s=0.0,
+                               tracer=tracer)
+    xs = jax.random.normal(jax.random.PRNGKey(1), (6, 8, 8, 3))
+    kinds = {}
+
+    def wave(kind, reqs):
+        n = len(tracer.spans)
+        for r in reqs:
+            server.submit(r)
+        assert all(r.ok for r in server.drain())
+        kinds[kind] = [s.span_id for s in tracer.spans[n:]
+                       if s.cat == "batch"]
+
+    wave("predict", [Request(uid=f"q{i}", kind="predict", x=xs[i])
+                     for i in range(3)])
+    wave("hit", [Request(uid=f"q{i}", kind="explain", x=xs[i],
+                         method="guided", topk=3 if i else None)
+                 for i in range(3)])
+    wave("cold", [Request(uid=f"c{i}", kind="explain", x=xs[3 + i],
+                          method="saliency") for i in range(2)])
+    wave("composite", [Request(uid="g0", kind="explain", x=xs[5],
+                               method="input_x_gradient")])
+    return kinds
+
+
+def test_every_launch_nests_its_phases_in_its_batch_span(tiny_cnn_adapter):
+    tracer = Tracer()
+    kinds = _serve_every_path(tiny_cnn_adapter, tracer)
+    tracer.finish()
+    assert integrity_errors(tracer.spans) == []
+    assert not any(s.args.get("incomplete") for s in tracer.spans)
+    by_id = {s.span_id: s for s in tracer.spans}
+    phases = [s for s in tracer.spans if s.cat == "phase"]
+    assert all(by_id[s.parent_id].cat == "batch" for s in phases)
+    for kind, ids in kinds.items():
+        assert ids, kind
+        for bid in ids:
+            batch = by_id[bid]
+            mine = [s for s in phases if s.parent_id == bid]
+            assert [s.name for s in mine] == PHASES[kind], kind
+            assert all(batch.t0 <= s.t0 <= s.t1 <= batch.t1 for s in mine)
+            for s in mine:
+                if s.name.startswith("engine."):
+                    assert s.args["live"] == batch.args["n"]
+                    assert s.args["rows"] >= s.args["live"]
+                    assert s.args["method"] == batch.args["method"]
+    assert {s.args["seeds"] for s in phases
+            if s.name == "engine.replay"} == {1, 3}
+
+
+def test_scoped_spans_land_on_the_profiler_host_plane(tiny_cnn_adapter,
+                                                      tmp_path):
+    import glob
+
+    from jax.profiler import ProfileData
+    _serve_every_path(tiny_cnn_adapter, Tracer())     # compile outside
+    tracer = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve_every_path(tiny_cnn_adapter, tracer)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for ev in line.events:
+                    sid = next((v for k, v in ev.stats if k == "span_id"),
+                               None)
+                    if sid is not None:
+                        host[int(sid)] = (ev.name, ev.start_ns * 1e-9,
+                                          ev.duration_ns * 1e-9)
+    scoped = [s for s in tracer.spans if s.cat in ("batch", "phase")]
+    assert len(scoped) > 20
+    offsets = []
+    for s in scoped:
+        name, start, dur = host[s.span_id]
+        assert name == s.name
+        assert dur == pytest.approx(s.duration, abs=1e-3)
+        offsets.append(start - s.t0)
+    assert max(offsets) - min(offsets) < 1e-3
+
+
+@pytest.mark.parametrize("schedstat", ["readable", "unreadable"])
+def test_batch_spans_carry_thread_cpu_and_runq_seconds(tiny_cnn_adapter,
+                                                       monkeypatch,
+                                                       schedstat):
+    import os
+    if schedstat == "unreadable":
+        monkeypatch.setattr(clock_lib, "_SCHEDSTAT", "/nonexistent/schedstat")
+    readable = os.path.exists(clock_lib._SCHEDSTAT)
+    tracer = Tracer()
+    _serve_every_path(tiny_cnn_adapter, tracer)
+    batches = [s for s in tracer.spans if s.cat == "batch"]
+    assert batches
+    for s in batches:
+        assert s.args["cpu_s"] >= 0
+        if readable:
+            assert s.args["runq_s"] >= 0
+        else:
+            assert s.args["runq_s"] is None
+
+
+def test_untraced_server_reads_no_thread_counters(tiny_cnn_adapter,
+                                                  monkeypatch):
+    def refuse():
+        raise AssertionError("an untraced server read a thread counter")
+
+    monkeypatch.setattr(clock_lib, "thread_times", refuse)
+    assert _serve_every_path(tiny_cnn_adapter, NULL_TRACER)
 
 
 # ---------------------------------------------------------------------------
@@ -421,7 +556,7 @@ def test_profiler_signature_matches_planner_kw_order():
 # ---------------------------------------------------------------------------
 
 
-def test_drift_rows_cover_every_launch_and_join_profiler():
+def test_drift_rows_cover_every_launch():
     from repro.models import cnn
     from repro.plan.drift import drift_rows, format_drift
     cfg = cnn.CNNConfig(in_hw=(8, 8), channels=(4, 4), fc=(16,))
@@ -429,20 +564,9 @@ def test_drift_rows_cover_every_launch_and_join_profiler():
     assert rows and all(r["est_us"] > 0 for r in rows)
     families = {r["family"] for r in rows}
     assert {"conv2d_fwd", "conv2d_bwd", "vmm_fwd", "vmm_bwd"} <= families
-    assert all(r["measured_us"] is None for r in rows)
-
-    # a profiler aggregate keyed like the first vmm row joins as measured
-    prof = obs_profile.KernelProfiler()
-    target = next(r for r in rows if r["family"] == "vmm_fwd")
-    dims = tuple(int(d) for d in target["shape"].split("x"))
-    prof.records[("vmm_fwd", dims, "f32")] = [3, 3e-3, 1e-3, 1e-3]
-    joined = drift_rows(cfg, profiler=prof)
-    hit = next(r for r in joined if r["shape"] == target["shape"]
-               and r["family"] == "vmm_fwd")
-    assert hit["source"] == "profiler"
-    assert hit["measured_us"] == pytest.approx(1000.0)
-    assert hit["drift"] == pytest.approx(1000.0 / hit["est_us"])
-    assert "vmm_fwd" in format_drift(joined)
+    assert all(r["measured_us"] is None and r["drift"] is None
+               and r["source"] is None for r in rows)
+    assert "vmm_fwd" in format_drift(rows)
 
 
 def test_drift_joins_tuning_cache_and_persists_strict(tmp_path):

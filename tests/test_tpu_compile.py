@@ -205,3 +205,62 @@ def test_backward_seeds_program_compiles(one_chip, compiled, precision,
         text = jax.jit(explain).lower(p_spec, x_spec).compile().as_text()
     # 4 conv + 2 pool + 5 relu/mask + 2 FC forwards, 4 conv + 2 FC backwards
     assert text.count("tpu_custom_call") >= 12
+
+
+#: the kernel families a ``pallas_call`` may be named after
+KERNEL_FAMILIES = ("conv2d_fwd", "conv2d_bwd", "vmm_fwd", "vmm_bwd",
+                   "maxpool_fwd", "maxpool_bwd", "relu_mask_fwd",
+                   "relu_mask_bwd", "ssm_scan")
+
+
+@pytest.mark.parametrize("precision", ["f32", "fxp16"])
+def test_served_programs_name_every_kernel_by_family(one_chip, compiled,
+                                                     precision):
+    """The serving engine's two programs, the residual forward and the
+    seed-batched replay, compiled for the chip: every Mosaic kernel is an
+    instruction named after its family, so the device trace names it too."""
+    import re
+
+    cfg = cnn.CNNConfig()
+    params = jax.eval_shape(lambda: cnn.init(jax.random.PRNGKey(0), cfg))
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    p_spec = jax.tree.map(spec, params)
+    x_spec = jax.ShapeDtypeStruct((N, 32, 32, 3), jnp.float32,
+                                  sharding=one_chip)
+
+    # the engine's protocol: the static feature shape stays out of the
+    # residuals a jitted program hands over (``CNNModel.pair``)
+    feat_shape = cfg.feature_hw() + (cfg.channels[-1],)
+
+    def forward(params, x):
+        logits, res = cnn.forward_with_residuals(params, x, cfg, "guided",
+                                                 precision)
+        return logits, {k: v for k, v in res.items() if k != "feat_shape"}
+
+    def replay(params, residuals, seeds):
+        return cnn.backward_seeds(params, dict(residuals,
+                                               feat_shape=feat_shape),
+                                  seeds, cfg, "guided", precision)
+
+    _, res = jax.eval_shape(forward, params, x_spec)
+    seeds = jax.ShapeDtypeStruct((S, N, cfg.num_classes), jnp.float32,
+                                 sharding=one_chip)
+    names = {}
+    for prog, fn, args in (
+            ("forward", forward, (p_spec, x_spec)),
+            ("replay", replay, (p_spec, jax.tree.map(spec, res), seeds))):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        names[prog] = re.findall(
+            r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text)
+        assert names[prog], prog
+        for name in names[prog]:
+            family = name.rsplit(".", 1)[0]
+            assert family in KERNEL_FAMILIES, (prog, name)
+    assert {n.rsplit(".", 1)[0] for n in names["forward"]} >= {
+        "conv2d_fwd", "vmm_fwd", "maxpool_fwd", "relu_mask_fwd"}
+    assert {n.rsplit(".", 1)[0] for n in names["replay"]} >= {
+        "conv2d_bwd", "vmm_bwd"}
