@@ -6,7 +6,8 @@ latency deadline and runs it:
 
   * **predict** batches run the adapter's residual-returning forward; each
     request's packed masks are parked in the LRU residual cache under its
-    ``uid``.
+    ``uid``, as its row of the launch's residual tree (no per-example slice:
+    a launch's logits are read to the host once and its tree is shared).
   * **explain** batches split into cache **hits** — a pure-BP method with a
     cached predict for the same ``uid``: the forward pass is skipped and all
     hits in the bucket backpropagate together through ONE seed-batched fused
@@ -42,7 +43,9 @@ thread's CPU and run-queue seconds over it (``cpu_s``, ``runq_s``), with one
 ``cat="phase"`` child per phase: ``batch.stack``, ``engine.forward``,
 ``targets``, ``engine.replay``, ``engine.attribute``, ``cache.gather``,
 ``cache.store`` and ``respond``.  The engine phases carry the launch's
-``program``, padded ``rows``, ``live`` rows, ``seeds`` and ``method``.  All
+``program``, padded ``rows``, ``live`` rows, ``seeds`` and ``method``;
+``cache.gather`` carries the hit rows it ``copied`` (sliced or
+concatenated; the rest replay the stored tree as is).  All
 of them are scoped spans (:meth:`repro.obs.trace.Tracer.scope`), so under
 ``jax.profiler`` they also appear on the device trace's clock.
 """
@@ -64,7 +67,8 @@ from repro.serve.api import (EXPLAIN, PREDICT, SHED_EXPIRED,
                              InvalidRequestError, Request, Response,
                              ShedError, shed_response)
 from repro.serve.batcher import Batch, MicroBatcher, pad_size
-from repro.serve.residual_cache import CacheEntry, ResidualCache
+from repro.serve.residual_cache import (CacheEntry, ResidualCache,
+                                        example_bits)
 from repro.serve.stats import ServerStats
 from repro.serve.adapters import concat_examples, slice_example
 
@@ -389,10 +393,10 @@ class ExplanationServer:
         self.stats.record_batch(live, rows)
         now = self.clock()
         with self._phase("cache.store"):
-            for i, req in enumerate(batch.requests):
-                self.cache.put(req.uid, CacheEntry(
-                    logits=logits[i], residuals=slice_example(residuals, i),
-                    rules=self.adapter.store_rules))
+            logits = np.asarray(logits)     # the launch's one host read
+            self._store(batch.requests, logits, residuals,
+                        self.adapter.store_rules)
+            for req in batch.requests:
                 if req.trace is not None:
                     req.trace.root.child("cache", cat="cache", t0=now).end(
                         t=now, result="store")
@@ -400,6 +404,18 @@ class ExplanationServer:
             return [self._finish(req, Response(
                 uid=req.uid, kind=PREDICT, logits=logits[i], batch_size=rows))
                 for i, req in enumerate(batch.requests)]
+
+    def _store(self, reqs: List[Request], logits: np.ndarray, residuals,
+               rules: str) -> None:
+        """Park each request's row of a launch: the entries share the
+        launch's residual tree by reference (no device op), and a 1-row
+        launch's tree is already its one example's."""
+        rows = logits.shape[0]
+        bits = example_bits(residuals)
+        for i, req in enumerate(reqs):
+            self.cache.put(req.uid, CacheEntry(
+                logits=logits[i], residuals=residuals, rules=rules,
+                row=None if rows == 1 else i, bits=bits))
 
     @staticmethod
     def _rules_compatible(stored_rules: str, method: str) -> bool:
@@ -468,10 +484,21 @@ class ExplanationServer:
         # pow2-pad the hit group too (rows repeat entry 0, sliced off below)
         # so the BP program compiles for a handful of batch shapes only.
         psize = pad_size(len(reqs), self.batcher.fill_target)
-        ent_pad = entries + [entries[0]] * (psize - len(reqs))
         tgt_pad = targets + [targets[0]] * (psize - len(reqs))
-        with self._phase("cache.gather"):
-            residuals = concat_examples([e.residuals for e in ent_pad])
+        # a lone one-example entry replays its stored tree as is; any other
+        # group slices each entry's row out of its launch and concatenates
+        zero_copy = psize == 1 and entries[0].row is None
+        copied = 0 if zero_copy else len(reqs)
+        self.cache.count_gather(len(reqs), copied)
+        with self._phase("cache.gather", copied=copied):
+            if zero_copy:
+                residuals = entries[0].residuals
+            else:
+                parts = [e.residuals if e.row is None
+                         else slice_example(e.residuals, e.row)
+                         for e in entries]
+                residuals = concat_examples(
+                    parts + [parts[0]] * (psize - len(reqs)))
         num_classes = entries[0].logits.shape[-1]
         with self._phase("engine.replay", program="replay", rows=psize,
                          live=len(reqs), seeds=len(targets[0]),
@@ -562,7 +589,8 @@ class ExplanationServer:
                          live=live, seeds=0, method=method):
             logits, residuals = adapter.predict(xb)
         with self._phase("targets"):
-            targets = [self._targets_for(r, logits[i])
+            host_logits = np.asarray(logits)    # the launch's one host read
+            targets = [self._targets_for(r, host_logits[i])
                        for i, r in enumerate(reqs)]
         with self._phase("engine.replay", program="replay", rows=rows,
                          live=live, seeds=len(targets[0]), method=method):
@@ -576,14 +604,11 @@ class ExplanationServer:
         self.stats.record_batch(live, rows)
         if not degraded:
             with self._phase("cache.store"):
-                for i, req in enumerate(reqs):
-                    self.cache.put(req.uid, CacheEntry(
-                        logits=logits[i],
-                        residuals=slice_example(residuals, i),
-                        rules=adapter.store_rules))
+                self._store(reqs, host_logits, residuals,
+                            adapter.store_rules)
         with self._phase("respond"):
             return [self._finish(req, Response(
-                uid=req.uid, kind=EXPLAIN, logits=logits[i],
+                uid=req.uid, kind=EXPLAIN, logits=host_logits[i],
                 relevance=rel[:, i] if req.topk is not None else rel[0, i],
                 targets=tuple(int(t) for t in targets[i]), method=method,
                 batch_size=rows))

@@ -10,6 +10,8 @@ from repro.models import cnn
 from repro.serve import (CNNAdapter, ExplanationServer, MicroBatcher,
                          Request, ResidualCache, bucket_key, registry,
                          residual_bits)
+from repro.obs.trace import Tracer
+from repro.serve.adapters import slice_example
 from repro.serve.api import EXPLAIN, PREDICT
 from repro.serve.residual_cache import CacheEntry
 
@@ -409,6 +411,139 @@ def test_deconvnet_stored_masks_only_replay_deconvnet(setup):
                                   np.asarray(rel[0]))
 
 
+class _Recording:
+    """Adapter proxy that keeps every predict's (logits, residuals)."""
+
+    def __init__(self, inner):
+        self.inner, self.outputs = inner, []
+
+    def predict(self, xb):
+        out = self.inner.predict(xb)
+        self.outputs.append(out)
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
+
+
+def _predict_launch(srv, x, uids):
+    """One predict launch of ``len(uids)`` rows (submit, then drain)."""
+    for i, uid in enumerate(uids):
+        srv.submit(Request(uid=uid, kind=PREDICT, x=x[i]))
+    return {r.uid: r for r in srv.drain()}
+
+
+def _explain_group(srv, x, rows, method="guided", prefix="p"):
+    """Explains of ``rows`` (uids ``<prefix><row>``) in ONE launch."""
+    for i in rows:
+        srv.submit(Request(uid=f"{prefix}{i}", kind=EXPLAIN, x=x[i],
+                           method=method))
+    return {r.uid: r for r in srv.drain()}
+
+
+@pytest.fixture(params=["f32", "fxp16"])
+def served(request):
+    return request.getfixturevalue(
+        "setup" if request.param == "f32" else "setup_fxp")
+
+
+def test_one_row_predict_stores_the_launch_tree_by_reference(served):
+    """A 1-row predict parks the forward's own residual arrays (no slice
+    ran) and its logits as a host row."""
+    _, adapter, x = served
+    rec = _Recording(adapter)
+    srv = make_server(rec)
+    resp = srv.serve([Request(uid="a", kind=PREDICT, x=x[0])])["a"]
+    (logits, residuals), = rec.outputs
+    entry = srv.cache.peek("a")
+    assert entry.row is None
+    stored = jax.tree.leaves(entry.residuals)
+    assert len(stored) == len(jax.tree.leaves(residuals)) > 0
+    for a, b in zip(stored, jax.tree.leaves(residuals)):
+        assert a is b
+    assert isinstance(entry.logits, np.ndarray)
+    np.testing.assert_array_equal(entry.logits, np.asarray(logits)[0])
+    np.testing.assert_array_equal(np.asarray(resp.logits), entry.logits)
+
+
+def test_multi_row_predict_hits_bitwise_equal_cold(served):
+    """Hits of every row of a 4-row predict, each alone and all four in
+    one group, equal a cold explain of the same images bitwise."""
+    _, adapter, x = served
+    cold = _explain_group(make_server(adapter), x, range(4))
+    assert not any(r.cache_hit for r in cold.values())
+    singles = {}
+    for i in range(4):
+        singles.update(_explain_group(make_server(adapter), x, [i],
+                                      prefix="s"))
+    srv = make_server(adapter)
+    _predict_launch(srv, x, [f"p{i}" for i in range(4)])
+    assert [srv.cache.peek(f"p{i}").row for i in range(4)] == [0, 1, 2, 3]
+    alone = {}
+    for i in (3, 1, 2, 0):
+        alone.update(_explain_group(srv, x, [i]))
+    group = _explain_group(srv, x, range(4))
+    for i in range(4):
+        for hit in (alone[f"p{i}"], group[f"p{i}"]):
+            assert hit.cache_hit
+            np.testing.assert_array_equal(np.asarray(hit.relevance),
+                                          np.asarray(cold[f"p{i}"].relevance))
+            np.testing.assert_array_equal(np.asarray(hit.logits),
+                                          np.asarray(cold[f"p{i}"].logits))
+            np.testing.assert_array_equal(
+                np.asarray(hit.relevance),
+                np.asarray(singles[f"s{i}"].relevance))
+        assert alone[f"p{i}"].batch_size == 1 and group[f"p{i}"].batch_size == 4
+
+
+def test_entry_bits_are_one_examples(served):
+    """Entries referencing a launch still account ONE example's bits, so
+    ``bits_stored`` and ``peak_bits`` read as when each row was sliced."""
+    _, adapter, x = served
+    rec = _Recording(adapter)
+    srv = make_server(rec)
+    _predict_launch(srv, x, [f"p{i}" for i in range(3)])    # 4 rows, 3 live
+    srv.serve([Request(uid="one", kind=PREDICT, x=x[3])])
+    (_, res4), (_, res1) = rec.outputs
+    per_example = residual_bits(slice_example(res4, 0))
+    assert per_example == residual_bits(res1) > 0
+    for i in range(3):
+        assert srv.cache.peek(f"p{i}").bits == residual_bits(
+            slice_example(res4, i)) == per_example
+        assert residual_bits(srv.cache.peek(f"p{i}").residuals) == (
+            4 * per_example)                 # the tree is the launch's
+    assert srv.cache.peek("one").bits == per_example
+    st = srv.cache.stats
+    assert st.bits_stored == st.peak_bits == 4 * per_example
+    cache = ResidualCache(capacity=2)
+    for i in range(3):                       # evicts p0: bits leave with it
+        cache.put(f"p{i}", srv.cache.peek(f"p{i}"))
+    assert cache.stats.bits_stored == 2 * per_example
+    assert cache.stats.peak_bits == 3 * per_example   # read before evicting
+
+
+def test_zero_copy_share_counts_one_row_hits(served):
+    """A lone hit of a 1-row launch replays the stored tree as is; hits of
+    a multi-row launch, or any group of two or more, are copied."""
+    _, adapter, x = served
+    tracer = Tracer()
+    srv = make_server(adapter, tracer=tracer)
+    srv.serve([Request(uid="z", kind=PREDICT, x=x[0])])
+    srv.serve([Request(uid="z", kind=EXPLAIN, x=x[0], method="saliency")])
+    st = srv.cache.stats
+    assert (st.zero_copy_rows, st.copied_rows) == (1, 0)
+    assert st.snapshot()["zero_copy_share"] == 1.0
+    _predict_launch(srv, x, ["p0", "p1"])
+    _explain_group(srv, x, [1], method="saliency")            # sliced
+    _explain_group(srv, x, [0, 1], method="saliency")         # concatenated
+    assert (st.zero_copy_rows, st.copied_rows) == (1, 3)
+    assert st.snapshot()["zero_copy_share"] == 0.25
+    gathers = [s.args["copied"] for s in tracer.spans
+               if s.name == "cache.gather"]
+    assert gathers == [0, 1, 2]
+    assert [s.name for s in tracer.spans].count("cache.store") == 2
+
+
 def test_cold_bp_explain_warms_cache(setup):
     """A cold pure-BP explain stores its forward's masks: the next explain
     for the same uid (any BP method) skips the forward."""
@@ -632,3 +767,45 @@ def test_mesh_server_heatmaps_bitwise_with_single_device(setup):
         assert out_s[uid].ok and out_m[uid].ok
         np.testing.assert_array_equal(np.asarray(out_s[uid].relevance),
                                       np.asarray(out_m[uid].relevance))
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_mesh_server_multi_row_hits_bitwise_with_single_device(setup,
+                                                                shards):
+    """A 4-row predict on a mesh adapter parks its int32-word residuals by
+    reference; hits of rows other than 0 (alone, then together) slice
+    them at gather time and equal the single-device server bitwise."""
+    from repro.engine.engine import _Words
+    params, _, x = setup
+    single = CNNAdapter(params, CFG, device="edge-small")
+    meshed = CNNAdapter(params, CFG, device=f"mesh:edge-small:{shards}")
+    outs = []
+    for adapter in (single, meshed):
+        srv = make_server(adapter)
+        out = _predict_launch(srv, x, [f"p{i}" for i in range(4)])
+        for i in (1, 3):
+            out.update(_explain_group(srv, x, [i], method="saliency",
+                                      prefix="p"))
+        grp = _explain_group(srv, x, [2, 3], method="saliency")
+        out.update({f"g{uid}": r for uid, r in grp.items()})
+        assert all(r.ok for r in out.values())
+        assert all(r.cache_hit for r in out.values() if r.kind == EXPLAIN)
+        assert srv.cache.peek("p2").row == 2
+        outs.append((srv, out))
+    (_, out_s), (srv_m, out_m) = outs
+    words = jax.tree.leaves(srv_m.cache.peek("p3").residuals,
+                            is_leaf=lambda n: isinstance(n, _Words))
+    assert any(isinstance(w, _Words) for w in words)
+    cold = _explain_group(make_server(single), x, [1, 2, 3],
+                          method="saliency", prefix="c")
+    for key, i in (("p1", 1), ("p3", 3), ("gp2", 2), ("gp3", 3)):
+        np.testing.assert_array_equal(np.asarray(out_m[key].relevance),
+                                      np.asarray(cold[f"c{i}"].relevance))
+    assert out_s.keys() == out_m.keys()
+    for key in out_s:
+        np.testing.assert_array_equal(np.asarray(out_s[key].logits),
+                                      np.asarray(out_m[key].logits))
+        if out_s[key].kind == EXPLAIN:
+            np.testing.assert_array_equal(
+                np.asarray(out_s[key].relevance),
+                np.asarray(out_m[key].relevance))
