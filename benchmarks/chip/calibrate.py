@@ -30,7 +30,7 @@ import chipbench  # noqa: E402
 
 chipbench.pin_compile_cache()
 
-from chipbench import bench, compare  # noqa: E402
+from chipbench import bench  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -51,24 +51,25 @@ def main(argv=None) -> int:
         plan = prep.plan(seed, args.seconds)
         m = bench.measure(prep, plan, args.seconds, trace=False)
         failed = sum(not r.ok for r in m.window.records())
-        e2e = bench.end_to_end(prep.cell, m.window, args.seconds, 0.0)
-        served = bench.served_answers(m, plan)
+        e2e = bench.end_to_end(prep.cell, m.window, 0.0)
+        served = bench.served_answers(prep, m, plan)
         del m
         gc.collect()
         row = {"workload": args.workload, "seed": seed, "who": "program",
                "answers": len(served), "failed": failed,
-               "readings": compare.numbers(prep.params, prep.model, served),
+               "readings": prep.kind.numbers(prep.params, prep.model,
+                                             served),
                "end_to_end": {k: v["value"] for k, v in e2e.items()}}
         lines.append(row)
         print(json.dumps(row), flush=True)
         if i < args.control_seeds:
             mode = prep.cell.config["control"]
-            ctrl = compare.control_answers(prep.params, prep.model, served,
-                                           mode)
+            ctrl = prep.kind.control_answers(prep.params, prep.model,
+                                             served, mode)
             row = {"workload": args.workload, "seed": seed,
                    "who": f"control:{mode}", "answers": len(ctrl),
-                   "readings": compare.numbers(prep.params, prep.model,
-                                               ctrl)}
+                   "readings": prep.kind.numbers(prep.params, prep.model,
+                                                 ctrl)}
             lines.append(row)
             print(json.dumps(row), flush=True)
     if args.out is not None:

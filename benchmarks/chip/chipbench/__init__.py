@@ -1,8 +1,10 @@
 """The chip benchmark's yardstick: traffic, drivers, reference, reductions.
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: its
-configuration in ``configs/<config>.json``, its traffic mix in
-``traffic/<mix>.json`` and each per-layer metric's reader in
+configuration in ``configs/<config>.json``, the configuration's model kind
+in ``kinds/<kind>.py`` (the weights, the system under test, the payloads,
+the comparison with the plain reference, the work counter), its traffic
+mix in ``traffic/<mix>.json`` and each per-layer metric's reader in
 ``metrics/<metric>.py``.  From the program under test the harness takes
 only its public serving entry points and the spans and counters they keep.
 """

@@ -5,6 +5,7 @@ prepare once and measure many windows in one process.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import json
 import shutil
@@ -66,35 +67,7 @@ class _Lowerings:
             self.compiles += 1
 
 
-def _model_config(model: dict):
-    from repro.models import cnn
-    return cnn.CNNConfig(
-        in_hw=tuple(model["in_hw"]), in_ch=model["in_ch"],
-        channels=tuple(model["channels"]), kernel=model["kernel"],
-        fc=tuple(model["fc"]), num_classes=model["num_classes"],
-        conv_relu=model["conv_relu"], pool_every=model["pool_every"])
-
-
-def engine_device(config: dict, chips: int) -> str:
-    """The engine's device profile: the configuration's on one chip, and
-    the program's ``mesh:<profile>:<n>`` over it on ``n`` chips."""
-    return config["device"] if chips == 1 else (
-        f"mesh:{config['device']}:{chips}")
-
-
-def build_server_parts(config: dict, params, chips: int):
-    """The system under test: EngineSpec -> build -> CNNAdapter, at the
-    configuration's precision, on the cell's chips."""
-    from repro import engine as engine_lib
-    from repro.serve import CNNAdapter
-    eng = engine_lib.build(engine_lib.EngineSpec(
-        model=engine_lib.CNNModel(params, _model_config(config["model"])),
-        method="saliency", precision=config["precision"],
-        device=engine_device(config, chips)))
-    return CNNAdapter.from_engine(eng)
-
-
-def end_to_end(cell, window: drive.Window, seconds: float, setup_s: float):
+def end_to_end(cell, window: drive.Window, setup_s: float):
     """The cell's end-to-end metrics from the window's records."""
     miss = window.miss_latency_s()
 
@@ -105,12 +78,17 @@ def end_to_end(cell, window: drive.Window, seconds: float, setup_s: float):
         v = percentile(lat, q)
         return None if v is None else 1e3 * v
 
-    done = [r for r in window.records(drive.EXPLAIN)
-            if r.ok and r.done_t <= window.end]
+    # The rate is every explain the window's sessions asked for (none is
+    # started after the close) over the time from the window's start to
+    # the last answer.  A closed loop's clients move in rounds of a few
+    # seconds, and a count cut at the close would take the last round
+    # whole or not at all.
+    done = [r.done_t for r in window.records(drive.EXPLAIN) if r.ok]
     values = {
         "explain_p50_ms": lambda: pct(drive.EXPLAIN, 50),
         "predict_p50_ms": lambda: pct(drive.PREDICT, 50),
-        "explains_per_s": lambda: len(done) / seconds,
+        "explains_per_s": lambda: (len(done) / (max(done) - window.t0)
+                                   if done else None),
         "setup_s": lambda: setup_s,
     }
     out = {}
@@ -153,7 +131,7 @@ def _lateness(window: drive.Window) -> str:
 @dataclass
 class Prepared:
     """A cell ready to measure: the device checked, the weights made, the
-    system under test built."""
+    system under test built through the cell's model kind."""
     cell: cell_lib.Cell
     harness_dir: Path
     device: dict
@@ -166,9 +144,14 @@ class Prepared:
     def model(self) -> dict:
         return self.cell.config["model"]
 
+    @property
+    def kind(self):
+        return self.cell.kind
+
     def plan(self, seed: int, seconds: float, mix: Optional[dict] = None):
-        shape = tuple(self.model["in_hw"]) + (self.model["in_ch"],)
-        return traffic.make_plan(mix or self.cell.mix, seed, seconds, shape)
+        return traffic.make_plan(mix or self.cell.mix, seed, seconds,
+                                 functools.partial(self.kind.payloads,
+                                                   self.model))
 
 
 _LOWERED: Optional[_Lowerings] = None
@@ -200,13 +183,12 @@ def prepare(workload: str, *, bench_file: Path = ROOT / "BENCHMARK.json",
         device = {"platform": d0.platform, "kind": d0.device_kind,
                   "count": len(devices)}
     lowerings()
-    from chipbench import reference
     config = cell.config
     t = time.monotonic()
     params = jax.block_until_ready(
-        reference.init_params(config["model"], config["weight_seed"]))
+        cell.kind.init_params(config["model"], config["weight_seed"]))
     t_params = time.monotonic() - t
-    adapter = build_server_parts(config, params, cell.chips)
+    adapter = cell.kind.build_adapter(config, params, cell.chips)
     log(f"weights made in {t_params:.3f} s, engine built in "
         f"{time.monotonic() - t - t_params:.3f} s")
     return Prepared(cell=cell, harness_dir=Path(harness_dir), device=device,
@@ -224,7 +206,8 @@ def _cache_stats() -> str:
 
 def warm(prep: Prepared, plan: traffic.Plan) -> int:
     from repro.serve import ExplanationServer
-    n = drive.warm_up(ExplanationServer(prep.adapter), prep.cell.mix, plan)
+    n = drive.warm_up(ExplanationServer(prep.adapter), prep.cell.mix, plan,
+                      prep.kind.warm_payloads)
     gc.collect()
     gc.freeze()
     return n
@@ -320,7 +303,8 @@ def reduce_trace(prep: Prepared, m: Measured, dump_dir: Optional[Path] = None):
     ctx = RunContext(model=prep.model, precision=prep.cell.config["precision"],
                      chips=prep.cell.chips, shards=prep.adapter.n_shards,
                      peak=prep.peak, window=m.window, server=m.server,
-                     spans=list(m.server.tracer.spans), trace=dtrace)
+                     spans=list(m.server.tracer.spans), trace=dtrace,
+                     flops=prep.kind.flops)
     ctx.launches = launches_from_spans(ctx.spans, m.window,
                                        m.server.batcher.fill_target)
     metrics = per_layer(prep.cell, ctx, prep.harness_dir)
@@ -329,9 +313,8 @@ def reduce_trace(prep: Prepared, m: Measured, dump_dir: Optional[Path] = None):
     return metrics, tracing.busy_s(dtrace), breakdown
 
 
-def served_answers(m: Measured, plan: traffic.Plan):
-    return [compare.served_from_response(r.kind, plan.image(r.session),
-                                         r.resp)
+def served_answers(prep: Prepared, m: Measured, plan: traffic.Plan):
+    return [prep.kind.served(r.kind, plan.payload(r.session), r.resp)
             for r in m.window.records() if r.ok]
 
 
@@ -378,7 +361,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         device["busy_s"] = busy
         device["window_s"] = m.window_s
     else:
-        result["metrics"] = end_to_end(prep.cell, m.window, seconds, setup_s)
+        result["metrics"] = end_to_end(prep.cell, m.window, setup_s)
     result["device"] = device
     del recs
 
@@ -404,11 +387,11 @@ def assess(prep: Prepared, m: Measured, plan: traffic.Plan):
     the server: -> (correct, checks, all readings, answers compared).  A
     failed request, or a window with no answer, is not correct."""
     failed = sum(not r.ok for r in m.window.records())
-    served = served_answers(m, plan)
+    served = served_answers(prep, m, plan)
     m.server = m.window = None
     gc.unfreeze()
     gc.collect()
-    values = (compare.numbers(prep.params, prep.model, served)
+    values = (prep.kind.numbers(prep.params, prep.model, served)
               if served else {})
     ok, checks = compare.judge(values, prep.cell.config.get("limits", {}))
     checks["failed_requests"] = {"value": failed, "limit": 0}

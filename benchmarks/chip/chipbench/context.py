@@ -1,13 +1,13 @@
 """What a per-layer reader is given: the window's records, the server's
-counters and spans, the reduced device trace, and the launches the window
-made, rebuilt from the program's spans."""
+counters and spans, the reduced device trace, the launches the window
+made, rebuilt from the program's spans, and the model kind's module that
+counts the work (``flops``)."""
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
-import flops as flops_lib
 from chipbench import tracing
 from chipbench.drive import EXPLAIN, PREDICT, Window
 
@@ -86,8 +86,8 @@ class RunContext:
     spans: List[Any] = field(default_factory=list)
     trace: Optional[tracing.DeviceTrace] = None
     launches: List[Launch] = field(default_factory=list)
+    flops: Any = None                # the model kind's work counter module
 
-    flops = flops_lib
     tracing = tracing
 
     @property
@@ -108,7 +108,7 @@ class RunContext:
             if not rec.ok:
                 continue
             seeds = (rec.topk or 1) if rec.kind == EXPLAIN else 0
-            total += flops_lib.request_flops(
+            total += self.flops.request_flops(
                 self.model, rec.kind, seeds,
                 cold=rec.kind == EXPLAIN and not rec.resp.cache_hit)
         return float(total)

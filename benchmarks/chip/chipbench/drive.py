@@ -59,13 +59,15 @@ def _request(uid, kind, x, kind_of, due=None):
                    arrive_t=due)
 
 
-def warm_up(server, mix: dict, plan: traffic.Plan) -> int:
-    """Run every shape the mix launches, twice.  A launch stacks its live
-    requests and pads them to a power of two, so every live count up to the
-    server's seats is sent once; each padded size is then sent for every
-    (method, panel) bucket of the explains: cache hits after the predicts,
-    or cold.  Returns the number of requests served; raises SetupError on
-    any failed response."""
+def warm_up(server, mix: dict, plan: traffic.Plan, warm_payloads) -> int:
+    """Run every shape the mix launches, twice.  ``warm_payloads(plan,
+    seats)`` (the model kind's) gives, per payload shape the plan sends, as
+    many payloads of it as the server has seats.  A launch stacks its live
+    requests of one shape and pads them to a power of two, so for each
+    shape every live count up to the seats is sent once; each padded size
+    is then sent for every (method, panel) bucket of the explains: cache
+    hits after the predicts, or cold.  Returns the number of requests
+    served; raises SetupError on any failed response."""
     fill = server.batcher.fill_target
     pow2 = traffic.pad_sizes(fill)
     bucket_list = traffic.buckets(mix)
@@ -83,20 +85,22 @@ def warm_up(server, mix: dict, plan: traffic.Plan) -> int:
                              f"{bad[0].error if bad else ''}")
         served += len(out)
 
-    for rnd, n in itertools.product(range(2), range(1, fill + 1)):
-        xs = [plan.image(j) for j in range(n)]
-        kinds = bucket_list if n in pow2 else bucket_list[:1]
-        if plan.predict_first:
-            uids = [f"w{rnd}.{n}.{j}" for j in range(n)]
-            wave([_request(u, PREDICT, x, None) for u, x in zip(uids, xs)])
-            if n in pow2:
+    for g, group in enumerate(warm_payloads(plan, fill)):
+        for rnd, n in itertools.product(range(2), range(1, fill + 1)):
+            xs = group[:n]
+            kinds = bucket_list if n in pow2 else bucket_list[:1]
+            if plan.predict_first:
+                uids = [f"w{g}.{rnd}.{n}.{j}" for j in range(n)]
+                wave([_request(u, PREDICT, x, None)
+                      for u, x in zip(uids, xs)])
+                if n in pow2:
+                    for b in kinds:
+                        wave([_request(u, EXPLAIN, x, b)
+                              for u, x in zip(uids, xs)])
+            else:
                 for b in kinds:
-                    wave([_request(u, EXPLAIN, x, b)
-                          for u, x in zip(uids, xs)])
-        else:
-            for b in kinds:
-                wave([_request(f"w{rnd}.{n}.{b}.{j}", EXPLAIN, x, b)
-                      for j, x in enumerate(xs)])
+                    wave([_request(f"w{g}.{rnd}.{n}.{b}.{j}", EXPLAIN, x, b)
+                          for j, x in enumerate(xs)])
     return served
 
 
@@ -146,7 +150,7 @@ class Window:
                   method=kind_of[0] if kind == EXPLAIN else None,
                   topk=kind_of[1] if kind == EXPLAIN else None, due=due)
         self.recs[(uid, kind)] = rec
-        req = _request(uid, kind, plan.image(session), kind_of, due)
+        req = _request(uid, kind, plan.payload(session), kind_of, due)
         t = self.clock()
         rec.late_s = t - due
         try:

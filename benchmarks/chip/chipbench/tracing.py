@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import bisect
 import glob
+import heapq
 import os
 import re
 from dataclasses import dataclass, field
@@ -209,25 +210,32 @@ def top_ops(trace: DeviceTrace, n: int = 10) -> List[List]:
 def idle_gaps(trace: DeviceTrace, n: int = 10) -> List[List]:
     """Idle time of the first device grouped by what the host was doing:
     each gap between its ops is named by the shortest host event covering
-    the gap's middle (the client's annotations, the runtime's events), and
-    the ``n`` names with the most idle seconds are returned."""
+    the gap's middle (the first of those, in order of start, where several
+    are as short; the client's annotations, the runtime's events), and the
+    ``n`` names with the most idle seconds are returned.  One sweep over
+    the gaps in time order: host events join a heap by duration as they
+    start and leave it once ended, so a trace of a million host events
+    reduces in seconds."""
     if not trace.ops:
         return []
     evs = sorted(trace.ops[trace.devices[0]], key=lambda e: e.start_ns)
     host = sorted(trace.host, key=lambda h: h.start_ns)
-    starts = [h.start_ns for h in host]
-    longest = max((h.dur_ns for h in host), default=0.0)
-    by_label: Dict[str, float] = {}
+    gaps: List[Tuple[float, float]] = []     # (middle, idle ns), in order
     end = None
     for ev in evs:
         if end is not None and ev.start_ns > end:
-            mid = (end + ev.start_ns) / 2
-            lo = bisect.bisect_left(starts, mid - longest)
-            hi = bisect.bisect_right(starts, mid)
-            inside = [h for h in host[lo:hi] if h.end_ns >= mid]
-            label = (min(inside, key=lambda h: h.dur_ns).name if inside
-                     else "no host event")
-            by_label[label] = by_label.get(label, 0.0) + (ev.start_ns - end)
+            gaps.append(((end + ev.start_ns) / 2, ev.start_ns - end))
         end = ev.end_ns if end is None else max(end, ev.end_ns)
+    by_label: Dict[str, float] = {}
+    live: List[Tuple[float, int]] = []            # (duration, host index)
+    nxt = 0
+    for mid, idle in gaps:
+        while nxt < len(host) and host[nxt].start_ns <= mid:
+            heapq.heappush(live, (host[nxt].dur_ns, nxt))
+            nxt += 1
+        while live and host[live[0][1]].end_ns < mid:
+            heapq.heappop(live)
+        label = host[live[0][1]].name if live else "no host event"
+        by_label[label] = by_label.get(label, 0.0) + idle
     top = sorted(by_label.items(), key=lambda kv: -kv[1])[:n]
     return [[label, ns / 1e9] for label, ns in top]

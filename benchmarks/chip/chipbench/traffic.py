@@ -17,17 +17,18 @@ A mix (``traffic/<mix>.json``) names ``"generator": "sessions"`` and sets:
 
 A run's timing skeleton (arrival times, think times and which session asks
 for which method and panel) is drawn from the mix alone and is the same in
-every run; ``--seed`` draws the images, N(0, 1), one per session.  So every
-seed holds the same work at the same moments, and two runs differ by the
-system's own noise and by what the images make the model answer (argmax and
-top-k targets), not by where a burst falls.
+every run; ``--seed`` draws the payloads, one per session, through the
+model kind's ``payloads`` (``kinds/<kind>.py``: N(0, 1) images for the
+CNN).  So every seed holds the same work at the same moments, and two runs
+differ by the system's own noise and by what the payloads make the model
+answer (argmax and top-k targets), not by where a burst falls.
 """
 from __future__ import annotations
 
 import fractions
 import math
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -36,8 +37,8 @@ _MASK64 = (1 << 64) - 1
 SKELETON_SEED = 0
 #: Sessions pre-drawn for a closed loop; more are taken round-robin.
 CLOSED_POOL = 8192
-#: Distinct images drawn for a closed loop (sessions cycle over them).
-CLOSED_IMAGES = 1024
+#: Distinct payloads drawn for a closed loop (sessions cycle over them).
+CLOSED_PAYLOADS = 1024
 
 
 def rng(seed: int, stream: int) -> np.random.Generator:
@@ -91,11 +92,11 @@ class Plan:
     arrivals: np.ndarray            # open: offsets from window start, s
     think: np.ndarray               # per session think time, s
     kinds: List[Tuple[str, Optional[int]]]   # per session explain kind
-    images: np.ndarray              # [n_images, H, W, C] float32
+    payloads: Sequence[np.ndarray]  # per session request input (the kind's)
     clients: int = 0                # closed loop callers
 
-    def image(self, session: int) -> np.ndarray:
-        return self.images[session % len(self.images)]
+    def payload(self, session: int) -> np.ndarray:
+        return self.payloads[session % len(self.payloads)]
 
     def kind(self, session: int) -> Tuple[str, Optional[int]]:
         return self.kinds[session % len(self.kinds)]
@@ -105,7 +106,11 @@ class Plan:
 
 
 def make_plan(mix: dict, seed: int, seconds: float,
-              example_shape: Tuple[int, ...]) -> Plan:
+              payloads: Callable[[dict, int, int], Sequence[np.ndarray]]
+              ) -> Plan:
+    """The plan of one run: the timing skeleton from ``mix``, and
+    ``payloads(mix, seed, n)``'s ``n`` request inputs (a kind's
+    ``payloads`` with its model bound)."""
     if mix.get("generator") != "sessions":
         raise ValueError(f"unknown traffic generator {mix.get('generator')!r}")
     loop = mix["loop"]
@@ -123,12 +128,12 @@ def make_plan(mix: dict, seed: int, seconds: float,
                                      float(mix["idle_len_s"]))
         else:
             arrivals = unit / rate
-        n_images = n
+        n_payloads = n
         clients = 0
     elif loop == "closed":
         n = CLOSED_POOL
         arrivals = np.zeros(0)
-        n_images = CLOSED_IMAGES
+        n_payloads = CLOSED_PAYLOADS
         clients = int(mix["clients"])
     else:
         raise ValueError(f"loop must be open|closed, got {loop!r}")
@@ -137,11 +142,9 @@ def make_plan(mix: dict, seed: int, seconds: float,
              if think_mean > 0 else np.zeros(n))
     kinds = explain_kinds(n, methods, float(mix.get("panel_share", 0.0)),
                           int(mix.get("panel_k", 1)), rng(SKELETON_SEED, 3))
-    images = rng(seed, 4).standard_normal(
-        (n_images,) + tuple(example_shape), dtype=np.float32)
     return Plan(loop=loop, predict_first=bool(mix["predict_first"]),
-                arrivals=arrivals, think=think, kinds=kinds, images=images,
-                clients=clients)
+                arrivals=arrivals, think=think, kinds=kinds,
+                payloads=payloads(mix, seed, n_payloads), clients=clients)
 
 
 def buckets(mix: dict) -> List[Tuple[str, Optional[int]]]:

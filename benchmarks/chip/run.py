@@ -18,8 +18,10 @@ prints one JSON line as the last line of standard output::
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics from a profiler trace of the window and the server's
-spans.  ``--seed`` draws the images; the mix fixes the timing skeleton
-(arrivals, think times, methods and panels), the same for every seed.
+spans.  ``--seed`` draws the payloads (through the configuration's model
+kind, ``kinds/<kind>.py``: images for the CNN); the mix fixes the timing
+skeleton (arrivals, think times, methods and panels), the same for every
+seed.
 The numbers compared, each beside its limit, are also the last lines of
 standard error.
 """

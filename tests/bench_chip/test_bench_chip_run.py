@@ -69,7 +69,7 @@ def test_refuses_a_checkout_of_the_benchmark_alone(tmp_path):
                         ignore=shutil.ignore_patterns("__pycache__"))
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     proc = subprocess.run(
-        [sys.executable] + bench_json["command"]
+        [sys.executable] + bench_json["command"][1:]
         + ["--workload", "f32-sessions", "--seed", "5", "--seconds", "1",
            "--trace", "0"],
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120)
@@ -94,13 +94,14 @@ TINY_CLOSED_MIX = {"generator": "sessions", "loop": "closed", "clients": 24,
                    "panel_share": 0.5, "panel_k": 3}
 
 
-def _prepared(tmp_path_factory, chips, mix):
+def _prepared(tmp_path_factory, chips, mix, name="tiny"):
     """The f32 configuration's limits and precision on a tiny CNN, on
-    ``chips`` devices, prepared and warmed once."""
+    ``chips`` devices, as the cell ``name``, prepared and warmed once."""
     d = tmp_path_factory.mktemp("tiny")
     (d / "traffic").mkdir()
     (d / "configs").mkdir()
     os.symlink(HARNESS / "metrics", d / "metrics")
+    os.symlink(HARNESS / "kinds", d / "kinds")
     shutil.copy(HARNESS / "peaks.json", d / "peaks.json")
     config = dict(CONFIGS["paper-cnn-f32"], model=TINY_MODEL)
     (d / "configs" / "tiny.json").write_text(json.dumps(config))
@@ -108,10 +109,10 @@ def _prepared(tmp_path_factory, chips, mix):
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     spec["configs"] = [{"name": "tiny", "source": "test", "reduced": [],
                         "file": "configs/tiny.json", "why": "test"}]
-    spec["workloads"] = [{"name": "tiny", "config": "tiny", "chips": chips,
+    spec["workloads"] = [{"name": name, "config": "tiny", "chips": chips,
                           "traffic": "tiny-mix", "why": "test"}]
     (d / "BENCHMARK.json").write_text(json.dumps(spec))
-    prep = bench.prepare("tiny", bench_file=d / "BENCHMARK.json",
+    prep = bench.prepare(name, bench_file=d / "BENCHMARK.json",
                          harness_dir=d, require_accelerator=False)
     bench.warm(prep, prep.plan(0, 1.0))
     return prep
@@ -126,6 +127,32 @@ def tiny(tmp_path_factory):
 def tiny_mesh(tmp_path_factory):
     prep = _prepared(tmp_path_factory, 2, TINY_CLOSED_MIX)
     assert prep.adapter.n_shards == 2
+    return prep
+
+
+@pytest.fixture(scope="module")
+def mesh4_cell():
+    """The four-chip cell's real entry: the f32 configuration under the
+    closed-loop sessions mix, reporting its rate of explains."""
+    cell = cell_lib.load("f32-sessions-mesh4", ROOT / "BENCHMARK.json")
+    assert cell.chips == 4 and cell.kind.__name__ == "chipbench_kind_cnn"
+    assert cell.config == CONFIGS["paper-cnn-f32"]
+    mix = json.loads((HARNESS / "traffic" / "sessions-closed.json")
+                     .read_text())
+    assert cell.mix == mix and mix["loop"] == "closed"
+    assert {m["name"] for m in cell.end_to_end} >= {
+        "explains_per_s", "explain_p50_ms", "setup_s"}
+    return cell
+
+
+@pytest.fixture(scope="module")
+def tiny_mesh4(tmp_path_factory, mesh4_cell):
+    """That cell, on four devices at a tiny CNN: 32-seat launches."""
+    prep = _prepared(tmp_path_factory, mesh4_cell.chips, mesh4_cell.mix,
+                     name=mesh4_cell.name)
+    assert prep.adapter.n_shards == 4
+    assert [m["name"] for m in prep.cell.end_to_end] == [
+        m["name"] for m in mesh4_cell.end_to_end]
     return prep
 
 
@@ -191,6 +218,7 @@ def _fault(name, monkeypatch):
     ("tiny", "half_the_batch_left_out"), ("tiny", "logits_altered"),
     ("tiny", "state_of_another_request"), ("tiny", "wrong_target"),
     ("tiny_mesh", "sound"), ("tiny_mesh", "exchange_between_chips_left_out"),
+    ("tiny_mesh4", "sound"),
 ])
 def test_correct_only_when_the_timed_path_is_sound(cell, fault, monkeypatch,
                                                    request):
@@ -201,6 +229,8 @@ def test_correct_only_when_the_timed_path_is_sound(cell, fault, monkeypatch,
     if fault == "sound":     # a fault's own eager ops may compile
         assert m.lowered == 0, bench.lowerings().names[-m.lowered:]
     n_explains = len(m.window.records("explain"))
+    e2e = bench.end_to_end(prep.cell, m.window, 0.0)
+    assert set(e2e) == {x["name"] for x in prep.cell.end_to_end}
     ok, checks, values, n = bench.assess(prep, m, plan)
     assert n > 0 and n_explains > 0
     assert ok == (fault == "sound"), (fault, checks, values)

@@ -1,5 +1,7 @@
 """The trace reduction: busy union, idle share, per-family kernel time,
 program classification and launches rebuilt from the server's spans."""
+import random
+
 import pytest
 
 import _chipbench_path  # noqa: F401
@@ -96,3 +98,39 @@ def test_launches_from_spans():
         Launch("forward", 1), Launch("forward", 4),
         Launch("replay", 1, 3, "guided"), Launch("replay", 2, 3, "guided")]
     assert [pad_rows(n, 8) for n in (1, 3, 5, 9)] == [1, 4, 8, 8]
+
+
+def _idle_gaps_by_scan(trace, n=10):
+    """``idle_gaps`` by its definition: for each gap of the first device,
+    every host event is looked at; the shortest that covers the gap's
+    middle (the first in order of start among equals) names it."""
+    evs = sorted(trace.ops[trace.devices[0]], key=lambda e: e.start_ns)
+    host = sorted(trace.host, key=lambda h: h.start_ns)
+    by_label, end = {}, None
+    for ev in evs:
+        if end is not None and ev.start_ns > end:
+            mid = (end + ev.start_ns) / 2
+            inside = [h for h in host if h.start_ns <= mid <= h.end_ns]
+            label = (min(inside, key=lambda h: h.dur_ns).name if inside
+                     else "no host event")
+            by_label[label] = by_label.get(label, 0.0) + (ev.start_ns - end)
+        end = ev.end_ns if end is None else max(end, ev.end_ns)
+    top = sorted(by_label.items(), key=lambda kv: -kv[1])[:n]
+    return [[label, ns / 1e9] for label, ns in top]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_idle_gaps_names_each_gap_by_its_shortest_covering_event(seed):
+    """On random traces with nested, tied and long host events, the sweep
+    reads what looking at every host event for every gap reads."""
+    r = random.Random(seed)
+    ops = [Event(r.randrange(0, 20000, 10), r.randrange(1, 60), "op")
+           for _ in range(300)]
+    host = [Event(r.randrange(0, 20000, 10), r.choice([5, 10, 10, 40, 400]),
+                  f"h{r.randrange(12)}") for _ in range(600)]
+    host += [Event(r.randrange(0, 15000), 5000, "server.poll")
+             for _ in range(3)]
+    tr = DeviceTrace(window_s=2e-5, ops={"/device:TPU:0": ops,
+                                         "/device:TPU:1": ops[:5]},
+                     modules={}, host=host)
+    assert tracing.idle_gaps(tr, 12) == _idle_gaps_by_scan(tr, 12)

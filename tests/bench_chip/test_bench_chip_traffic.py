@@ -1,28 +1,34 @@
 """The traffic generator: deterministic per seed, the same timing skeleton
-and work in every seed, distinct images across seeds."""
+and work in every seed, distinct payloads (the CNN kind's images) across
+seeds."""
 import collections
+import functools
 
 import numpy as np
 
 import _chipbench_path  # noqa: F401
+from _chipbench_path import HARNESS
+from chipbench import cell as cell_lib
 from chipbench import traffic
 
 SESSIONS = {"generator": "sessions", "loop": "open", "arrivals": "poisson",
             "rate_per_s": 50.0, "predict_first": True, "think_mean_s": 0.5,
             "methods": ["saliency", "deconvnet", "guided"],
             "panel_share": 1 / 3, "panel_k": 3}
-SHAPE = (32, 32, 3)
+MODEL = {"in_hw": [32, 32], "in_ch": 3}
+CNN = cell_lib.load_kind("cnn", HARNESS)
 
 
 def _plan(seed, mix=SESSIONS, seconds=20.0):
-    return traffic.make_plan(mix, seed, seconds, SHAPE)
+    return traffic.make_plan(mix, seed, seconds,
+                             functools.partial(CNN.payloads, MODEL))
 
 
 def test_same_seed_same_plan():
     a, b = _plan(2**31 + 12345), _plan(2**31 + 12345)
     np.testing.assert_array_equal(a.arrivals, b.arrivals)
     np.testing.assert_array_equal(a.think, b.think)
-    np.testing.assert_array_equal(a.images, b.images)
+    np.testing.assert_array_equal(a.payloads, b.payloads)
     assert a.kinds == b.kinds
 
 
@@ -31,8 +37,8 @@ def test_seeds_share_the_skeleton_and_differ_in_images():
     np.testing.assert_array_equal(a.arrivals, b.arrivals)
     np.testing.assert_array_equal(a.think, b.think)
     assert a.kinds == b.kinds
-    assert not np.array_equal(a.images, b.images)
-    assert a.images.shape == b.images.shape
+    assert not np.array_equal(a.payloads, b.payloads)
+    assert a.payloads.shape == b.payloads.shape
 
 
 def test_open_loop_rate_and_window():
@@ -72,7 +78,7 @@ def test_closed_loop_plan():
     assert p.think_s(5) == 0.0
     assert set(p.kinds) == {("saliency", 3), ("saliency", None),
                             ("guided", 3), ("guided", None)}
-    assert len(p.images) == traffic.CLOSED_IMAGES
+    assert len(p.payloads) == traffic.CLOSED_PAYLOADS
 
 
 def test_buckets_and_pad_sizes():
