@@ -21,6 +21,7 @@ ARCHS = (
     "hymba-1.5b",
     "seamless-m4t-medium",
     "llava-next-mistral-7b",
+    "jamba2-3b",
 )
 
 

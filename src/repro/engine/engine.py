@@ -49,13 +49,16 @@ class Engine:
         self._mesh = None
         self._n_shards = 1
         if hasattr(model, "token_step"):
-            # LM token-attribution engine: one jitted FP+BP step program per
-            # score mode (the default "ixg" eagerly, others lazily), all
-            # running the resolved SSM scan plan.
+            # LM token-attribution engine: two jitted FP+BP step programs,
+            # compiled on first use, both running the resolved SSM scan
+            # plan: the argmax-seeded one gives the ixg and grad_norm
+            # scores of one BP, the other the contrastive ones.
             self._plan = spec.resolve_plan()
-            self._token_step = jax.jit(
-                model.token_step(spec.method, plan=self._plan))
-            self._token_steps: Dict[str, Any] = {"ixg": self._token_step}
+            self._token_step = {
+                c: jax.jit(model.token_step(spec.method, plan=self._plan,
+                                            precision=spec.precision,
+                                            contrastive=c))
+                for c in (False, True)}
             self._backend: Optional[BackwardEngine] = None
             self._model_fn = None
             return
@@ -409,19 +412,20 @@ class Engine:
 
         ``mode`` picks the per-token score reduction (``ixg`` input x
         gradient, ``grad_norm`` saliency norm, ``contrastive``
-        argmax-vs-runner-up); each mode is one jitted step program,
-        compiled on first use and sharing the engine's resolved SSM scan
-        plan."""
+        argmax-vs-runner-up).  ``ixg`` and ``grad_norm`` are two
+        reductions of one jitted step program, ``contrastive`` another;
+        each compiles on first use per batch shape and runs the engine's
+        resolved SSM scan plan."""
         if self._token_step is None:
             raise ValueError(
                 f"{type(self.spec.model).__name__} engines explain arrays; "
                 f"explain_tokens needs an LMModel spec")
-        step = self._token_steps.get(mode)
-        if step is None:
-            step = jax.jit(self.spec.model.token_step(
-                self.spec.method, plan=self._plan, mode=mode))
-            self._token_steps[mode] = step
-        return step(batch)
+        from repro.launch.steps import TOKEN_MODES
+        if mode not in TOKEN_MODES:
+            raise ValueError(f"mode={mode!r} not in {TOKEN_MODES}")
+        logits, scores = self._token_step[mode == "contrastive"](
+            self.spec.model.params, batch)
+        return logits, scores[mode]
 
     # -- internals -----------------------------------------------------------
 

@@ -195,30 +195,57 @@ class LMModel(_ParamsIdentity):
         return False            # vjp-only: no manual residual pair for LMs
 
     def token_step(self, method: str, *, plan=None,
-                   mode: str = "ixg") -> Callable:
-        """``(batch) -> (last-position logits [B, V], scores [B, S])``.
+                   precision: str = "f32",
+                   contrastive: bool = False) -> Callable:
+        """``(params, batch) -> (last-position logits [B, V], {mode:
+        scores [B, S]})``: the argmax-seeded step gives the ``ixg`` and
+        ``grad_norm`` reductions of one BP, the ``contrastive`` step the
+        argmax-minus-runner-up one.
 
         ``method`` must be a gradient rule set (perturbation methods are
         forward-only over pixel grids — there is no token BP to run).
         ``plan`` threads a ``plan_lm`` TilePlan's ``(d_tile, chunk)`` knobs
-        into the SSM Pallas scan launches; ``mode`` picks the per-token
-        score reduction (``ixg | grad_norm | contrastive`` — see
-        :func:`repro.launch.steps.make_attribute_step`).
+        into the SSM Pallas scan launches.  The weights are an argument,
+        so a compiled program never carries them as constants.
+
+        ``precision="f32"`` computes in float32 with every dot and einsum
+        at ``Precision.HIGHEST`` (a TPU otherwise runs an f32 dot as one
+        bfloat16 pass); ``"bf16"`` keeps the configuration's dtype and the
+        backend's default dots.
         """
         if method not in RULE_SETS:
             raise ValueError(
                 f"token attribution needs a gradient rule set {RULE_SETS}; "
                 f"method={method!r} has no token BP")
+        if precision not in ("f32", "bf16"):
+            raise ValueError(f"LM token attribution runs f32|bf16, got "
+                             f"precision={precision!r}")
         from repro.launch import steps as steps_lib
-        step = steps_lib.make_attribute_step(
-            self.cfg, method, triangle_skip=self.triangle_skip,
-            plan=plan, mode=mode)
-        params = self.params
+        step = steps_lib.make_token_step(
+            lm_compute_cfg(self.cfg, precision), method,
+            triangle_skip=self.triangle_skip, plan=plan,
+            contrastive=contrastive)
+        return lm_precision(step, precision)
 
-        def run(batch):
-            return step(params, batch)
 
-        return run
+def lm_compute_cfg(cfg, precision: str):
+    """The model configuration an LM program computes in: float32 under
+    ``precision="f32"``, the configuration's own dtype otherwise."""
+    return cfg.with_(dtype="float32") if precision == "f32" else cfg
+
+
+def lm_precision(fn: Callable, precision: str) -> Callable:
+    """``fn`` traced with every dot at ``Precision.HIGHEST`` under
+    ``precision="f32"`` (the TPU's default is one bfloat16 pass)."""
+    if precision != "f32":
+        return fn
+
+    def run(*args):
+        import jax
+        with jax.default_matmul_precision("highest"):
+            return fn(*args)
+
+    return run
 
 
 @dataclass(frozen=True, eq=False)
@@ -394,8 +421,8 @@ class EngineSpec:
             # LM handle: plan the SSM scan chunking (dense stacks have no
             # planned Pallas kernel — None keeps the default launches).
             cfg = self.model.cfg
-            if not any(k in ("mamba", "hybrid")
-                       for k, _, _ in cfg.layer_plan()):
+            from repro.models.config import SSM_KINDS
+            if not any(k in SSM_KINDS for k, _, _ in cfg.layer_plan()):
                 return None
             from repro.plan import LM_PLAN_SEQ, TuningCache, plan_lm
             return plan_lm(cfg, device=self.device, precision=self.precision,

@@ -1,9 +1,9 @@
 """Jit'd selective-scan wrapper.
 
-Forward runs the Pallas state-stationary kernel; the backward falls back to
-autodiff over the jnp reference recurrence (attribution and training through
-SSM blocks differentiate the pure-JAX chunked scan in mamba.py; this kernel
-is the serving/prefill hot-path).
+Forward runs the Pallas state-stationary kernel, which also keeps the
+state entering each chunk; the backward is the scan's adjoint recurrence,
+the Pallas kernel ``ssm_scan_bwd``: it recomputes one chunk's states from
+the kept one and walks the chunk backwards, last chunk first.
 
 ``d_tile``/``chunk`` are the planner's knobs (``repro.plan.ScanTile``): how
 many channels ride one grid cell and how many timesteps one sequential chunk
@@ -20,8 +20,8 @@ import functools
 
 import jax
 
-from repro.kernels.ssm_scan import ref
-from repro.kernels.ssm_scan.ssm_scan import selective_scan_pallas
+from repro.kernels.ssm_scan.ssm_scan import (selective_scan_bwd_pallas,
+                                             selective_scan_pallas)
 
 _DEFAULT_D_TILE = 256
 _DEFAULT_CHUNK = 64
@@ -35,11 +35,18 @@ def _knobbed(d_tile: int, chunk: int):
                                      d_tile=d_tile, chunk=chunk)
 
     def _fwd(dt, x, bmat, cmat, a, h0):
-        return scan(dt, x, bmat, cmat, a, h0), (dt, x, bmat, cmat, a, h0)
+        y, h_last, starts = selective_scan_pallas(
+            dt, x, bmat, cmat, a, h0, d_tile=d_tile, chunk=chunk,
+            with_starts=True)
+        return (y, h_last), (dt, x, bmat, cmat, a, h0, starts)
 
     def _bwd(res, g):
-        _, vjp = jax.vjp(lambda *args: ref.selective_scan(*args), *res)
-        return vjp(g)
+        dt, x, bmat, cmat, a, h0, starts = res
+        gy, gh = g
+        grads = selective_scan_bwd_pallas(dt, x, bmat, cmat, a, starts, gy,
+                                          gh, chunk=chunk, d_tile=d_tile)
+        return tuple(gv.astype(v.dtype) for gv, v in
+                     zip(grads, (dt, x, bmat, cmat, a, h0)))
 
     scan.defvjp(_fwd, _bwd)
     return scan

@@ -21,7 +21,7 @@ from repro.dist import params as dist_params
 from repro.engine import methods as engine_methods
 from repro.dist.sharding import physical_spec
 from repro.models import transformer as tf
-from repro.models.config import ModelConfig
+from repro.models.config import SSM_KINDS, ModelConfig
 from repro.optim import adamw_init, adamw_update, clip_by_global_norm, cosine_schedule
 
 _KEEP_F32 = ("A_log", "dt_bias", "D")   # SSM dynamics: stay f32 in compute
@@ -167,12 +167,50 @@ def ssm_scan_tiles(cfg: ModelConfig, plan=None):
     """
     tiles = {}
     for si, (kind, _, _) in enumerate(cfg.layer_plan()):
-        if kind not in ("mamba", "hybrid"):
+        if kind not in SSM_KINDS:
             continue
         t = plan.get(f"ssm{si}.scan") if plan is not None else None
         tiles[si] = ((t.d_tile, t.chunk) if t is not None
                      else (cfg.d_inner, cfg.ssm_chunk))
     return tiles or None
+
+
+def make_token_step(cfg: ModelConfig, method: str = "saliency", *,
+                    triangle_skip: bool = True, plan=None,
+                    contrastive: bool = False):
+    """``(params, batch) -> (last-position logits [B, V], {mode: [B, S]})``:
+    one FP + input-gradient BP, reduced per prompt position.  A prompt's
+    left padding (:data:`repro.models.transformer.PAD_ID`) is masked out
+    of every mixer, so its positions score zero.
+
+    The argmax seed gives both gradient reductions from the one BP
+    (``ixg`` and ``grad_norm``); ``contrastive`` seeds argmax minus
+    runner-up and gives ``contrastive``.  Only the last position's head
+    is computed, and each layer is rematerialised by ``cfg.remat``.
+    """
+    scan_tiles = ssm_scan_tiles(cfg, plan)
+
+    def token_step(params, batch):
+        h = tf.embed_inputs(params, cfg, batch)
+        enc_frames = batch.get("frames")
+        live = tf.live_positions(batch["tokens"], h.shape[1])
+
+        def f(e):
+            return tf.forward_from_embeddings(
+                params, cfg, e, method=method, enc_frames=enc_frames,
+                triangle_skip=triangle_skip, scan_tiles=scan_tiles,
+                last_only=True, live=live)[0]
+
+        if contrastive:
+            logits, _, scores = engine_methods.attribute_tokens_contrastive(
+                f, h)
+            return logits[:, -1, :], {"contrastive": scores}
+        logits, rel, scores = attribution.attribute_tokens(f, h)
+        return logits[:, -1, :], {
+            "ixg": scores,
+            "grad_norm": jnp.linalg.norm(rel.astype(jnp.float32), axis=-1)}
+
+    return token_step
 
 
 def make_attribute_step(cfg: ModelConfig, method: str = "saliency", *,
@@ -196,26 +234,12 @@ def make_attribute_step(cfg: ModelConfig, method: str = "saliency", *,
     """
     if mode not in TOKEN_MODES:
         raise ValueError(f"mode={mode!r} not in {TOKEN_MODES}")
-    scan_tiles = ssm_scan_tiles(cfg, plan)
+    step = make_token_step(cfg, method, triangle_skip=triangle_skip,
+                           plan=plan, contrastive=mode == "contrastive")
 
     def attribute_step(params, batch):
-        h = tf.embed_inputs(params, cfg, batch)
-        enc_frames = batch.get("frames")
-
-        def f(e):
-            return tf.forward_from_embeddings(
-                params, cfg, e, method=method, enc_frames=enc_frames,
-                remat=False, triangle_skip=triangle_skip,
-                scan_tiles=scan_tiles)[0]
-
-        if mode == "contrastive":
-            logits, rel, scores = engine_methods.attribute_tokens_contrastive(
-                f, h)
-        else:
-            logits, rel, scores = attribution.attribute_tokens(f, h)
-            if mode == "grad_norm":
-                scores = jnp.linalg.norm(rel.astype(jnp.float32), axis=-1)
-        return logits[:, -1, :], scores
+        logits, scores = step(params, batch)
+        return logits, scores[mode]
 
     return attribute_step
 

@@ -30,11 +30,12 @@ import jax
 import jax.numpy as jnp
 
 from repro import engine as engine_lib
+from repro.models import transformer as tf
 
-#: Token id LEFT-padding fills with.  The stacks are unmasked, so padding
-#: shifts absolute positions — an approximation the pow2 length grid bounds
-#: (a request is padded at most to the next bucket, never arbitrarily).
-PAD_ID = 0
+#: Token id LEFT-padding fills with.  The token programs mask the leading
+#: run of it out of every mixer (``transformer.live_positions``), so a
+#: padded prompt's live positions compute what the unpadded prompt's do.
+PAD_ID = tf.PAD_ID
 
 #: Smallest sequence bucket; shorter requests pad up to it.
 MIN_BUCKET = 8
@@ -54,8 +55,8 @@ def pad_tokens(tokens, length: Optional[int] = None, pad_id: int = PAD_ID):
     (default: its :func:`bucket_len`).
 
     Left padding keeps the live tokens adjacent to the explained position
-    (the final one); the per-position scores of the padded prefix are
-    reported but meaningless, exactly like a padded batch row.
+    (the final one); the token programs mask the padded prefix, whose
+    per-position scores are reported as zeros.
     """
     t = jnp.asarray(tokens, jnp.int32)
     s = t.shape[-1]
@@ -73,13 +74,20 @@ class LMAdapter:
 
     input_kind = "tokens"
 
+    #: token id the prompts are left-padded with
+    pad_id = PAD_ID
+
     def __init__(self, params, cfg, *, store_rules: str = "saliency",
                  precision: str = "f32", device: Optional[str] = None,
-                 autotune: bool = False):
+                 autotune: bool = False, max_batch: Optional[int] = None):
         self.params = params
         self.cfg = cfg
         self.store_rules = store_rules
         self.precision = precision
+        #: the server's seats per launch (None: the server's default); a
+        #: long-context model sizes it so the longest bucket's launch fits
+        #: beside the weights
+        self.max_batch = max_batch
         # The base engine: resolves the SSM scan plan for ``device`` once;
         # per-rule siblings share it via the global build cache.
         self.engine = engine_lib.build(engine_lib.EngineSpec(
@@ -97,6 +105,7 @@ class LMAdapter:
         self.cfg = spec.model.cfg
         self.store_rules = spec.method
         self.precision = spec.precision
+        self.max_batch = None
         self.engine = eng
         self._engines = {spec.method: eng}
         self._predict = None
@@ -116,7 +125,9 @@ class LMAdapter:
     def with_precision(self, precision: str) -> "LMAdapter":
         eng = engine_lib.build(replace(self.engine.spec,
                                        precision=precision))
-        return LMAdapter.from_engine(eng)
+        out = LMAdapter.from_engine(eng)
+        out.max_batch = self.max_batch
+        return out
 
     def engine_for(self, rules: str) -> engine_lib.Engine:
         if rules not in self._engines:
@@ -134,16 +145,20 @@ class LMAdapter:
         ``mask_reuse=False`` and never look).
         """
         if self._predict is None:
-            from repro.models import transformer as tf
-            params, cfg, method = self.params, self.cfg, self.store_rules
+            from repro.engine.spec import lm_compute_cfg, lm_precision
+            cfg = lm_compute_cfg(self.cfg, self.precision)
+            method = self.store_rules
 
-            def run(tokens):
-                logits, _ = tf.forward(params, cfg, {"tokens": tokens},
-                                       method=method, remat=False)
+            def run(params, tokens):
+                h = tf.embed_inputs(params, cfg, {"tokens": tokens})
+                logits, _ = tf.forward_from_embeddings(
+                    params, cfg, h, method=method, remat=False,
+                    last_only=True,
+                    live=tf.live_positions(tokens, h.shape[1]))
                 return logits[:, -1, :]
 
-            self._predict = jax.jit(run)
-        return self._predict(xb), None
+            self._predict = jax.jit(lm_precision(run, self.precision))
+        return self._predict(self.params, xb), None
 
     def explain_cached(self, method: str, residuals, seeds):
         raise ValueError(

@@ -11,6 +11,9 @@ from typing import Tuple
 
 import jax.numpy as jnp
 
+#: block kinds that run a Mamba-1 mixer (and so the SSM scan)
+SSM_KINDS = ("mamba", "mamba_ffn", "hybrid")
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -28,6 +31,7 @@ class ModelConfig:
     norm: str = "rmsnorm"       # rmsnorm | layernorm
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope: bool = True                 # False: attention sees no positions
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
 
@@ -45,6 +49,11 @@ class ModelConfig:
     ssm_conv: int = 4
     dt_rank: int = 0                  # 0 -> ceil(d_model / 16)
     ssm_chunk: int = 128              # chunked selective-scan length
+    ssm_inner_norm: bool = False      # RMSNorm dt/B/C after x_proj (jamba)
+
+    # --- interleaved attention in an ssm stack (jamba) ---
+    attn_period: int = 0              # layer i is attention when
+    attn_offset: int = 0              # i % attn_period == attn_offset
 
     # --- hybrid (hymba) ---
     swa_window: int = 0               # 0 = full attention
@@ -90,7 +99,7 @@ class ModelConfig:
 
     @property
     def attention_free(self) -> bool:
-        return self.family == "ssm"
+        return self.family == "ssm" and not self.attn_period
 
     @property
     def sub_quadratic(self) -> bool:
@@ -98,8 +107,15 @@ class ModelConfig:
         return self.family in ("ssm", "hybrid")
 
     def block_kind(self, layer: int) -> str:
+        """``dense`` attention + FFN, ``moe``, ``hybrid`` (hymba's parallel
+        heads), ``mamba`` (mixer only, falcon-mamba) or ``mamba_ffn``
+        (mixer, then an FFN: jamba's Mamba layers).  An ssm stack with an
+        ``attn_period`` puts a dense layer at every ``attn_offset``."""
         if self.family == "ssm":
-            return "mamba"
+            if (self.attn_period
+                    and layer % self.attn_period == self.attn_offset):
+                return "dense"
+            return "mamba_ffn" if self.d_ff else "mamba"
         if self.family == "hybrid":
             return "hybrid"
         if self.n_experts > 0 and layer >= self.first_dense:
@@ -148,6 +164,12 @@ class ModelConfig:
             total += 2 * d  # norms
             if kind == "mamba":
                 total += mamba
+            elif kind == "mamba_ffn":
+                # + the conv bias (every mamba block holds one; the
+                # falcon-mamba count above leaves it out) and the dt/B/C
+                # norms
+                inner = (dtr + 2 * n) if self.ssm_inner_norm else 0
+                total += mamba + di + inner + dense_ffn
             elif kind == "hybrid":
                 total += attn + mamba + dense_ffn + 2 * d
             elif kind == "moe":

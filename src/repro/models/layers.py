@@ -144,8 +144,10 @@ def _head_layout(q, k4, v4, g: int):
     return q, k4, v4
 
 
-def _sdpa_full(q, k, v, *, q_pos, k_pos, causal: bool, window: int):
-    """q [B,S,N,hd], k/v [B,T,N,hd] (kv already repeated to N heads).
+def _sdpa_full(q, k, v, *, q_pos, k_pos, causal: bool, window: int,
+               k_live=None):
+    """q [B,S,N,hd], k/v [B,T,N,hd] (kv already repeated to N heads);
+    ``k_live`` [B,T] (bool) masks the keys of padded positions.
 
     Head-sharded: N lives on the "model" axis, so neither einsum contracts a
     sharded dim — zero attention collectives. (The previous grouped form let
@@ -160,7 +162,10 @@ def _sdpa_full(q, k, v, *, q_pos, k_pos, causal: bool, window: int):
         mask &= k_pos[None, :] <= q_pos[:, None]
     if window > 0:
         mask &= k_pos[None, :] > q_pos[:, None] - window
-    s = jnp.where(mask[None, None], s, -1e30)
+    mask = mask[None, None]
+    if k_live is not None:
+        mask = mask & k_live[:, None, None, :]
+    s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bnst,btnh->bsnh", p.astype(v.dtype), _grad_cast(v),
                    preferred_element_type=jnp.float32)
@@ -168,7 +173,7 @@ def _sdpa_full(q, k, v, *, q_pos, k_pos, causal: bool, window: int):
 
 
 def _sdpa_chunked(q, k, v, *, q_pos, k_pos, causal: bool, window: int,
-                  qc: int, kc: int, triangle_skip: bool):
+                  qc: int, kc: int, triangle_skip: bool, k_live=None):
     """Flash-style double-chunked attention, online softmax, f32 running stats.
 
     q [B,S,N,hd], k/v [B,T,N,hd] (kv repeated to N heads — head-sharded, see
@@ -205,6 +210,8 @@ def _sdpa_chunked(q, k, v, *, q_pos, k_pos, causal: bool, window: int,
         kk = k[:, t_lo: t_lo + nk * kc] if t_lo + nk * kc <= t else k[:, t_lo:]
         vv = v[:, t_lo: t_lo + nk * kc] if t_lo + nk * kc <= t else v[:, t_lo:]
         kpos_band = k_pos[t_lo: t_lo + kk.shape[1]]
+        live_band = (k_live[:, t_lo: t_lo + kk.shape[1]]
+                     if k_live is not None else None)
 
         def body(carry, j):
             m, l, acc = carry
@@ -218,7 +225,11 @@ def _sdpa_chunked(q, k, v, *, q_pos, k_pos, causal: bool, window: int,
                 msk &= kp[None, :] <= qp[:, None]
             if window > 0:
                 msk &= kp[None, :] > qp[:, None] - window
-            s = jnp.where(msk[None, None], s, -1e30)
+            msk = msk[None, None]
+            if live_band is not None:
+                msk = msk & jax.lax.dynamic_slice_in_dim(
+                    live_band, j * kc, kc, axis=1)[:, None, None, :]
+            s = jnp.where(msk, s, -1e30)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             corr = jnp.exp(m - m_new)
             e = jnp.exp(s - m_new[..., None])
@@ -239,12 +250,14 @@ def _sdpa_chunked(q, k, v, *, q_pos, k_pos, causal: bool, window: int,
 
 def attention(p, x, cfg, *, rope_cs=None, causal=True, window=0,
               cache=None, pos=None, kv_override=None, method="autodiff",
-              chunked=None, triangle_skip=True):
+              chunked=None, triangle_skip=True, k_live=None):
     """GQA attention, all modes.
 
     cache: optional dict {"k","v": [B, Tcap, Kv*hd]} (fused layout).  With
     ``pos`` (scalar) given, runs single-token decode and returns updated cache.
     kv_override: (k4, v4) from a cross-attention source.
+    k_live: optional [B, T] bool, False at the keys of padded positions
+    (full-sequence passes only): no query attends to them.
     """
     b, s, _ = x.shape
     hd, hq, kvh = cfg.hd, cfg.n_heads, cfg.n_kv
@@ -314,10 +327,10 @@ def attention(p, x, cfg, *, rope_cs=None, causal=True, window=0,
             o = _sdpa_chunked(qh, kh, vh, q_pos=q_pos, k_pos=k_pos,
                               causal=causal, window=window,
                               qc=min(cfg.attn_chunk, s), kc=min(cfg.attn_chunk, t),
-                              triangle_skip=triangle_skip)
+                              triangle_skip=triangle_skip, k_live=k_live)
         else:
             o = _sdpa_full(qh, kh, vh, q_pos=q_pos, k_pos=k_pos,
-                           causal=causal, window=window)
+                           causal=causal, window=window, k_live=k_live)
 
     o2 = o.reshape(b, s, hq * hd)
     o2 = constrain(o2, "batch", None, "model")

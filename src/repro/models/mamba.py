@@ -29,7 +29,7 @@ def init_mamba(key, cfg):
     ks = jax.random.split(key, 6)
     # S4-style A init: -[1..N] per channel
     a = jnp.tile(jnp.arange(1, n + 1, dtype=jnp.float32)[None, :], (di, 1))
-    return {
+    p = {
         "in_proj": layers.dense_init(ks[0], d, 2 * di, cfg.jdtype),
         "conv_w": (jax.random.normal(ks[1], (cfg.ssm_conv, di), jnp.float32)
                    * (1.0 / cfg.ssm_conv)).astype(cfg.jdtype),
@@ -41,6 +41,11 @@ def init_mamba(key, cfg):
         "D": jnp.ones((di,), jnp.float32),
         "out_proj": layers.dense_init(ks[4], di, d, cfg.jdtype),
     }
+    if cfg.ssm_inner_norm:
+        p["dt_norm"] = layers.norm_init(dtr, "rmsnorm")
+        p["b_norm"] = layers.norm_init(n, "rmsnorm")
+        p["c_norm"] = layers.norm_init(n, "rmsnorm")
+    return p
 
 
 def _causal_conv(x, w, b, state: Optional[jnp.ndarray] = None):
@@ -78,7 +83,7 @@ def _chunk_scan(abar, bx, h0):
 
 def mamba_core(p, x, cfg, method="autodiff",
                state: Optional[dict] = None, pos=None,
-               use_pallas: bool = False, scan_tile=None):
+               use_pallas: bool = False, scan_tile=None, live=None):
     """x: [B, S, d] -> (out [B, S, d], new_state|None).
 
     state = {"h": [B, di, N] f32, "conv": [B, k-1, di]} for decode.
@@ -90,6 +95,10 @@ def mamba_core(p, x, cfg, method="autodiff",
     Pallas path; grid splits are bitwise-neutral for the scan, so a planned
     launch computes the same bits as the default one); ``use_pallas=True``
     alone keeps the kernel's default knobs.
+
+    ``live`` [B, S] (bool) is False at the positions of left padding: the
+    conv and the scan see zeros there, so the state reaching the first
+    live position is the state of an unpadded sequence.
     """
     b, s, d = x.shape
     di, n = cfg.d_inner, cfg.ssm_state
@@ -97,13 +106,22 @@ def mamba_core(p, x, cfg, method="autodiff",
     xz = x @ p["in_proj"]
     xz = constrain(xz, "batch", None, "model")
     xin, z = jnp.split(xz, 2, axis=-1)
+    keep = None if live is None else live[..., None].astype(xin.dtype)
+    if keep is not None:
+        xin = xin * keep
 
     conv_state = state["conv"] if state is not None else None
     xc, new_conv = _causal_conv(xin, p["conv_w"], p["conv_b"], conv_state)
     xc = rules.act(xc, "silu", method, cfg.residual_policy)
+    if keep is not None:
+        xc = xc * keep
 
     bcdt = xc @ p["x_proj"]                               # [B, S, dtr+2N]
     dt_r, bmat, cmat = jnp.split(bcdt, [cfg.dtr, cfg.dtr + n], axis=-1)
+    if cfg.ssm_inner_norm:                                # jamba
+        dt_r = layers.apply_norm(p["dt_norm"], dt_r, "rmsnorm")
+        bmat = layers.apply_norm(p["b_norm"], bmat, "rmsnorm")
+        cmat = layers.apply_norm(p["c_norm"], cmat, "rmsnorm")
     dt = jax.nn.softplus((dt_r @ p["dt_proj"]).astype(jnp.float32)
                          + p["dt_bias"])                  # [B, S, di] f32
     a = -jnp.exp(p["A_log"])                              # [di, N]

@@ -31,6 +31,10 @@ from repro.dist.sharding import constrain
 from repro.models import layers, mamba, moe
 from repro.models.config import ModelConfig
 
+#: Token id a prompt is LEFT-padded with to its length bucket
+#: (``repro.lm.pad_tokens``).
+PAD_ID = 0
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -39,15 +43,16 @@ from repro.models.config import ModelConfig
 def _init_block(key, cfg: ModelConfig, kind: str, cross: bool = False):
     ks = jax.random.split(key, 8)
     p = {"norm1": layers.norm_init(cfg.d_model, cfg.norm)}
-    if kind == "mamba":
+    if kind in ("mamba", "mamba_ffn"):
         p["mixer"] = mamba.init_mamba(ks[0], cfg)
-        return p
+        if kind == "mamba":
+            return p
     if kind == "hybrid":
         p["attn"] = layers.init_attention(ks[0], cfg)
         p["ssm"] = mamba.init_mamba(ks[1], cfg)
         p["norm_attn"] = layers.norm_init(cfg.d_model, cfg.norm)
         p["norm_ssm"] = layers.norm_init(cfg.d_model, cfg.norm)
-    else:
+    elif kind != "mamba_ffn":
         p["attn"] = layers.init_attention(ks[0], cfg)
     p["norm2"] = layers.norm_init(cfg.d_model, cfg.norm)
     if kind == "moe":
@@ -108,29 +113,34 @@ def _cross_attend(p, x, cfg, cache, enc_out, method):
 
 def _block(p, x, cfg, kind: str, *, rope_cs, window: int, method: str,
            cache=None, pos=None, enc_out=None, causal=True,
-           triangle_skip=True, scan_tile=None):
-    """One layer. Returns (x, new_cache_slice, aux)."""
+           triangle_skip=True, scan_tile=None, live=None):
+    """One layer. Returns (x, new_cache_slice, aux).  ``live`` [B, S]
+    (bool) keeps left padding out of every mixer (see
+    :func:`forward_from_embeddings`)."""
     aux = jnp.zeros((), jnp.float32)
     h = layers.apply_norm(p["norm1"], x, cfg.norm)
     new_cache = cache
 
-    if kind == "mamba":
-        out, new_state = mamba.mamba_core(p["mixer"], h, cfg, method,
-                                          state=cache, pos=pos,
-                                          scan_tile=scan_tile)
-        return x + out, new_state, aux
-
-    if kind == "hybrid":
+    if kind in ("mamba", "mamba_ffn"):
+        with jax.named_scope("mamba"):
+            out, new_cache = mamba.mamba_core(p["mixer"], h, cfg, method,
+                                              state=cache, pos=pos,
+                                              scan_tile=scan_tile, live=live)
+        x = x + out
+        if kind == "mamba":
+            return x, new_cache, aux
+    elif kind == "hybrid":
         attn_cache = cache["attn"] if cache is not None else None
         ssm_state = cache["ssm"] if cache is not None else None
         a = layers.attention(p["attn"], h, cfg, rope_cs=rope_cs, causal=causal,
                              window=window, cache=attn_cache, pos=pos,
-                             method=method, triangle_skip=triangle_skip)
+                             method=method, triangle_skip=triangle_skip,
+                             k_live=live)
         if attn_cache is not None:
             a, attn_cache = a
         sout, ssm_state = mamba.mamba_core(p["ssm"], h, cfg, method,
                                            state=ssm_state, pos=pos,
-                                           scan_tile=scan_tile)
+                                           scan_tile=scan_tile, live=live)
         # hymba: mean of per-branch-normalized outputs
         mix = 0.5 * (layers.apply_norm(p["norm_attn"], a, cfg.norm)
                      + layers.apply_norm(p["norm_ssm"], sout, cfg.norm))
@@ -141,9 +151,11 @@ def _block(p, x, cfg, kind: str, *, rope_cs, window: int, method: str,
         self_cache = None
         if cache is not None:
             self_cache = {"k": cache["k"], "v": cache["v"]}
-        a = layers.attention(p["attn"], h, cfg, rope_cs=rope_cs, causal=causal,
-                             window=window, cache=self_cache, pos=pos,
-                             method=method, triangle_skip=triangle_skip)
+        with jax.named_scope("attention"):
+            a = layers.attention(p["attn"], h, cfg, rope_cs=rope_cs,
+                                 causal=causal, window=window,
+                                 cache=self_cache, pos=pos, method=method,
+                                 triangle_skip=triangle_skip, k_live=live)
         if self_cache is not None:
             a, self_cache = a
         x = x + a
@@ -164,7 +176,8 @@ def _block(p, x, cfg, kind: str, *, rope_cs, window: int, method: str,
     if kind == "moe":
         f, aux = moe.moe_ffn(p["ffn"], h2, cfg, method)
     else:
-        f = layers.ffn(p["ffn"], h2, cfg, method)
+        with jax.named_scope("ffn"):
+            f = layers.ffn(p["ffn"], h2, cfg, method)
     return x + f, new_cache, aux
 
 
@@ -184,7 +197,7 @@ def _remat(fn, cfg):
 
 def _run_segments(params, cfg, x, *, rope_cs, method, caches=None, pos=None,
                   enc_out=None, causal=True, remat=True, triangle_skip=True,
-                  scan_tiles=None):
+                  scan_tiles=None, live=None):
     """Scan each homogeneous segment; returns (x, new_caches, aux_total).
 
     ``scan_tiles`` is an optional per-SEGMENT dict ``{si: (d_tile, chunk)}``
@@ -209,7 +222,7 @@ def _run_segments(params, cfg, x, *, rope_cs, method, caches=None, pos=None,
                                  window=window, method=method, cache=lc,
                                  pos=pos, enc_out=enc_out, causal=causal,
                                  triangle_skip=triangle_skip,
-                                 scan_tile=seg_tile)
+                                 scan_tile=seg_tile, live=live)
             return (xx, aux_acc + aux), nc
 
         fn = _remat(body, cfg) if remat else body
@@ -223,6 +236,14 @@ def _run_segments(params, cfg, x, *, rope_cs, method, caches=None, pos=None,
 # ---------------------------------------------------------------------------
 # embeddings / frontends (stubs per assignment: precomputed embeddings)
 # ---------------------------------------------------------------------------
+
+
+def _rope(cfg, s: int):
+    """RoPE tables for ``s`` positions, or None where attention sees no
+    positions (jamba: the Mamba layers carry the order)."""
+    if not cfg.rope:
+        return None
+    return layers.rope_tables(jnp.arange(s), cfg.hd, cfg.rope_theta)
 
 
 def embed_inputs(params, cfg, batch: Dict, method="autodiff"):
@@ -242,8 +263,7 @@ def encode(params, cfg, frames, method="autodiff"):
     """Bidirectional encoder over stub frame embeddings -> [B, S_src, d]."""
     x = frames.astype(cfg.jdtype)
     x = constrain(x, "batch", None, None)
-    rope_cs = layers.rope_tables(jnp.arange(x.shape[1]), cfg.hd,
-                                 cfg.rope_theta)
+    rope_cs = _rope(cfg, x.shape[1])
 
     def body(carry, lp):
         xx = carry
@@ -267,25 +287,46 @@ def encode(params, cfg, frames, method="autodiff"):
 
 def forward_from_embeddings(params, cfg: ModelConfig, h, *, method="autodiff",
                             enc_frames=None, remat=True, causal=True,
-                            triangle_skip=True, scan_tiles=None):
+                            triangle_skip=True, scan_tiles=None,
+                            last_only=False, live=None):
     """Backbone from embeddings -> (logits, aux). The attribution entry.
 
     ``scan_tiles`` routes SSM segments through the planned Pallas scan
     (``{segment_index: (d_tile, chunk)}``); None keeps the XLA chunked scan.
+    ``last_only`` computes the head at the last position alone (logits
+    ``[B, 1, V]``): next-token attribution needs no other.
+    ``live`` [B, S] (bool, from :func:`live_positions`) is False on left
+    padding: no attention reads those keys and the Mamba conv and scan see
+    zeros there, so every live position computes what it would in the
+    unpadded sequence (exactly so where attention has no absolute
+    positions) and the padding's embeddings get no gradient.
     """
     h = constrain(h.astype(cfg.jdtype), "batch", None, None)
     s = h.shape[1]
-    rope_cs = layers.rope_tables(jnp.arange(s), cfg.hd, cfg.rope_theta)
+    rope_cs = _rope(cfg, s)
     enc_out = None
     if cfg.enc_layers and enc_frames is not None:
         enc_out = encode(params, cfg, enc_frames, method)
     x, _, aux = _run_segments(params, cfg, h, rope_cs=rope_cs, method=method,
                               enc_out=enc_out, causal=causal, remat=remat,
                               triangle_skip=triangle_skip,
-                              scan_tiles=scan_tiles)
+                              scan_tiles=scan_tiles, live=live)
+    if last_only:
+        x = x[:, -1:]
     x = layers.apply_norm(params["final_norm"], x, cfg.norm)
     logits = layers.lm_head(params["embed"], x, cfg)
     return logits, aux
+
+
+def live_positions(tokens, n_embedded: int, pad_id: int = PAD_ID):
+    """[B, S] token ids -> [B, n_embedded] bool: False on the run of
+    ``pad_id`` that left-pads a prompt, True from its first other token on
+    (and on embeddings a frontend puts before the tokens)."""
+    live = jnp.cumsum((tokens != pad_id).astype(jnp.int32), axis=-1) > 0
+    extra = n_embedded - live.shape[-1]
+    if extra:
+        live = jnp.pad(live, ((0, 0), (extra, 0)), constant_values=True)
+    return live
 
 
 def forward(params, cfg: ModelConfig, batch: Dict, *, method="autodiff",
@@ -320,7 +361,7 @@ def init_cache(cfg: ModelConfig, batch: int, capacity: int, src_len: int = 0):
             "conv": jnp.zeros((count, batch, cfg.ssm_conv - 1, cfg.d_inner),
                               cfg.jdtype),
         }
-        if kind == "mamba":
+        if kind in ("mamba", "mamba_ffn"):
             caches.append(ssm_c)
         elif kind == "hybrid":
             caches.append({"attn": attn_c, "ssm": ssm_c})
@@ -335,7 +376,7 @@ def prefill(params, cfg: ModelConfig, batch: Dict, cache, *,
     h = embed_inputs(params, cfg, batch, method)
     h = constrain(h.astype(cfg.jdtype), "batch", None, None)
     s = h.shape[1]
-    rope_cs = layers.rope_tables(jnp.arange(s), cfg.hd, cfg.rope_theta)
+    rope_cs = _rope(cfg, s)
     enc_out = None
     if cfg.enc_layers and "frames" in batch:
         enc_out = encode(params, cfg, batch["frames"], method)
@@ -353,7 +394,9 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, pos, *,
     """One decode step: tokens [B, 1] at position ``pos`` (traced scalar)."""
     h = layers.embed(params["embed"], tokens, cfg)
     # rope_cs=(): sentinel "non-None" — decode builds tables from ``pos``.
-    x, new_caches, _ = _run_segments(params, cfg, h, rope_cs=(), method=method,
+    x, new_caches, _ = _run_segments(params, cfg, h,
+                                     rope_cs=() if cfg.rope else None,
+                                     method=method,
                                      caches=cache, pos=pos, remat=False)
     x = layers.apply_norm(params["final_norm"], x, cfg.norm)
     logits = layers.lm_head(params["embed"], x, cfg)

@@ -114,17 +114,23 @@ class _Scope:
     """A span open for a ``with`` block, mirrored on the profiler's host
     plane by a ``TraceAnnotation`` of the same name and ``span_id``."""
 
-    __slots__ = ("span", "_note")
+    __slots__ = ("span", "_note", "_stamp")
 
-    def __init__(self, span: Span):
+    def __init__(self, span: Span, stamp: bool = True):
         self.span = span
         self._note = None
+        self._stamp = stamp
 
     def __enter__(self) -> Span:
         from jax.profiler import TraceAnnotation
         self._note = TraceAnnotation(self.span.name,
                                      span_id=self.span.span_id)
         self._note.__enter__()
+        if self._stamp:
+            # the span starts when its profiler event does: a clock read
+            # made before the event (a preemption apart) would skew their
+            # offset
+            self.span.t0 = self.span._tracer.clock()
         return self.span
 
     def __exit__(self, *exc) -> bool:
@@ -180,10 +186,12 @@ class Tracer:
               t0: Optional[float] = None, **args: Any):
         """A context manager: the span ``start`` would make, ended when the
         block exits (by return or raise), and on the profiler's host plane
-        while it is open.  ``as`` binds the span."""
+        while it is open.  ``as`` binds the span; unless ``t0`` is given,
+        it is read on entering the block, just after the profiler event
+        starts."""
         span = self.start(name, cat=cat, trace_id=trace_id, parent=parent,
                           t0=t0, args=args)
-        return NULL_SCOPE if span is NULL_SPAN else _Scope(span)
+        return NULL_SCOPE if span is NULL_SPAN else _Scope(span, t0 is None)
 
     def finish(self) -> None:
         """Terminate any still-open spans (marked incomplete)."""

@@ -285,7 +285,8 @@ def pool_footprint(n: int, h: int, w: int, c: int,
 
 def ssm_scan_footprint(b: int, s: int, d: int, n: int,
                        d_tile: int = None, chunk: int = None,
-                       precision: str = "f32") -> Footprint:
+                       precision: str = "f32",
+                       layout: Optional[Tuple[int, int]] = None) -> Footprint:
     """One (batch, d-tile, chunk) grid cell of :func:`selective_scan_pallas`.
 
     The scan is a VPU recurrence (no MXU dots), so like
@@ -296,28 +297,32 @@ def ssm_scan_footprint(b: int, s: int, d: int, n: int,
 
     Block accounting mirrors the kernel's BlockSpecs exactly: dt is cast to
     f32 at the call site (4 B regardless of ``precision``), x/y ride the
-    operand dtype, B/C/A/h blocks and the h scratch are f32.  ``d_tile=None``
-    models the UNPLANNED launch (the whole ``d`` axis in one cell — what the
-    attribution step runs without a plan); ``chunk=None`` defaults the chunk
-    length to the full sequence.
+    operand dtype, the [chunk, n, 1] B/C column blocks, the [n, d_tile]
+    A/h blocks and the state scratch are f32.  On tiled VMEM (``layout``)
+    every block pads to whole tiles (a B/C column to a full lane row) and
+    the pipelined in/out blocks are double-buffered.  ``d_tile=None``
+    models the UNPLANNED launch (the whole ``d`` axis in one cell — what
+    the attribution step runs without a plan); ``chunk=None`` defaults the
+    chunk length to the full sequence.
     """
     elt = _elt(precision)
     dt_t = min(d_tile if d_tile is not None else d, d)
     ck = min(chunk if chunk is not None else s, s)
     n_chunks = -(-s // ck)
-    dt_blk = ck * dt_t * 4                  # dt cast to f32 at the call site
-    x_blk = ck * dt_t * elt
-    bc_blk = 2 * ck * n * 4                 # B and C blocks, f32
-    a_blk = dt_t * n * 4
-    h0_blk = dt_t * n * 4
-    scr = dt_t * n * 4                      # carried-state VMEM scratch
-    y_blk = ck * dt_t * elt
-    hl_blk = dt_t * n * 4
+    dt_blk = _vmem((ck, dt_t), 4, layout)   # dt cast to f32 at the call site
+    x_blk = _vmem((ck, dt_t), elt, layout)
+    bc_blk = 2 * _vmem((ck, n, 1), 4, layout)     # B and C columns, f32
+    a_blk = _vmem((n, dt_t), 4, layout)
+    h0_blk = _vmem((n, dt_t), 4, layout)
+    scr = _vmem((n, dt_t), 4, layout)       # carried-state VMEM scratch
+    y_blk = _vmem((ck, dt_t), elt, layout)
+    hl_blk = _vmem((n, dt_t), 4, layout)
     cells = b * (d // dt_t if d % dt_t == 0 else -(-d // dt_t)) * n_chunks
-    loads = dt_blk + x_blk + bc_blk + a_blk + h0_blk
+    loads = (ck * dt_t * (4 + elt) + 2 * ck * n * 4 + 2 * dt_t * n * 4)
+    buffers = 2 if layout is not None else 1
     return Footprint(
-        vmem_bytes=(dt_blk + x_blk + bc_blk + a_blk + h0_blk + scr
-                    + y_blk + hl_blk),
+        vmem_bytes=(buffers * (dt_blk + x_blk + bc_blk + a_blk + h0_blk
+                               + y_blk + hl_blk) + scr),
         hbm_bytes=cells * loads + b * n_chunks * ck * d * elt + b * d * n * 4,
         flops=0,
         mxu_util=1.0)

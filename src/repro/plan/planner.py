@@ -26,6 +26,7 @@ from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 from repro.kernels.tiling import LANE, SUBLANE, align_up, pow2_span
+from repro.models.config import SSM_KINDS
 from repro.plan import model as cost
 from repro.plan.cache import TuningCache, cache_key
 from repro.plan.profiles import MeshProfile, get_profile
@@ -246,7 +247,7 @@ def measure_kernel(family: str, kw: Dict[str, Any], tile,
 def _footprint(family: str, kw: Dict[str, Any], tile, precision: str,
                profile) -> cost.Footprint:
     """Footprint of one launch on ``profile`` (its MXU edge, and for the
-    conv families its on-chip layout: tiled VMEM or flat RAM)."""
+    conv and scan families its on-chip layout: tiled VMEM or flat RAM)."""
     mxu = profile.mxu
     layout = (profile.sublane, profile.lane) if profile.tiled_vmem else None
     if family == "conv2d_fwd":
@@ -279,7 +280,7 @@ def _footprint(family: str, kw: Dict[str, Any], tile, precision: str,
             kw["b"], kw["s"], kw["d"], kw["n"],
             tile.d_tile if tile is not None else None,
             tile.chunk if tile is not None else kw["chunk_default"],
-            precision=precision)
+            precision=precision, layout=layout)
     raise ValueError(f"unknown kernel family {family!r}")
 
 
@@ -301,10 +302,20 @@ def _candidates(family: str, kw: Dict[str, Any]) -> List[Any]:
         # d_tile must DIVIDE the channel axis (the kernel asserts it);
         # chunk lengths are free pow2s — the kernel pads the tail chunk.
         d = kw["d"]
-        dts = [t for t in pow2_span(SUBLANE, d) if d % t == 0]
+        dts = [t for t in pow2_span(SUBLANE, min(d, SCAN_MAX_D_TILE))
+               if d % t == 0]
         cks = pow2_span(SUBLANE, align_up(kw["s"], SUBLANE))
         return [ScanTile(dt, ck) for dt in dts for ck in cks]
     raise ValueError(f"no tile candidates for family {family!r}")
+
+
+#: Widest scan d-tile a plan takes.  The kernels carry the [n, d_tile]
+#: state through their per-step loop in vector registers: at n 16 and 512
+#: channels it is 8 of the 64, leaving room for the step's temporaries
+#: (the backward holds about six such arrays); a wider tile would spill
+#: them to VMEM every step, while the HBM traffic the ranking weighs
+#: barely moves.
+SCAN_MAX_D_TILE = 512
 
 
 def _tile_volume(tile) -> int:
@@ -505,7 +516,7 @@ def lm_kernel_shapes(cfg, batch: int = 1, seq: int = LM_PLAN_SEQ):
     """
     out = []
     for si, (kind, _count, _window) in enumerate(cfg.layer_plan()):
-        if kind in ("mamba", "hybrid"):
+        if kind in SSM_KINDS:
             out.append((f"ssm{si}.scan", "ssm_scan",
                         dict(b=batch, s=seq, d=cfg.d_inner, n=cfg.ssm_state,
                              chunk_default=cfg.ssm_chunk)))

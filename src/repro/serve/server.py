@@ -75,7 +75,7 @@ from repro.serve.adapters import concat_examples, slice_example
 
 class ExplanationServer:
     def __init__(self, adapter, *, cache_capacity: int = 256,
-                 max_batch: int = 8, max_delay_s: float = 0.002,
+                 max_batch: Optional[int] = None, max_delay_s: float = 0.002,
                  clock: Callable[[], float] = clock_lib.monotonic,
                  method_opts: Optional[Dict[str, dict]] = None,
                  admission: Optional[AdmissionConfig] = None,
@@ -91,9 +91,13 @@ class ExplanationServer:
             self.tracer.clock = clock      # spans and deadlines share "now"
         self._batch_span = NULL_SPAN       # the launch in flight (_dispatch)
         self._trace_seq = itertools.count()
-        # Mesh-sharded adapters (engine built for a mesh:<profile>:<n>
-        # device) expose n_shards; the batcher then fills buckets toward
-        # max_batch * n_shards seats so every launch occupies the mesh.
+        # Seats per launch: the caller's, else the adapter's own (sized to
+        # its memory), else 8.  Mesh-sharded adapters (engine built for a
+        # mesh:<profile>:<n> device) expose n_shards; the batcher then
+        # fills buckets toward max_batch * n_shards seats so every launch
+        # occupies the mesh.
+        if max_batch is None:
+            max_batch = getattr(adapter, "max_batch", None) or 8
         self.batcher = MicroBatcher(max_batch=max_batch,
                                     max_delay_s=max_delay_s, clock=clock,
                                     n_shards=getattr(adapter, "n_shards", 1))
@@ -303,13 +307,15 @@ class ExplanationServer:
             # the batch is its own track; request spans point at it by id
             bid = f"batch#{next(self._trace_seq)}"
             scope = self.tracer.scope(
-                f"batch/{batch.kind}", cat="batch", trace_id=bid, t0=t0,
+                f"batch/{batch.kind}", cat="batch", trace_id=bid,
                 n=len(batch.requests), degraded=batch.degraded,
                 method=(batch.requests[0].method
                         if batch.kind == EXPLAIN else ""))
         # the span's ends and its profiler event's stay a few statements
         # apart: their offset is what puts spans on the device's clock
         with scope as bspan:
+            if bspan.enabled:
+                t0 = bspan.t0             # read as the profiler event began
             if self.tracer.enabled:
                 for req in batch.requests:
                     if req.trace is not None:
@@ -559,8 +565,11 @@ class ExplanationServer:
                 # non-foldable stochastic methods ride singleton buckets
                 # (batcher token), so reqs is exactly one request here
                 key = reqs[0].key
+        tokens = (self._token_counts(adapter, reqs, xb)
+                  if self.tracer.enabled else {})
         with self._phase("engine.attribute", program="attribute",
-                         rows=xb.shape[0], live=live, seeds=1, method=method):
+                         rows=xb.shape[0], live=live, seeds=1, method=method,
+                         **tokens):
             logits, rel = explainer.attribute(xb, target=target, key=key)
             jax.block_until_ready(rel)
         self.stats.record_batch(live, xb.shape[0])
@@ -574,6 +583,18 @@ class ExplanationServer:
                     relevance=rel[i], targets=(int(tgt),), method=method,
                     batch_size=xb.shape[0])))
         return out
+
+    @staticmethod
+    def _token_counts(adapter, reqs: List[Request], xb) -> Dict[str, int]:
+        """A token launch's prompt positions (traced runs only): the live
+        rows' tokens that are not left padding, and every position the
+        launch computes (padding rows and padded positions included)."""
+        if getattr(adapter, "input_kind", None) != "tokens":
+            return {}
+        pad = adapter.pad_id
+        live = sum(int(np.count_nonzero(np.asarray(r.x) != pad))
+                   for r in reqs)
+        return {"tokens_live": live, "tokens_padded": int(np.prod(xb.shape))}
 
     def _explain_cold_bp(self, method: str, reqs: List[Request],
                          degraded: bool = False) -> List[Response]:

@@ -24,7 +24,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.models import cnn
 
 KERNEL_MODULES = ("conv2d.conv2d", "conv2d.fxp", "pool.pool",
-                  "relu_mask.relu_mask", "vmm.vmm", "vmm.fxp")
+                  "relu_mask.relu_mask", "vmm.vmm", "vmm.fxp",
+                  "ssm_scan.ssm_scan")
 METHODS = ("saliency", "deconvnet", "guided")
 N, S = 8, 3                       # serving batch, top-3 seed panel
 DTYPE = {"f32": jnp.float32, "bf16": jnp.bfloat16, "fxp16": jnp.int16}
@@ -210,7 +211,7 @@ def test_backward_seeds_program_compiles(one_chip, compiled, precision,
 #: the kernel families a ``pallas_call`` may be named after
 KERNEL_FAMILIES = ("conv2d_fwd", "conv2d_bwd", "vmm_fwd", "vmm_bwd",
                    "maxpool_fwd", "maxpool_bwd", "relu_mask_fwd",
-                   "relu_mask_bwd", "ssm_scan")
+                   "relu_mask_bwd", "ssm_scan", "ssm_scan_bwd")
 
 
 @pytest.mark.parametrize("precision", ["f32", "fxp16"])
@@ -264,3 +265,76 @@ def test_served_programs_name_every_kernel_by_family(one_chip, compiled,
         "conv2d_fwd", "vmm_fwd", "maxpool_fwd", "relu_mask_fwd"}
     assert {n.rsplit(".", 1)[0] for n in names["replay"]} >= {
         "conv2d_bwd", "vmm_bwd"}
+
+
+# -- the LM path: AI21-Jamba2-3B's selective scan and token step --------------
+
+
+def test_ssm_scan_compiles_at_jamba_widths_as_planned(one_chip, compiled):
+    """The scan's forward kernel and its backward at jamba2-3b's d_inner
+    5120 and d_state 16, at the tile ``plan_lm`` picks for v5e, over a
+    4096-token prompt: each one Mosaic kernel named after its family."""
+    import re
+
+    import repro.configs as configs
+    from repro.plan import plan_lm
+    cfg = configs.get("jamba2-3b").with_(n_layers=14)
+    tiles = {t for _, t in plan_lm(cfg, device="tpu-v5e",
+                                   precision="f32").entries}
+    assert len(tiles) == 1
+    tile, = tiles
+    fn = _mod("ssm_scan.ssm_scan").selective_scan_pallas
+    s, d, n = 4096, cfg.d_inner, cfg.ssm_state
+    f32 = jnp.float32
+    text = compile_for(
+        one_chip, lambda *a: fn(*a, d_tile=tile.d_tile, chunk=tile.chunk),
+        ((1, s, d), f32), ((1, s, d), f32), ((1, s, n), f32),
+        ((1, s, n), f32), ((d, n), f32), ((1, d, n), f32))
+    names = re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert [name.rsplit(".", 1)[0] for name in names] == ["ssm_scan"]
+    bwd = _mod("ssm_scan.ssm_scan").selective_scan_bwd_pallas
+    chunks = s // tile.chunk
+    text = compile_for(
+        one_chip, lambda *a: bwd(*a, chunk=tile.chunk, d_tile=tile.d_tile),
+        ((1, s, d), f32), ((1, s, d), f32), ((1, s, n), f32),
+        ((1, s, n), f32), ((d, n), f32), ((1, chunks, n, d), f32),
+        ((1, s, d), f32), ((1, d, n), f32))
+    names = re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert [name.rsplit(".", 1)[0] for name in names] == ["ssm_scan_bwd"]
+
+
+def test_lm_token_step_runs_every_dot_at_highest_under_f32(one_chip,
+                                                           compiled):
+    """The served token-attribution program of jamba2-3b (SMOKE widths,
+    the whole layer pattern), lowered and compiled for the chip under
+    ``precision="f32"``: every dot and einsum asks for HIGHEST, and the
+    scan and its backward are the two Mosaic kernels, each named after its
+    family, as the device trace reads them."""
+    import re
+
+    import repro.configs as configs
+    from repro import engine as engine_lib
+    from repro.models import transformer as tf
+    cfg = configs.get_smoke("jamba2-3b")
+    params = jax.eval_shape(lambda: tf.init(jax.random.PRNGKey(0), cfg))
+    p_spec = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params)
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32, sharding=one_chip)
+    lowered = {}
+    for precision, want in (("f32", True), ("bf16", False)):
+        step = engine_lib.LMModel(None, cfg).token_step(
+            "saliency", precision=precision)
+        lowered[precision] = jax.jit(step).lower(p_spec, {"tokens": tokens})
+        dots = re.findall(r"stablehlo\.dot_general[^\n]*",
+                          lowered[precision].as_text())
+        assert len(dots) > 20
+        assert all(("precision = [HIGHEST, HIGHEST]" in d) == want
+                   for d in dots), precision
+    text = lowered["f32"].compile().as_text()
+    kernels = {name.rsplit(".", 1)[0] for name in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)}
+    assert kernels == {"ssm_scan", "ssm_scan_bwd"}
