@@ -23,29 +23,10 @@ from __future__ import annotations
 from dataclasses import replace
 from typing import Any, Dict, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro import engine as engine_lib
 from repro.models import cnn
-
-
-def slice_example(tree, i: int):
-    """Per-example [1, ...] slice of a batched residual/array pytree.
-
-    Non-array leaves (e.g. static shape ints) pass through unchanged.
-    """
-    return jax.tree.map(
-        lambda lf: lf[i:i + 1] if hasattr(lf, "ndim") and lf.ndim else lf,
-        tree)
-
-
-def concat_examples(trees):
-    """Rebuild a batch from per-example slices (inverse of slice_example)."""
-    return jax.tree.map(
-        lambda *ls: (jnp.concatenate(ls)
-                     if hasattr(ls[0], "ndim") and ls[0].ndim else ls[0]),
-        *trees)
 
 
 class CNNAdapter:

@@ -13,20 +13,28 @@ for the Table III CNN at batch 1), so thousands of in-flight explanations
 fit where a handful of activation caches would; the cache still bounds
 itself by entry count and reports its exact bit footprint.
 
-A launch's entries share its residual tree by reference (each names its
-``row``; a 1-row launch's tree already is its one example), so storing a
-launch issues no device op; the per-example slice is taken only where an
-explain hit needs it, when the server gathers the replay batch.
+Every stored example's residuals live in one device-resident slot table:
+one array per residual leaf, ``[capacity, *leaf.shape[1:]]`` in the leaf's
+dtype, built from the first stored tree and placed on its device(s) — on a
+mesh, replicated over the forward's devices and holding the int32 words the
+forward hands out.  An entry names its ``slot``.  Storing a launch is ONE
+jitted write of its live rows (padding rows dropped, the table donated), and
+a hit group is ONE jitted take of its slots; both programs' shapes depend on
+the launch's padded size alone, whichever launches a group draws from.
 """
 from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Any, Optional
+from functools import partial
+from typing import Any, List, Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_serving_mesh
 from repro.obs import metrics as obsm
 
 
@@ -39,7 +47,7 @@ def residual_bits(residuals: Any) -> int:
 
 def example_bits(residuals: Any) -> int:
     """Bits of ONE example of a batched residual pytree, from the leaf
-    shapes alone: equals ``residual_bits(slice_example(residuals, i))``."""
+    shapes alone: one row of every leaf."""
     return sum(int(np.prod(leaf.shape[1:] if leaf.ndim else ()))
                * leaf.dtype.itemsize * 8
                for leaf in jax.tree.leaves(residuals)
@@ -48,17 +56,11 @@ def example_bits(residuals: Any) -> int:
 
 @dataclass
 class CacheEntry:
-    logits: Any          # [C] — the predicted logits (argmax targets, seeds)
-    residuals: Any       # packed masks/indices pytree: ONE example's, or,
-                         # with ``row``, the whole launch's it was stored from
+    logits: Any          # [C] host row — the predicted logits (targets, seeds)
     rules: str           # rule set the forward stored masks under
-    row: Optional[int] = None   # this example's row in ``residuals``
-    bits: int = 0        # one example's bits, whatever ``residuals`` holds
-
-    def __post_init__(self):
-        if not self.bits:
-            self.bits = (residual_bits(self.residuals) if self.row is None
-                         else example_bits(self.residuals))
+    bits: int            # one example's residual bits
+    slot: Optional[int] = None   # its row of the slot table (None: the
+                                 # adapter's forward returned no arrays)
 
 
 @dataclass
@@ -68,24 +70,61 @@ class CacheStats:
     evictions: int = 0
     bits_stored: int = 0
     peak_bits: int = 0
-    zero_copy_rows: int = 0    # hit rows replayed from the stored tree as is
-    copied_rows: int = 0       # hit rows sliced or concatenated at gather
+    gathers: int = 0           # take programs issued for hit groups
+    gathered_rows: int = 0     # rows they moved (padded group sizes)
 
     def hit_rate(self) -> float:
         total = self.hits + self.misses
         return self.hits / total if total else 0.0
 
-    def zero_copy_share(self) -> float:
-        total = self.zero_copy_rows + self.copied_rows
-        return self.zero_copy_rows / total if total else 0.0
+    def rows_per_gather(self) -> float:
+        return self.gathered_rows / self.gathers if self.gathers else 0.0
 
     def snapshot(self) -> dict:
         return {"hits": self.hits, "misses": self.misses,
                 "evictions": self.evictions, "hit_rate": self.hit_rate(),
                 "bits_stored": self.bits_stored, "peak_bits": self.peak_bits,
-                "zero_copy_rows": self.zero_copy_rows,
-                "copied_rows": self.copied_rows,
-                "zero_copy_share": self.zero_copy_share()}
+                "gathers": self.gathers, "gathered_rows": self.gathered_rows,
+                "rows_per_gather": self.rows_per_gather()}
+
+
+def _constrain(leaves: List[Any], mesh: Optional[Mesh], spec: P):
+    if mesh is None:
+        return leaves
+    sharding = NamedSharding(mesh, spec)
+    return [jax.lax.with_sharding_constraint(v, sharding) for v in leaves]
+
+
+@partial(jax.jit, static_argnums=0, donate_argnums=1)
+def _write(mesh, table, slots, rows):
+    """Rows ``i`` of ``rows`` into slots ``slots[i]`` of every leaf's table;
+    an out-of-range slot (a padding row) writes nothing."""
+    out = [t.at[slots].set(r, mode="drop") for t, r in zip(table, rows)]
+    return _constrain(out, mesh, P())
+
+
+@partial(jax.jit, static_argnums=0)
+def _take(mesh, table, slots):
+    """The table's rows ``slots`` of every leaf; on a mesh they come out
+    split over its devices when the row count divides evenly (each device
+    takes its own rows from its replica, with no collective)."""
+    out = [jnp.take(t, slots, axis=0) for t in table]
+    even = mesh is not None and slots.shape[0] % mesh.size == 0
+    return _constrain(out, mesh, P(*mesh.axis_names) if even else P())
+
+
+def _placement(leaf):
+    """-> (mesh or None, sharding) of a table for ``leaf``: replicated over
+    the serving mesh a multi-device leaf comes from (a jitted program takes
+    arguments in one device order only, and the mesh orders its devices by
+    the chips' topology), else on the leaf's one device."""
+    sharding = getattr(leaf, "sharding", None)     # None: a host array
+    devices = ({jax.devices()[0]} if sharding is None
+               else sharding.device_set)
+    if len(devices) == 1:
+        return None, jax.sharding.SingleDeviceSharding(next(iter(devices)))
+    mesh = make_serving_mesh(len(devices))
+    return mesh, NamedSharding(mesh, P())
 
 
 class ResidualCache:
@@ -97,6 +136,10 @@ class ResidualCache:
         self.capacity = capacity
         self._entries: "OrderedDict[str, CacheEntry]" = OrderedDict()
         self.stats = CacheStats()
+        self._table: Optional[List[Any]] = None   # one array per leaf
+        self._treedef = None
+        self._mesh: Optional[Mesh] = None
+        self._free = list(range(capacity - 1, -1, -1))   # pop() -> slot 0
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -104,19 +147,69 @@ class ResidualCache:
     def __contains__(self, uid: str) -> bool:
         return uid in self._entries
 
-    def put(self, uid: str, entry: CacheEntry) -> None:
-        if uid in self._entries:
-            self.stats.bits_stored -= self._entries.pop(uid).bits
+    def store(self, uids: Sequence[str], logits: np.ndarray, residuals,
+              rules: str) -> None:
+        """Park a launch: ``uids[i]`` gets row ``i`` of ``logits`` (host)
+        and of the batched ``residuals``.  The rows enter the slot table
+        through one write program, not waited on; a launch with no residual
+        arrays stores its logits alone."""
+        leaves, treedef = jax.tree.flatten(residuals)
+        if leaves and self._table is None:
+            self._build(leaves, treedef)
+        bits = example_bits(residuals)
+        slots = np.full(leaves[0].shape[0] if leaves else 0, self.capacity,
+                        np.int32)
+        row_of = {}                  # slot -> the launch row now holding it
+        for i, uid in enumerate(uids):
+            entry = CacheEntry(logits=logits[i], rules=rules, bits=bits)
+            self._put(uid, entry, bool(leaves))
+            if entry.slot is not None:
+                if entry.slot in row_of:     # reused within this launch:
+                    slots[row_of[entry.slot]] = self.capacity   # last wins
+                row_of[entry.slot] = i
+                slots[i] = entry.slot
+        if leaves:
+            self._table = _write(self._mesh, self._table, slots, leaves)
+
+    def gather(self, entries: Sequence[CacheEntry], rows: int):
+        """The residual tree of ``entries``' examples, padded to ``rows``
+        rows by repeating the first: one take program over every leaf."""
+        if entries[0].slot is None:
+            return None
+        slots = [e.slot for e in entries]
+        slots += slots[:1] * (rows - len(slots))
+        self.stats.gathers += 1
+        self.stats.gathered_rows += rows
+        return jax.tree.unflatten(self._treedef, _take(
+            self._mesh, self._table, np.asarray(slots, np.int32)))
+
+    def _build(self, leaves, treedef) -> None:
+        self._mesh, sharding = _placement(leaves[0])
+        self._treedef = treedef
+        self._table = [jax.device_put(
+            np.zeros((self.capacity, *lf.shape[1:]), lf.dtype), sharding)
+            for lf in leaves]
+
+    def _put(self, uid: str, entry: CacheEntry, slotted: bool) -> None:
+        """Insert ``entry``, evicting the LRU entries past capacity; a
+        re-stored uid keeps its slot, a new one takes a free slot."""
+        old = self._entries.pop(uid, None)
+        if old is not None:
+            self.stats.bits_stored -= old.bits
         self._entries[uid] = entry
         self.stats.bits_stored += entry.bits
         self.stats.peak_bits = max(self.stats.peak_bits,
                                    self.stats.bits_stored)
         obsm.RESIDUAL_CACHE.inc(event="store")
         while len(self._entries) > self.capacity:
-            _, old = self._entries.popitem(last=False)
-            self.stats.bits_stored -= old.bits
+            _, evicted = self._entries.popitem(last=False)
+            self.stats.bits_stored -= evicted.bits
+            self._free.extend([] if evicted.slot is None else [evicted.slot])
             self.stats.evictions += 1
             obsm.RESIDUAL_CACHE.inc(event="eviction")
+        if slotted:
+            entry.slot = (old.slot if old is not None and old.slot is not None
+                          else self._free.pop())
         obsm.RESIDUAL_CACHE_BITS.set(self.stats.bits_stored)
 
     def get(self, uid: str) -> Optional[CacheEntry]:
@@ -134,12 +227,6 @@ class ResidualCache:
         rules-incompatible entry the server declines to use)."""
         self.stats.misses += 1
         obsm.RESIDUAL_CACHE.inc(event="miss")
-
-    def count_gather(self, rows: int, copied: int) -> None:
-        """Account one gather of ``rows`` hit rows, ``copied`` of which were
-        sliced or concatenated (the rest entered the replay as stored)."""
-        self.stats.zero_copy_rows += rows - copied
-        self.stats.copied_rows += copied
 
     def peek(self, uid: str) -> Optional[CacheEntry]:
         """Presence probe — no recency update, no hit/miss accounting."""

@@ -6,8 +6,8 @@ latency deadline and runs it:
 
   * **predict** batches run the adapter's residual-returning forward; each
     request's packed masks are parked in the LRU residual cache under its
-    ``uid``, as its row of the launch's residual tree (no per-example slice:
-    a launch's logits are read to the host once and its tree is shared).
+    ``uid``, in a slot of the cache's device table (one write program per
+    launch; a launch's logits are read to the host once).
   * **explain** batches split into cache **hits** — a pure-BP method with a
     cached predict for the same ``uid``: the forward pass is skipped and all
     hits in the bucket backpropagate together through ONE seed-batched fused
@@ -44,8 +44,8 @@ thread's CPU and run-queue seconds over it (``cpu_s``, ``runq_s``), with one
 ``targets``, ``engine.replay``, ``engine.attribute``, ``cache.gather``,
 ``cache.store`` and ``respond``.  The engine phases carry the launch's
 ``program``, padded ``rows``, ``live`` rows, ``seeds`` and ``method``;
-``cache.gather`` carries the hit rows it ``copied`` (sliced or
-concatenated; the rest replay the stored tree as is).  All
+``cache.gather`` carries the ``rows`` its one take program moves (the hit
+group's padded size).  All
 of them are scoped spans (:meth:`repro.obs.trace.Tracer.scope`), so under
 ``jax.profiler`` they also appear on the device trace's clock.
 """
@@ -67,10 +67,8 @@ from repro.serve.api import (EXPLAIN, PREDICT, SHED_EXPIRED,
                              InvalidRequestError, Request, Response,
                              ShedError, shed_response)
 from repro.serve.batcher import Batch, MicroBatcher, pad_size
-from repro.serve.residual_cache import (CacheEntry, ResidualCache,
-                                        example_bits)
+from repro.serve.residual_cache import ResidualCache
 from repro.serve.stats import ServerStats
-from repro.serve.adapters import concat_examples, slice_example
 
 
 class ExplanationServer:
@@ -400,8 +398,8 @@ class ExplanationServer:
         now = self.clock()
         with self._phase("cache.store"):
             logits = np.asarray(logits)     # the launch's one host read
-            self._store(batch.requests, logits, residuals,
-                        self.adapter.store_rules)
+            self.cache.store([r.uid for r in batch.requests], logits,
+                             residuals, self.adapter.store_rules)
             for req in batch.requests:
                 if req.trace is not None:
                     req.trace.root.child("cache", cat="cache", t0=now).end(
@@ -410,18 +408,6 @@ class ExplanationServer:
             return [self._finish(req, Response(
                 uid=req.uid, kind=PREDICT, logits=logits[i], batch_size=rows))
                 for i, req in enumerate(batch.requests)]
-
-    def _store(self, reqs: List[Request], logits: np.ndarray, residuals,
-               rules: str) -> None:
-        """Park each request's row of a launch: the entries share the
-        launch's residual tree by reference (no device op), and a 1-row
-        launch's tree is already its one example's."""
-        rows = logits.shape[0]
-        bits = example_bits(residuals)
-        for i, req in enumerate(reqs):
-            self.cache.put(req.uid, CacheEntry(
-                logits=logits[i], residuals=residuals, rules=rules,
-                row=None if rows == 1 else i, bits=bits))
 
     @staticmethod
     def _rules_compatible(stored_rules: str, method: str) -> bool:
@@ -488,23 +474,11 @@ class ExplanationServer:
             targets = [self._targets_for(r, e.logits)
                        for r, e in zip(reqs, entries)]
         # pow2-pad the hit group too (rows repeat entry 0, sliced off below)
-        # so the BP program compiles for a handful of batch shapes only.
+        # so the take and BP programs compile for a handful of shapes only.
         psize = pad_size(len(reqs), self.batcher.fill_target)
         tgt_pad = targets + [targets[0]] * (psize - len(reqs))
-        # a lone one-example entry replays its stored tree as is; any other
-        # group slices each entry's row out of its launch and concatenates
-        zero_copy = psize == 1 and entries[0].row is None
-        copied = 0 if zero_copy else len(reqs)
-        self.cache.count_gather(len(reqs), copied)
-        with self._phase("cache.gather", copied=copied):
-            if zero_copy:
-                residuals = entries[0].residuals
-            else:
-                parts = [e.residuals if e.row is None
-                         else slice_example(e.residuals, e.row)
-                         for e in entries]
-                residuals = concat_examples(
-                    parts + [parts[0]] * (psize - len(reqs)))
+        with self._phase("cache.gather", rows=psize):
+            residuals = self.cache.gather(entries, psize)
         num_classes = entries[0].logits.shape[-1]
         with self._phase("engine.replay", program="replay", rows=psize,
                          live=len(reqs), seeds=len(targets[0]),
@@ -625,8 +599,8 @@ class ExplanationServer:
         self.stats.record_batch(live, rows)
         if not degraded:
             with self._phase("cache.store"):
-                self._store(reqs, host_logits, residuals,
-                            adapter.store_rules)
+                self.cache.store([r.uid for r in reqs], host_logits,
+                                 residuals, adapter.store_rules)
         with self._phase("respond"):
             return [self._finish(req, Response(
                 uid=req.uid, kind=EXPLAIN, logits=host_logits[i],

@@ -502,8 +502,8 @@ def test_mesh_engine_forward_replay_roundtrip(setup):
 def test_mesh_residuals_reshard_as_words_and_replay(setup):
     """The 4-shard forward hands out no sub-32-bit residual (packed uint8
     masks leave the shard_map as int32 words), and the residual cache's
-    per-example slice -> concat round trip replays like one device."""
-    from repro.serve.adapters import concat_examples, slice_example
+    slot-table store -> take round trip replays like one device."""
+    from repro.serve.residual_cache import ResidualCache
     params, x = setup
     e0 = build(spec_for(params, method="guided", device="edge-small"))
     e4 = build(spec_for(params, method="guided",
@@ -512,8 +512,10 @@ def test_mesh_residuals_reshard_as_words_and_replay(setup):
     logits4, res4 = e4.forward(x)
     assert all(v.dtype.itemsize >= 4 for v in jax.tree.leaves(res4))
     assert any(v.dtype == jnp.uint8 for v in jax.tree.leaves(res0))
-    cached = concat_examples([slice_example(res4, i)
-                              for i in range(x.shape[0])])
+    cache = ResidualCache(capacity=8)
+    uids = [f"r{i}" for i in range(x.shape[0])]
+    cache.store(uids, np.asarray(logits4), res4, "guided")
+    cached = cache.gather([cache.peek(u) for u in uids], x.shape[0])
     seeds = jax.nn.one_hot(jnp.argsort(logits0, -1)[:, ::-1][:, :2].T,
                            CFG.num_classes)
     np.testing.assert_allclose(np.asarray(e4.replay(cached, seeds)),

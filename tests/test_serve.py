@@ -11,9 +11,8 @@ from repro.serve import (CNNAdapter, ExplanationServer, MicroBatcher,
                          Request, ResidualCache, bucket_key, registry,
                          residual_bits)
 from repro.obs.trace import Tracer
-from repro.serve.adapters import slice_example
+from repro.serve import residual_cache
 from repro.serve.api import EXPLAIN, PREDICT
-from repro.serve.residual_cache import CacheEntry
 
 CFG = cnn.CNNConfig(in_hw=(8, 8), channels=(4, 4), fc=(16,))
 
@@ -212,13 +211,13 @@ def test_batcher_padding_roundtrip(setup):
 
 def test_cache_lru_eviction_and_accounting():
     cache = ResidualCache(capacity=2)
-    mk = lambda: CacheEntry(logits=jnp.zeros((10,)),
-                            residuals={"m": np.zeros((1, 4), np.uint8)},
-                            rules="saliency")
-    cache.put("a", mk())
-    cache.put("b", mk())
+    put = lambda uid: cache.store([uid], np.zeros((1, 10)),
+                                  {"m": np.zeros((1, 4), np.uint8)},
+                                  "saliency")
+    put("a")
+    put("b")
     assert cache.get("a") is not None           # refreshes recency
-    cache.put("c", mk())                        # evicts b (LRU)
+    put("c")                                    # evicts b (LRU)
     assert "b" not in cache and "a" in cache and "c" in cache
     assert cache.get("b") is None
     st = cache.stats
@@ -447,25 +446,6 @@ def served(request):
         "setup" if request.param == "f32" else "setup_fxp")
 
 
-def test_one_row_predict_stores_the_launch_tree_by_reference(served):
-    """A 1-row predict parks the forward's own residual arrays (no slice
-    ran) and its logits as a host row."""
-    _, adapter, x = served
-    rec = _Recording(adapter)
-    srv = make_server(rec)
-    resp = srv.serve([Request(uid="a", kind=PREDICT, x=x[0])])["a"]
-    (logits, residuals), = rec.outputs
-    entry = srv.cache.peek("a")
-    assert entry.row is None
-    stored = jax.tree.leaves(entry.residuals)
-    assert len(stored) == len(jax.tree.leaves(residuals)) > 0
-    for a, b in zip(stored, jax.tree.leaves(residuals)):
-        assert a is b
-    assert isinstance(entry.logits, np.ndarray)
-    np.testing.assert_array_equal(entry.logits, np.asarray(logits)[0])
-    np.testing.assert_array_equal(np.asarray(resp.logits), entry.logits)
-
-
 def test_multi_row_predict_hits_bitwise_equal_cold(served):
     """Hits of every row of a 4-row predict, each alone and all four in
     one group, equal a cold explain of the same images bitwise."""
@@ -478,7 +458,7 @@ def test_multi_row_predict_hits_bitwise_equal_cold(served):
                                       prefix="s"))
     srv = make_server(adapter)
     _predict_launch(srv, x, [f"p{i}" for i in range(4)])
-    assert [srv.cache.peek(f"p{i}").row for i in range(4)] == [0, 1, 2, 3]
+    assert [srv.cache.peek(f"p{i}").slot for i in range(4)] == [0, 1, 2, 3]
     alone = {}
     for i in (3, 1, 2, 0):
         alone.update(_explain_group(srv, x, [i]))
@@ -497,51 +477,149 @@ def test_multi_row_predict_hits_bitwise_equal_cold(served):
 
 
 def test_entry_bits_are_one_examples(served):
-    """Entries referencing a launch still account ONE example's bits, so
-    ``bits_stored`` and ``peak_bits`` read as when each row was sliced."""
+    """Entries account ONE example's bits, whatever launch stored them, so
+    ``bits_stored`` and ``peak_bits`` read as one row per entry."""
     _, adapter, x = served
     rec = _Recording(adapter)
     srv = make_server(rec)
     _predict_launch(srv, x, [f"p{i}" for i in range(3)])    # 4 rows, 3 live
     srv.serve([Request(uid="one", kind=PREDICT, x=x[3])])
-    (_, res4), (_, res1) = rec.outputs
-    per_example = residual_bits(slice_example(res4, 0))
+    (logits4, res4), (_, res1) = rec.outputs
+    per_example = residual_bits(res4) // 4
     assert per_example == residual_bits(res1) > 0
     for i in range(3):
-        assert srv.cache.peek(f"p{i}").bits == residual_bits(
-            slice_example(res4, i)) == per_example
-        assert residual_bits(srv.cache.peek(f"p{i}").residuals) == (
-            4 * per_example)                 # the tree is the launch's
+        entry = srv.cache.peek(f"p{i}")
+        assert entry.bits == per_example
+        assert residual_bits(srv.cache.gather([entry], 1)) == per_example
     assert srv.cache.peek("one").bits == per_example
     st = srv.cache.stats
     assert st.bits_stored == st.peak_bits == 4 * per_example
-    cache = ResidualCache(capacity=2)
-    for i in range(3):                       # evicts p0: bits leave with it
-        cache.put(f"p{i}", srv.cache.peek(f"p{i}"))
+    cache = ResidualCache(capacity=2)        # the third row evicts p0, and
+    cache.store([f"p{i}" for i in range(3)], np.asarray(logits4), res4,
+                "saliency")                  # its bits leave with it
     assert cache.stats.bits_stored == 2 * per_example
     assert cache.stats.peak_bits == 3 * per_example   # read before evicting
 
 
-def test_zero_copy_share_counts_one_row_hits(served):
-    """A lone hit of a 1-row launch replays the stored tree as is; hits of
-    a multi-row launch, or any group of two or more, are copied."""
+_MORE = jax.random.normal(jax.random.PRNGKey(7), (7, 8, 8, 3))
+
+
+def test_hit_group_from_three_launches_equals_cold(served):
+    """One hit group drawn from predict launches of 1, 2 and 4 rows equals
+    a cold explain of the same images bitwise."""
+    _, adapter, _ = served
+    x = _MORE
+    srv = make_server(adapter)
+    _predict_launch(srv, x[:1], ["q0"])
+    _predict_launch(srv, x[1:3], ["q1", "q2"])
+    _predict_launch(srv, x[3:7], ["q3", "q4", "q5", "q6"])
+    rows = [6, 0, 2, 4]
+    group = _explain_group(srv, x, rows, prefix="q")
+    cold = _explain_group(make_server(adapter), x, rows, prefix="c")
+    assert srv.cache.stats.gathers == 1
+    for i in rows:
+        hit = group[f"q{i}"]
+        assert hit.cache_hit and hit.batch_size == 4
+        np.testing.assert_array_equal(np.asarray(hit.relevance),
+                                      np.asarray(cold[f"c{i}"].relevance))
+        np.testing.assert_array_equal(np.asarray(hit.logits),
+                                      np.asarray(cold[f"c{i}"].logits))
+
+
+def test_evicted_slot_is_reused_without_stale_rows(served, monkeypatch):
+    """A slot freed by eviction goes to the next stored example, and hits
+    read that example's row: across launches, and within one launch whose
+    later rows evict its earlier ones (no write names a slot twice)."""
+    _, adapter, _ = served
+    x = _MORE
+    writes = []
+    write = residual_cache._write
+
+    def recording(mesh, table, slots, rows):
+        writes.append(slots.tolist())
+        return write(mesh, table, slots, rows)
+
+    monkeypatch.setattr(residual_cache, "_write", recording)
+    cold = _explain_group(make_server(adapter), x, range(6), prefix="c")
+    srv = make_server(adapter, cache_capacity=2)
+    _predict_launch(srv, x[:2], ["q0", "q1"])
+    slot0 = srv.cache.peek("q0").slot
+    _predict_launch(srv, x[2:3], ["q2"])                  # evicts q0
+    assert "q0" not in srv.cache and srv.cache.peek("q2").slot == slot0
+    got = _explain_group(srv, x, [2, 1], prefix="q")
+    # 3 live rows and a padding row: q3 is stored, then evicted by q5
+    _predict_launch(srv, x[3:6], ["q3", "q4", "q5"])
+    assert len(srv.cache) == 2 and "q4" in srv.cache and "q5" in srv.cache
+    assert {srv.cache.peek(u).slot for u in ("q4", "q5")} == {0, 1}
+    assert writes[-1] == [2, srv.cache.peek("q4").slot,
+                          srv.cache.peek("q5").slot, 2]      # 2: dropped
+    got.update(_explain_group(srv, x, [5, 4], prefix="q"))
+    for i in (2, 1, 5, 4):
+        assert got[f"q{i}"].cache_hit
+        np.testing.assert_array_equal(np.asarray(got[f"q{i}"].relevance),
+                                      np.asarray(cold[f"c{i}"].relevance))
+
+
+def test_rows_per_gather_counts_each_groups_take(served):
+    """Each hit group is one take of its padded size: the counter and the
+    ``cache.gather`` span's ``rows`` read the same sequence."""
     _, adapter, x = served
     tracer = Tracer()
     srv = make_server(adapter, tracer=tracer)
     srv.serve([Request(uid="z", kind=PREDICT, x=x[0])])
     srv.serve([Request(uid="z", kind=EXPLAIN, x=x[0], method="saliency")])
+    _predict_launch(srv, x, ["p0", "p1", "p2"])
+    _explain_group(srv, x, [1], method="saliency")
+    _explain_group(srv, x, [0, 1, 2], method="saliency")      # padded to 4
+    _explain_group(srv, x, [0, 1], method="saliency")
     st = srv.cache.stats
-    assert (st.zero_copy_rows, st.copied_rows) == (1, 0)
-    assert st.snapshot()["zero_copy_share"] == 1.0
-    _predict_launch(srv, x, ["p0", "p1"])
-    _explain_group(srv, x, [1], method="saliency")            # sliced
-    _explain_group(srv, x, [0, 1], method="saliency")         # concatenated
-    assert (st.zero_copy_rows, st.copied_rows) == (1, 3)
-    assert st.snapshot()["zero_copy_share"] == 0.25
-    gathers = [s.args["copied"] for s in tracer.spans
-               if s.name == "cache.gather"]
-    assert gathers == [0, 1, 2]
+    assert (st.gathers, st.gathered_rows) == (4, 8)
+    assert st.snapshot()["rows_per_gather"] == 2.0
+    assert [s.args["rows"] for s in tracer.spans
+            if s.name == "cache.gather"] == [1, 1, 4, 2]
     assert [s.name for s in tracer.spans].count("cache.store") == 2
+
+
+class _Lowerings:
+    """Counts the programs JAX lowers while ``on`` (a jit cache miss)."""
+
+    _EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    _active = None
+
+    def __init__(self):
+        self.on, self.names = False, []
+        if _Lowerings._active is None:
+            jax.monitoring.register_event_duration_secs_listener(
+                lambda event, _s, **kw: _Lowerings._active._event(event, kw))
+        _Lowerings._active = self
+
+    def _event(self, event, kw):
+        if self.on and event == self._EVENT:
+            self.names.append(str(kw.get("fun_name", "?")))
+
+
+def test_group_across_launches_lowers_no_new_program(served):
+    """Once one launch of each padded size has been stored and gathered,
+    groups drawn from several launches run programs already lowered."""
+    _, adapter, _ = served
+    x = _MORE
+    warm = make_server(adapter)
+    for n in (1, 2, 4):
+        uids = [f"w{n}.{i}" for i in range(n)]
+        _predict_launch(warm, x, uids)
+        _explain_group(warm, x, range(n), prefix=f"w{n}.")
+    low = _Lowerings()
+    srv = make_server(adapter)
+    low.on = True
+    _predict_launch(srv, x[:1], ["q0"])
+    _predict_launch(srv, x[1:3], ["q1", "q2"])
+    _predict_launch(srv, x[3:7], ["q3", "q4", "q5", "q6"])
+    out = _explain_group(srv, x, [6, 0, 2], prefix="q")
+    out.update(_explain_group(srv, x, [1, 5], prefix="q"))
+    low.on = False
+    assert all(r.cache_hit for r in out.values()) and len(out) == 5
+    assert srv.cache.stats.gathers == 2
+    assert low.names == []
 
 
 def test_cold_bp_explain_warms_cache(setup):
@@ -772,9 +850,9 @@ def test_mesh_server_heatmaps_bitwise_with_single_device(setup):
 @pytest.mark.parametrize("shards", [1, 4])
 def test_mesh_server_multi_row_hits_bitwise_with_single_device(setup,
                                                                 shards):
-    """A 4-row predict on a mesh adapter parks its int32-word residuals by
-    reference; hits of rows other than 0 (alone, then together) slice
-    them at gather time and equal the single-device server bitwise."""
+    """A 4-row predict on a mesh adapter parks its int32-word residuals in
+    the slot table; hits of rows other than 0 (alone, then together) take
+    them back by slot and equal the single-device server bitwise."""
     from repro.engine.engine import _Words
     params, _, x = setup
     single = CNNAdapter(params, CFG, device="edge-small")
@@ -790,12 +868,15 @@ def test_mesh_server_multi_row_hits_bitwise_with_single_device(setup,
         out.update({f"g{uid}": r for uid, r in grp.items()})
         assert all(r.ok for r in out.values())
         assert all(r.cache_hit for r in out.values() if r.kind == EXPLAIN)
-        assert srv.cache.peek("p2").row == 2
+        assert srv.cache.peek("p2").slot == 2
         outs.append((srv, out))
     (_, out_s), (srv_m, out_m) = outs
-    words = jax.tree.leaves(srv_m.cache.peek("p3").residuals,
-                            is_leaf=lambda n: isinstance(n, _Words))
+    row = srv_m.cache.gather([srv_m.cache.peek("p3")], 1)
+    words = jax.tree.leaves(row, is_leaf=lambda n: isinstance(n, _Words))
     assert any(isinstance(w, _Words) for w in words)
+    assert all(t.dtype == jnp.int32 for t in srv_m.cache._table)
+    if shards > 1:          # the table takes the serving mesh's device order
+        assert srv_m.cache._mesh == meshed.engine.mesh
     cold = _explain_group(make_server(single), x, [1, 2, 3],
                           method="saliency", prefix="c")
     for key, i in (("p1", 1), ("p3", 3), ("gp2", 2), ("gp3", 3)):
@@ -809,3 +890,35 @@ def test_mesh_server_multi_row_hits_bitwise_with_single_device(setup,
             np.testing.assert_array_equal(
                 np.asarray(out_s[key].relevance),
                 np.asarray(out_m[key].relevance))
+
+
+def test_mesh_cache_follows_a_topology_ordered_serving_mesh(monkeypatch):
+    """A TPU's serving mesh orders its devices by the chips' topology (a
+    v5e 2x2 ring: 0, 1, 3, 2), and a jitted program takes its arguments in
+    one device order only: the slot table follows the serving mesh, so a
+    hit group from several launches replays as on one device."""
+    make_mesh = jax.make_mesh
+
+    def ring(shape, axes, devices=None, **kw):
+        devices = list(jax.devices() if devices is None else devices)
+        if len(devices) == 4:
+            devices = [devices[i] for i in (0, 1, 3, 2)]
+        return make_mesh(shape, axes, devices=devices, **kw)
+
+    monkeypatch.setattr(jax, "make_mesh", ring)
+    params = cnn.init(jax.random.PRNGKey(5), CFG)     # a fresh engine build
+    meshed = CNNAdapter(params, CFG, device="mesh:edge-small:4")
+    assert [d.id for d in meshed.engine.mesh.devices.flat] == [0, 1, 3, 2]
+    x = _MORE
+    srv = make_server(meshed, max_batch=1)             # 4 seats a launch
+    _predict_launch(srv, x[:1], ["q0"])
+    _predict_launch(srv, x[1:3], ["q1", "q2"])
+    _predict_launch(srv, x[3:6], ["q3", "q4", "q5"])
+    got = _explain_group(srv, x, [5, 0, 2, 3], method="saliency", prefix="q")
+    assert srv.cache._mesh == meshed.engine.mesh
+    cold = _explain_group(make_server(CNNAdapter(params, CFG)), x,
+                          [5, 0, 2, 3], method="saliency", prefix="c")
+    for i in (5, 0, 2, 3):
+        assert got[f"q{i}"].ok and got[f"q{i}"].cache_hit
+        np.testing.assert_array_equal(np.asarray(got[f"q{i}"].relevance),
+                                      np.asarray(cold[f"c{i}"].relevance))
